@@ -138,9 +138,9 @@ def _streamed_rows(problem: str, sizes: dict, *, smoke: bool):
     probe = ga.Engine(spec, "fused-islands",
                       options=ga.EngineOptions(cost_table=False))
     cfg = probe.backend.topology.cfg
-    # below the full stack, but a double-buffered 2-island tile fits:
+    # below the full stack, but a 2-island tile fits the tile rule:
     # the heuristic plans streamed with tile_islands=2
-    budget = KS.resident_vmem_bytes(cfg, isl - 3)
+    budget = 2 * KS.resident_vmem_bytes(cfg, 2)
     return [
         _one_row(f"engine_fused-islands[{problem}]+streamed",
                  "fused-islands", spec, smoke=smoke,

@@ -64,11 +64,11 @@ def main():
                              "gens_per_epoch": 16})
     table = sweep(specs + [free_spec], backend="fused-islands", log=print)
     # streamed coverage: an 8-island stack under a forced budget that only
-    # fits a double-buffered 2-island tile -> candidates [streamed, gridded]
+    # fits a 2-island tile -> candidates [streamed, gridded]
     from repro.kernels import ga_step as K
     stream_spec = ga.GASpec(problem="F3", **{**BASE, "n_islands": 8})
     probe = ga.Engine(stream_spec, "fused-islands", cost_table=False)
-    budget = K.resident_vmem_bytes(probe.backend.topology.cfg, 5)
+    budget = 2 * K.resident_vmem_bytes(probe.backend.topology.cfg, 2)
     sweep([stream_spec], backend="fused-islands",
           options=ga.EngineOptions(cost_table=False, vmem_budget=budget),
           table=table, log=print)

@@ -44,8 +44,8 @@ def main():
 
     probe = ga.Engine(SPEC, "fused-islands", cost_table=False)
     cfg = probe.backend.topology.cfg
-    # below the 8-island stack, but a double-buffered 2-island tile fits
-    budget = K.resident_vmem_bytes(cfg, 5)
+    # below the 8-island stack, but a 2-island tile fits the tile rule
+    budget = 2 * K.resident_vmem_bytes(cfg, 2)
     opts = ga.EngineOptions(cost_table=False, vmem_budget=budget)
     res = ga.solve(SPEC, backend="fused-islands", options=opts)
 

@@ -161,9 +161,23 @@ F3 = register_problem(ProblemDef(
 
 # --- The standard n-variable GA benchmark suite (configurable V) -----------
 
+
+def vsum(t: jax.Array) -> jax.Array:
+    """Σ over the trailing variable axis as a left fold of static slices.
+
+    One fixed add order that XLA and the Pallas kernel both execute — a
+    ``jnp.sum`` leaves the order of its float adds to each compiler, and the
+    fused and reference executors then round the same population
+    differently."""
+    acc = t[..., 0]
+    for i in range(1, t.shape[-1]):
+        acc = acc + t[..., i]
+    return acc
+
+
 register_problem(ProblemDef(
     name="sphere",
-    fn=lambda v: jnp.sum(v * v, axis=-1),
+    fn=lambda v: vsum(v * v),
     domain=(-5.12, 5.12),
     term=lambda v, i: v.astype(np.float64) ** 2,
 ))
@@ -171,8 +185,7 @@ register_problem(ProblemDef(
 register_problem(ProblemDef(
     name="rastrigin",
     # 10V + Σ x² - 10 cos(2πx), folded as Σ (x² - 10 cos(2πx) + 10)
-    fn=lambda v: jnp.sum(
-        v * v - 10.0 * jnp.cos(2.0 * np.pi * v) + 10.0, axis=-1),
+    fn=lambda v: vsum(v * v - 10.0 * jnp.cos(2.0 * np.pi * v) + 10.0),
     domain=(-5.12, 5.12),
     term=lambda v, i: (v.astype(np.float64) ** 2
                        - 10.0 * np.cos(2.0 * np.pi * v) + 10.0),
@@ -181,9 +194,9 @@ register_problem(ProblemDef(
 register_problem(ProblemDef(
     name="rosenbrock",
     # coupled terms -> not separable -> arith/kernel modes only
-    fn=lambda v: jnp.sum(
+    fn=lambda v: vsum(
         100.0 * (v[..., 1:] - v[..., :-1] * v[..., :-1]) ** 2
-        + (1.0 - v[..., :-1]) ** 2, axis=-1),
+        + (1.0 - v[..., :-1]) ** 2),
     domain=(-2.048, 2.048),
     min_vars=2,
 ))
@@ -192,8 +205,8 @@ register_problem(ProblemDef(
     name="ackley",
     # two coupled reductions -> not γ(Σφ)-separable -> arith/kernel only
     fn=lambda v: (-20.0 * jnp.exp(
-        -0.2 * jnp.sqrt(jnp.mean(v * v, axis=-1)))
-        - jnp.exp(jnp.mean(jnp.cos(2.0 * np.pi * v), axis=-1))
+        -0.2 * jnp.sqrt(vsum(v * v) / v.shape[-1]))
+        - jnp.exp(vsum(jnp.cos(2.0 * np.pi * v)) / v.shape[-1])
         + 20.0 + np.e),
     domain=(-32.768, 32.768),
 ))
@@ -203,7 +216,7 @@ def decode(u: jax.Array, c: int, domain: tuple) -> jax.Array:
     """Decode a c-bit unsigned gene to its real value (single shared box)."""
     lo, hi = domain
     scale = (hi - lo) / float((1 << c) - 1)
-    return lo + u.astype(jnp.float32) * jnp.float32(scale)
+    return lo + u.astype(jnp.int32).astype(jnp.float32) * jnp.float32(scale)
 
 
 # ---------------------------------------------------------------------------
@@ -340,13 +353,20 @@ class FitnessProgram:
     # ---- lowerings ------------------------------------------------------
 
     def decode(self, x: jax.Array) -> jax.Array:
-        """uint32 bits (..., V) -> f32 values (..., V), per-variable box."""
+        """uint32 bits (..., V) -> f32 values (..., V), per-variable box.
+
+        A box shared by every variable (every registered problem) decodes
+        with scalar constants, so the traced stage captures no arrays; the
+        int32 hop is exact (genes are ≤ 31 bits) and is the only
+        uint32 -> f32 path Mosaic lowers."""
         c = self.bits_per_var
         lo = np.asarray([d[0] for d in self.domains], np.float32)
         span = np.asarray([(d[1] - d[0]) / ((1 << c) - 1)
                            for d in self.domains], np.float32)
-        mask = np.uint32((1 << c) - 1)
-        return jnp.asarray(lo) + (x & mask).astype(jnp.float32) * jnp.asarray(span)
+        if len(set(self.domains)) == 1:
+            lo, span = lo[0], span[0]
+        u = (x & np.uint32((1 << c) - 1)).astype(jnp.int32)
+        return lo + u.astype(jnp.float32) * span
 
     def stage(self, x: jax.Array) -> jax.Array:
         """The arith/in-kernel FFM stage: uint32 bits (..., V) -> f32 (...,).

@@ -92,13 +92,13 @@ def _splice_elites(states: G.GAState, y: jax.Array, elites: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# Kernel-traceable migration math — THE rule set for elite/worst selection
-# and splicing, shared verbatim by the XLA epoch path AND the Pallas
-# resident-epoch kernel (kernels/ga_step.ga_epoch_kernel).  Everything here
-# is gather/scatter-free: first-occurrence argmin/argmax is a min-reduction
-# over a masked 2-D iota, and "gather row idx" / "scatter row idx" are a
-# masked sum / a select — exact for uint32 (single nonzero per mask) and
-# legal inside a TPU kernel, where dynamic per-row gathers are not.
+# Migration math — THE rule set for elite/worst selection and splicing on
+# island stacks (I, N, V).  Everything here is gather/scatter-free:
+# first-occurrence argmin/argmax is a min-reduction over a masked 2-D iota,
+# and "gather row idx" / "scatter row idx" are a masked sum / a select —
+# exact for uint32 (single nonzero per mask).  The Pallas epoch kernels
+# apply the same rules to one lane-major island at a time
+# (kernels/ga_step._best_slot / _take_column / _splice).
 # ---------------------------------------------------------------------------
 
 
@@ -154,8 +154,8 @@ def ring_migrate_stack(x: jax.Array, y: jax.Array, *, minimize: bool
     """One full ring migration over an in-block island stack (I, N, V):
     elite extraction -> shift-by-one across the island axis (the `jnp.roll`
     ring, written as a concat so it traces into a kernel) -> worst-slot
-    splice.  Returns (x', elite_x, elite_y).  Shared by `migrate_ring`
-    (XLA, between launches) and the resident-epoch kernel (in VMEM)."""
+    splice.  Returns (x', elite_x, elite_y).  `migrate_ring` runs it between
+    launches; the resident-epoch kernel applies the same rules in VMEM."""
     elite_x, elite_y = elites_stack(x, y, minimize=minimize)
     shifted = jnp.concatenate([elite_x[-1:], elite_x[:-1]], axis=0)
     x2 = splice_at(x, worst_slot(y, minimize=minimize), shifted)
@@ -188,9 +188,8 @@ def migrate_ring(states: G.GAState, y: jax.Array, *, minimize: bool
     ([19]); `lax.ppermute` plays the same role on a device mesh (see
     `migrate_ring_sharded`).  This is THE migration step shared by
     `make_local_step` and the engine's island_ring topology (any executor).
-    It delegates to `ring_migrate_stack`, the kernel-traceable form — so the
-    between-launch XLA migration and the resident-epoch kernel's in-VMEM
-    migration are the same math by construction.
+    It delegates to `ring_migrate_stack`, whose elite/worst/splice rules the
+    resident-epoch kernel's in-VMEM migration repeats island by island.
 
     Returns (new_states, elite_x [I, V], elite_y [I]).
     """

@@ -501,14 +501,20 @@ class SingleTopology(Topology):
         r = _arg_best(per_rep, mini)
         tb = np.asarray(tb)                                    # [R, gens]
         reduce = np.min if mini else np.max
+        tele = RT.RunTelemetry(per_repeat=RT.ReplicaStats(
+            best=per_rep, best_x=np.asarray(bx), traj_best=tb,
+            traj_mean=np.asarray(tm)))
+        if self.executor.name == "fused":
+            # the one launch shape a single population has: the gridded
+            # generation kernel, on the spec's selection lane
+            tele.plan = RT.PlanInfo(
+                mode="gridded", lane=self.cfg.sel_lane,
+                gens_per_launch=max(1, min(self.spec.gens_per_epoch, gens)))
         return Segment(state=state, best_y=float(per_rep[r]),
                        best_x=np.asarray(bx)[r],
                        traj_best=reduce(tb, axis=0),
                        traj_mean=np.asarray(tm).mean(axis=0),
-                       gens=gens,
-                       telemetry=RT.RunTelemetry(per_repeat=RT.ReplicaStats(
-                           best=per_rep, best_x=np.asarray(bx),
-                           traj_best=tb, traj_mean=np.asarray(tm))))
+                       gens=gens, telemetry=tele)
 
 
 class IslandRingTopology(Topology):
@@ -708,10 +714,6 @@ class IslandRingTopology(Topology):
                                                    plan_cfg)
             plan["vmem_estimate_bytes"] = _ga_step.resident_vmem_bytes(
                 plan_cfg, self.i_local, const_bytes)
-            if os.environ.get("REPRO_VMEM_COMPILER_CHECK") == "1":
-                plan["vmem_compiler_check"] = _ga_step.resident_compiler_check(
-                    plan_cfg, self.executor.fit, self.i_local,
-                    interpret=getattr(self.executor, "interpret", None))
         elif self.executor.name == "fused":
             # gridded fused launches hold ONE island per program instance —
             # report its lane-aware working set so benches can show the
@@ -781,8 +783,8 @@ class IslandRingTopology(Topology):
     def _resident_runner(self, k: int):
         """Jitted resident launch (no mesh): ONE `ga_epoch_kernel` call
         folding k whole migration intervals (k*migrate_every generations,
-        ring migration in VMEM).  Returns the same (state', by, bx, tb, tm)
-        contract as `_epoch`, with one trajectory sample per launch."""
+        ring migration in VMEM).  Returns the same (state', by, bx, tb, tm,
+        bg) contract as `_epoch`, with one trajectory sample per launch."""
         key = self._runner_key("resident", k)
         E = self.icfg.migrate_every
         R = self.spec.n_repeats
@@ -793,7 +795,7 @@ class IslandRingTopology(Topology):
         sq = (lambda a: a) if R > 1 else (lambda a: a[0])
 
         def launch(states):                    # states: [R?, I, ...]
-            x, sel, cross, mut, y, by, bx = _ga_step.ga_epoch_kernel(
+            x, sel, cross, mut, y, by, bx, bg = _ga_step.ga_epoch_kernel(
                 g4(states.x), g4(states.sel_lfsr), g4(states.cross_lfsr),
                 g4(states.mut_lfsr), cfg=cfg, ffm=ffm, migrate_every=E,
                 intervals=k, interpret=interp)
@@ -801,7 +803,7 @@ class IslandRingTopology(Topology):
                               states.k + k * E)
             tb = jnp.min(y, axis=-1) if mini else jnp.max(y, axis=-1)
             return (state, sq(by), sq(bx), sq(tb)[..., None],
-                    sq(jnp.mean(y, axis=-1))[..., None])
+                    sq(jnp.mean(y, axis=-1))[..., None], sq(bg))
 
         return self._cached_runner(key, lambda: jax.jit(launch))
 
@@ -809,7 +811,7 @@ class IslandRingTopology(Topology):
         """Jitted migration-free resident launch (`migration="none"`, no
         mesh): ONE `ga_epoch_kernel(migrate=False)` call folding g
         generations — no ring, so g is unconstrained by `migrate_every`.
-        Same (state', by, bx, tb, tm) contract as `_resident_runner`."""
+        Same (state', by, bx, tb, tm, bg) contract as `_resident_runner`."""
         key = self._runner_key("resident-free", g)
         R = self.spec.n_repeats
         mini = self.spec.minimize
@@ -819,7 +821,7 @@ class IslandRingTopology(Topology):
         sq = (lambda a: a) if R > 1 else (lambda a: a[0])
 
         def launch(states):                    # states: [R?, I, ...]
-            x, sel, cross, mut, y, by, bx = _ga_step.ga_epoch_kernel(
+            x, sel, cross, mut, y, by, bx, bg = _ga_step.ga_epoch_kernel(
                 g4(states.x), g4(states.sel_lfsr), g4(states.cross_lfsr),
                 g4(states.mut_lfsr), cfg=cfg, ffm=ffm, migrate_every=g,
                 intervals=1, migrate=False, interpret=interp)
@@ -827,7 +829,7 @@ class IslandRingTopology(Topology):
                               states.k + g)
             tb = jnp.min(y, axis=-1) if mini else jnp.max(y, axis=-1)
             return (state, sq(by), sq(bx), sq(tb)[..., None],
-                    sq(jnp.mean(y, axis=-1))[..., None])
+                    sq(jnp.mean(y, axis=-1))[..., None], sq(bg))
 
         return self._cached_runner(key, lambda: jax.jit(launch))
 
@@ -843,7 +845,7 @@ class IslandRingTopology(Topology):
         and gridded plans.  On a mesh the launch is shard_mapped and the
         boundary elite crosses shards via the `ppermute` ring INSIDE the
         scan body — which is why, unlike resident-sharded, k > 1 intervals
-        fold per launch.  Same (state', by, bx, tb, tm) contract as
+        fold per launch.  Same (state', by, bx, tb, tm, bg) contract as
         `_resident_runner` (one trajectory sample per launch)."""
         tile = self.plan["tile_islands"]
         key = self._runner_key("streamed", k, tile)
@@ -864,15 +866,18 @@ class IslandRingTopology(Topology):
                     g4(states.mut_lfsr),
                     jnp.full((n_groups, i_loc),
                              jnp.inf if mini else -jnp.inf, jnp.float32),
-                    jnp.zeros((n_groups, i_loc, cfg.v), jnp.uint32))
+                    jnp.zeros((n_groups, i_loc, cfg.v), jnp.uint32),
+                    jnp.zeros((n_groups, i_loc), jnp.int32))
 
-            def interval(carry, _):
-                x, sel, cross, mut, by, bx = carry
+            def interval(carry, j):
+                x, sel, cross, mut, by, bx, bg = carry
                 outs = _ga_step.ga_streamed_epoch_kernel(
                     x, sel, cross, mut, cfg=cfg, ffm=ffm, migrate_every=E,
                     tile_islands=tile, migrate=migrate, interpret=interp)
+                lbg = outs[-1]
                 if migrate:
-                    x, sel, cross, mut, ymig, lby, lbx, elite, widx = outs
+                    x, sel, cross, mut, ymig, lby, lbx, elite, widx = \
+                        outs[:-1]
                     if mesh is None:
                         # island 0 receives island I-1's elite — the same
                         # roll `ring_migrate_stack` writes as a concat
@@ -887,23 +892,24 @@ class IslandRingTopology(Topology):
                             [recv[:, None], elite[:, :-1]], axis=1)
                     x = jax.vmap(ISL.splice_at)(x, widx, incoming)
                 else:
-                    x, sel, cross, mut, ymig, lby, lbx = outs
+                    x, sel, cross, mut, ymig, lby, lbx = outs[:-1]
                 # fold the interval's in-kernel best into the launch best
                 # (strict improvement: earlier intervals win ties, matching
                 # the resident kernel's sequential per-generation fold)
                 better = lby < by if mini else lby > by
                 by = jnp.where(better, lby, by)
                 bx = jnp.where(better[..., None], lbx, bx)
-                return (x, sel, cross, mut, by, bx), ymig
+                bg = jnp.where(better, j * E + lbg, bg)
+                return (x, sel, cross, mut, by, bx, bg), ymig
 
-            carry, ys = jax.lax.scan(interval, init, None, length=k)
-            x, sel, cross, mut, by, bx = carry
+            carry, ys = jax.lax.scan(interval, init, jnp.arange(k))
+            x, sel, cross, mut, by, bx, bg = carry
             ymig = ys[-1]                      # final interval, pre-splice
             state = G.GAState(sq(x), sq(sel), sq(cross), sq(mut),
                               states.k + k * E)
             tb = jnp.min(ymig, axis=-1) if mini else jnp.max(ymig, axis=-1)
             return (state, sq(by), sq(bx), sq(tb)[..., None],
-                    sq(jnp.mean(ymig, axis=-1))[..., None])
+                    sq(jnp.mean(ymig, axis=-1))[..., None], sq(bg))
 
         fn = launch
         if mesh is not None:
@@ -919,7 +925,8 @@ class IslandRingTopology(Topology):
                                     k=pfor(0))
             fn = shard_map(
                 launch, mesh, in_specs=(state_specs,),
-                out_specs=(state_specs, pfor(0), pfor(1), pfor(1), pfor(1)))
+                out_specs=(state_specs, pfor(0), pfor(1), pfor(1), pfor(1),
+                           pfor(0)))
 
         return self._cached_runner(key, lambda: jax.jit(fn))
 
@@ -941,7 +948,7 @@ class IslandRingTopology(Topology):
         sq = (lambda a: a) if R > 1 else (lambda a: a[0])
 
         def epoch(states):                     # states: [R?, I_loc, ...]
-            x, sel, cross, mut, y, by, bx, send, w0 = \
+            x, sel, cross, mut, y, by, bx, send, w0, bg = \
                 _ga_step.ga_epoch_kernel(
                     g4(states.x), g4(states.sel_lfsr),
                     g4(states.cross_lfsr), g4(states.mut_lfsr), cfg=cfg,
@@ -957,14 +964,16 @@ class IslandRingTopology(Topology):
                               states.k + E)
             tb = jnp.min(y, axis=-1) if mini else jnp.max(y, axis=-1)
             return (state, sq(by), sq(bx), sq(tb)[..., None],
-                    sq(jnp.mean(y, axis=-1))[..., None])
+                    sq(jnp.mean(y, axis=-1))[..., None], sq(bg))
 
         return epoch
 
     def _epoch(self):
         """Jitted epoch over the canonical state layout ([I,...] or
-        [R, I, ...]); returns (state', by, bx, tb, tm) with by/bx/tb/tm in
-        [R, I, ...] layout (leading R axis only when n_repeats > 1).  On a
+        [R, I, ...]); returns (state', by, bx, tb, tm, bg) with
+        by/bx/tb/tm/bg in [R, I, ...] layout (leading R axis only when
+        n_repeats > 1); bg is the launch generation each island's best was
+        first seen in, for the segment's interval-first tie rule.  On a
         mesh the epoch body is shard_mapped over the island axis — the body
         sees [R?, I/n_shards, ...] blocks and the ring crosses shards via
         `ppermute`; telemetry comes back as the same global arrays."""
@@ -988,12 +997,15 @@ class IslandRingTopology(Topology):
                 mig = lambda s, yy: ISL.migrate_ring_sharded(
                     s, yy, minimize=mini, mesh=mesh, axis_names=axes)
 
+            # a launch is one interval: every island's best shares its
+            # epoch, so the best-generation output is all zeros
             def one(states):                   # states: [I(_loc), ...]
                 states, by, bx, tb, tm = blk(states)
                 if migrate:
                     y = fit_stack(states)      # [I(_loc), N]
                     states, _ex, _ey = mig(states, y)
-                return states, by, bx, tb, tm
+                return states, by, bx, tb, tm, jnp.zeros(by.shape,
+                                                         jnp.int32)
 
             if R == 1:
                 epoch = one
@@ -1011,7 +1023,8 @@ class IslandRingTopology(Topology):
                     return (states, by.reshape(R, il),
                             bx.reshape((R, il) + bx.shape[1:]),
                             tb.reshape((R, il) + tb.shape[1:]),
-                            tm.reshape((R, il) + tm.shape[1:]))
+                            tm.reshape((R, il) + tm.shape[1:]),
+                            jnp.zeros((R, il), jnp.int32))
 
         if mesh is not None:
             from jax.sharding import PartitionSpec as P
@@ -1026,7 +1039,8 @@ class IslandRingTopology(Topology):
                                     k=pfor(0))
             epoch = shard_map(
                 epoch, mesh, in_specs=(state_specs,),
-                out_specs=(state_specs, pfor(0), pfor(1), pfor(1), pfor(1)))
+                out_specs=(state_specs, pfor(0), pfor(1), pfor(1), pfor(1),
+                           pfor(0)))
 
         return self._cached_runner(key, lambda: jax.jit(epoch))
 
@@ -1049,7 +1063,7 @@ class IslandRingTopology(Topology):
             sched, left = [], epochs * E
             while left:
                 g = min(g_max, left)
-                sched.append(self._resident_free_runner(g))
+                sched.append((self._resident_free_runner(g), g))
                 left -= g
             unit = g_max
         else:
@@ -1057,11 +1071,11 @@ class IslandRingTopology(Topology):
             while left:
                 k = min(per_launch, left)
                 if mode == "resident":
-                    sched.append(self._resident_runner(k))
+                    sched.append((self._resident_runner(k), k * E))
                 elif mode == "streamed":
-                    sched.append(self._streamed_runner(k))
+                    sched.append((self._streamed_runner(k), k * E))
                 else:
-                    sched.append(self._epoch())
+                    sched.append((self._epoch(), E))
                 left -= k
             unit = E * per_launch
         # running per-replica best across launches (telemetry arrays get
@@ -1069,12 +1083,19 @@ class IslandRingTopology(Topology):
         rep_y = np.full((R,), np.inf if mini else -np.inf, np.float32)
         rep_x = np.zeros((R, self.cfg.v), np.uint32)
         tb_ep, tm_ep = [], []          # per-launch, per-replica ([R] each)
-        launches = 0
-        for runner in sched:
-            state, by, bx, tb, tm = runner(state)
+        launches, g_start = 0, 0
+        for runner, g_launch in sched:
+            state, by, bx, tb, tm, bg = runner(state)
             by = np.asarray(by).reshape(R, -1)              # [R, I]
             bx = np.asarray(bx).reshape(R, -1, self.cfg.v)  # [R, I, V]
-            i = np.argmin(by, axis=1) if mini else np.argmax(by, axis=1)
+            # the reference's tie rule across islands: the first migration
+            # interval the launch best shows up in, then the first island
+            # (a launch folding k intervals must not let island order win)
+            m = reduce(by, axis=1, keepdims=True)
+            ivl = (g_start + np.asarray(bg).reshape(R, -1)) // E
+            i = np.argmin(np.where(by == m, ivl, np.iinfo(np.int32).max),
+                          axis=1)
+            g_start += g_launch
             ep_y = by[np.arange(R), i]                      # [R]
             ep_x = bx[np.arange(R), i]
             better = ep_y < rep_y if mini else ep_y > rep_y

@@ -32,7 +32,8 @@ class PlanInfo:
     """The epoch-plan decision a segment ran under.
 
     mode: "gridded" | "resident" | "resident-sharded" | "resident-free" |
-    "streamed" | "-" (no plan: single topology / reference executor).
+    "streamed" | "-" (no plan: reference/eager executors).  A single
+    population on the fused executor always runs "gridded".
     source: "heuristic" | "measured" | "forced" | "-".  fallback carries
     the VMEM-estimator reason when the resident shape was rejected (set for
     both the gridded fallback AND the streamed lane, which exists because

@@ -1,54 +1,54 @@
-"""Pallas TPU kernel: one fused GA generation per island.
+"""Pallas TPU kernels: fused GA generations for one island or a stack.
 
 This is the TPU re-expression of the paper's full-parallel datapath: on the
 FPGA, FFM/SM/CM/MM are N physically parallel circuits clocked as one 3-cycle
-pipeline; here the whole generation is ONE kernel launch whose working set
-(population, fitness vector, LFSR banks, one-hot tournament matrices) lives
-entirely in VMEM — no HBM round-trips between GA stages.
+pipeline; here a whole generation is one pass of a kernel whose working set
+(population, fitness row, LFSR banks, selection scratch) lives in VMEM — no
+HBM round-trips between GA stages.
+
+Layout.  Inside the kernels an island is LANE-MAJOR: x (V, N), sel (2, N),
+cross (V, N/2), mut (V, N), y (1, N) — the population index runs along the
+128 lanes of a vreg, so every op is a 2-D (sublane, lane) op Mosaic lowers.
+The launchers transpose x between the engine's (N, V) and (V, N) in XLA;
+the FFM stage is evaluated on the (N, V) view, exactly the function the
+reference executor evaluates.  A block holding several islands steps them
+one at a time (a `fori_loop` over the refs' leading island axis): islands
+are independent between migrations, and no op is ever 3-D.
 
 Key adaptation — MUX trees → two selection lanes (``GAConfig.sel_lane``):
   the paper gathers tournament contestants through N-input multiplexer trees
   (SMMUX1..3, the source of its O(N²) LUT growth).  The kernels implement
   that gather two bit-identical ways:
 
-  * ``"onehot"`` — the systolic array contracts an (N, N) one-hot matrix
-    against the population in O(N²) MACs, the MUX tree's asymptotics in
-    hardware we do have.  Bit-exactness is preserved by splitting each
-    uint32 word into two 16-bit halves before the f32 matmul (≤ 2^16 is
-    exactly representable; each one-hot row has a single nonzero so the
-    accumulation is exact), then recombining.
-  * ``"gather"`` — plain dynamic indexing (``jnp.take`` row gathers on the
-    VPU): O(N·V) working set, trivially exact, no one-hot scratch.  This
-    drops the dominant VMEM term and with it the N ≤ 1024 cap.
+  * ``"onehot"`` — (N, N) contestant masks: the fitness pick is an exact
+    masked max, the chromosome pick contracts the winner one-hot against the
+    population on the MXU in O(N²·V) MACs, the MUX tree's asymptotics in
+    hardware we do have.  Bit-exactness: each uint32 word splits into two
+    16-bit halves before the f32 matmul (≤ 2^16 is exact in f32; one nonzero
+    per column), then recombines.
+  * ``"gather"`` — dynamic indexing: `_lane_take` gathers lanes chunk by
+    chunk (Mosaic gathers only inside one 128-lane vreg), O(N·V) working
+    set, no (N, N) scratch.  This drops the one-hot term and with it the
+    N ≤ 1024 cap.
 
-  Both lanes consume the same tournament indices and apply the same tie
-  rules; the one-hot matmuls were already exact, so the lanes are
-  bit-identical to each other and to the reference path.
-
-Grid: one program instance per island.  VMEM budget per instance is
-lane-dependent (`resident_vmem_bytes`):
-
-  * onehot lane — dominated by the (N, N) one-hot f32 matrices (iota + two
-    contestant one-hots + winner ≈ 16·N² B): N ≤ 1024 keeps one island at
-    ≤ ~4 MiB apart from state, and `check_kernel_lane` raises past that
-    (fix: more islands, or ``sel_lane="gather"``).
-  * gather lane — state + offspring only, O(N·(V + 1)) per island: the
-    selection working set collapses from 16·N² B to a few index/fitness
-    vectors (~64× smaller at N=1024), so N = 2048+ single-island runs are
-    feasible.  Power-of-two N is still required on BOTH lanes (the
-    tournament indices are the top `idx_bits` of the LFSR draw).
-
-The FPGA paper tops out at N=64; larger populations use more islands, the
-gather lane, or the pure-JAX path in repro.core.ga.
+  Both lanes consume the same tournament indices and tie rules, so they are
+  bit-identical to each other and to the reference path.  Power-of-two N is
+  required on both (the tournament indices are the top `idx_bits` of the
+  LFSR draw).
 
 The FFM stage is PLUGGABLE: the kernel takes a traceable ``ffm`` function
 ``uint32[N, V] bits -> f32[N]`` (normally ``FitnessProgram.stage`` from
-repro.core.fitness — decode + the problem's jnp expression on the VPU) and
-traces it into the kernel body, so any n-variable registry problem or user
-blackbox runs fused, not just the paper's two-variable polynomials.  Because
-the reference executor evaluates the SAME function, fused stays bit-identical
-to reference for every program.  LUT-mode (HBM gather tables) stays in the
-pure-JAX path — gathers inside a TPU kernel would defeat the fusion.
+repro.core.fitness — decode + the problem's jnp expression) and traces it
+into the kernel body, so any n-variable registry problem or user blackbox
+runs fused.  The reference executor evaluates the SAME function; the
+registry problems reduce over V in one fixed order (`fitness.vsum`) so both
+compilers round alike.  LUT-mode (HBM gather tables) stays in the pure-JAX
+path.
+
+Mosaic's limits shape the bodies: no uint32 <-> f32 casts (they go through
+int32, exact for ≤ 31-bit genes and 16-bit halves), no unsigned min or
+reductions (int32 again), no scalar stores (bests are (1, 1) / (V, 1)
+blocks), and block shapes whose last two dims equal the array's.
 
 Epoch planning & VMEM budget — the TWO-TIER decision:
 
@@ -62,60 +62,43 @@ Epoch planning & VMEM budget — the TWO-TIER decision:
 
   * gridded (`ga_generation_kernel`) — one island per grid step; a launch
     folds up to `migrate_every` generations and the ring migration runs
-    BETWEEN launches in XLA (`islands.migrate_ring`).  VMEM per program
-    instance holds ONE island.  Always feasible; always the fallback.
+    BETWEEN launches in XLA (`islands.migrate_ring`).  Always feasible;
+    always the fallback.
   * resident (`ga_epoch_kernel`) — the island axis moves out of the grid
     into the kernel block: all (local-shard) islands live in one program
     instance's VMEM, and the launch folds `intervals × migrate_every`
-    generations with the ring migration (`islands.ring_migrate_stack`, the
-    same elite/worst tie rules) executed INSIDE the `fori_loop`.  One launch
-    spans many migration intervals, so `gens_per_epoch` is no longer capped
-    at `migrate_every`.  On a mesh, `boundary=True` keeps one interval per
-    launch and performs the intra-shard part of the migration in VMEM; the
-    boundary elite is handed back for the between-launch `lax.ppermute`
-    (mode "resident-sharded").
+    generations with the ring migration (the elite/worst tie rules of
+    `islands.ring_migrate_stack`) executed INSIDE the loop.  On a mesh,
+    `boundary=True` keeps one interval per launch and performs the
+    intra-shard part of the migration in VMEM; the boundary elite is handed
+    back for the between-launch `lax.ppermute` (mode "resident-sharded").
   * resident-free (`ga_epoch_kernel` with `migrate=False`) — the
     `migration="none"` ablation has no ring to run, so one launch folds the
-    WHOLE `gens_per_epoch` (any value, no whole-multiple rule) with zero
-    in-kernel migration work.
-  * streamed (`ga_streamed_epoch_kernel`) — the HBM-streaming lane for
-    populations PAST the residency budget: the island axis joins the grid
-    in tiles of `tile_islands` islands, and Pallas's grid pipeline
-    double-buffers the tile loads (the next tile's HBM→VMEM copy overlaps
-    the current tile's `migrate_every` generations), so only ~2 tiles of
-    working set ever occupy VMEM.  Elite/worst-slot extraction still runs
-    in-kernel per tile; the ring splice between tiles runs in XLA between
-    kernel passes, inside one jitted `lax.scan` over the migration
-    intervals (sharded meshes `ppermute` the boundary elite inside the
-    same scan, so unlike resident-sharded a launch folds k > 1 intervals).
-    `streamed_tile_islands` picks the largest island tile whose
-    double-buffered working set fits; when a spec outgrows residency the
-    planner now prefers this mode over the gridded fallback.
+    WHOLE `gens_per_epoch` with zero in-kernel migration work.
+  * streamed (`ga_streamed_epoch_kernel`) — populations PAST the residency
+    budget: the island axis joins the grid in tiles of `tile_islands`
+    islands, and Pallas's grid pipeline double-buffers the tile loads.
+    Elite/worst-slot extraction runs in-kernel per tile; the ring splice
+    runs in XLA between kernel passes, inside one jitted `lax.scan` over the
+    migration intervals.  `streamed_tile_islands` picks the tile.
 
   tier 2 — SELECTION (measured, `repro.autotune`): among feasible
   candidates the planner picks the best *measured* gens/s from a per-host
   cost table when one covers the spec, and otherwise keeps the first
   candidate — `epoch_mode_candidates` orders candidates so that index 0 IS
   the heuristic (resident when it fits, else streamed when a tile fits,
-  else gridded), making the no-table path deterministic without
-  measurement.
+  else gridded).
 
-  The VMEM estimator is LANE-AWARE: the island state stack (population +
-  LFSR banks + fitness) PLUS the per-island selection working set — on the
-  onehot lane the one-hot tournament matrices, which materialize as
-  [I, N, N] under the in-kernel island vmap; on the gather lane a few O(N)
-  index/fitness vectors — PLUS any hoisted FFM
-  constants must stay under `resident_vmem_budget()` (default 16 MiB ≈ one
-  TPU core's VMEM; override with REPRO_RESIDENT_VMEM_BUDGET).  When it does
-  not fit, the engine silently falls back to the gridded kernel (capping
-  generations per launch at `migrate_every` again) — a perf fallback, never
-  an error.  On real TPUs the estimate can additionally be cross-checked
-  against the compiler's own VMEM accounting (`resident_compiler_check`
-  compiles with `pltpu.CompilerParams(vmem_limit_bytes=budget)` and records
-  the estimator-vs-compiler margin); in interpret mode the check reports
-  "unavailable" and the byte estimator stands alone.
+  The VMEM estimator (`resident_vmem_bytes`) counts the (8, 128) tile
+  padding Mosaic applies: the double-buffered island blocks of the stack,
+  ONE island's generation temporaries (lane-aware: (N, N) masks on the
+  onehot lane) and the hoisted FFM constants.  The budget comes from
+  `VMEM_BUDGET_BYTES`, keyed by `device_kind` (REPRO_RESIDENT_VMEM_BUDGET
+  overrides), and every launch hands the same number to Mosaic as
+  `vmem_limit_bytes` — so a plan the estimator accepts compiles
+  (tests/test_tpu_compile.py compiles the planned shapes for a v5e).
 
-  Hoisted FFM closure constants are size-gated separately: both kernels
+  Hoisted FFM closure constants are size-gated separately: the kernels
   refuse constants above `ffm_const_limit()` (default 2 MiB, override with
   REPRO_FFM_CONST_LIMIT) because every grid step re-reads them into VMEM —
   a large captured array (e.g. a dataset) should run on the reference path
@@ -133,12 +116,17 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.core import islands as ISL
 from repro.core import lfsr
 from repro.core.ga import GAConfig, ONEHOT_MAX_N
 
 # The kernel-facing FFM stage: uint32 bits (N, V) -> f32 fitness (N,).
 FfmStage = Callable[[jax.Array], jax.Array]
+
+# A TPU vreg is (8 sublanes, 128 lanes) of 32-bit words: VMEM arrays pad
+# their last two dims to it, and Mosaic gathers within one vreg only.
+_SUBLANES = 8
+_LANES = 128
+_LANE_SHIFT = 7
 
 
 def _lfsr_draw(state, steps: int):
@@ -168,35 +156,6 @@ def _lfsr_draw(state, steps: int):
         state = out
         steps -= t
     return state
-
-
-def _lfsr_draw_banks(banks, steps: int):
-    """One fused GF(2) leap advancing several LFSR banks at once.
-
-    The paper clocks its three RNG banks (selection / crossover / mutation)
-    in lockstep; leaping each bank separately pays the leap-table mask loop
-    three times per generation.  The leap is elementwise in the register
-    word and every bank advances by the same `steps`, so the banks
-    concatenate — each flattened to one (1, size) lane row — into a single
-    register file, ONE `_lfsr_draw` advances everything, and the result
-    splits back.  Bit-identical per element to leaping each bank alone."""
-    flat = jnp.concatenate([b.reshape(1, -1) for b in banks], axis=1)
-    flat = _lfsr_draw(flat, steps)
-    out, off = [], 0
-    for b in banks:
-        size = int(np.prod(b.shape))
-        out.append(flat[:, off:off + size].reshape(b.shape))
-        off += size
-    return tuple(out)
-
-
-def _onehot_gather_u32(oh: jax.Array, x: jax.Array) -> jax.Array:
-    """Exact uint32 gather via two 16-bit-half f32 matmuls on the MXU."""
-    hi = (x >> 16).astype(jnp.float32)
-    lo = (x & jnp.uint32(0xFFFF)).astype(jnp.float32)
-    ghi = jax.lax.dot(oh, hi, precision=jax.lax.Precision.HIGHEST)
-    glo = jax.lax.dot(oh, lo, precision=jax.lax.Precision.HIGHEST)
-    return (ghi.astype(jnp.uint32) << 16) | glo.astype(jnp.uint32)
 
 
 def check_kernel_lane(cfg: GAConfig) -> None:
@@ -297,30 +256,79 @@ def _check_const_gate(nbytes: int) -> None:
             "(REPRO_FFM_CONST_LIMIT overrides the gate)")
 
 
+# Per-chip VMEM budget of one kernel launch, keyed by `jax.Device.device_kind`.
+# TPU v5e ("TPU v5 lite") has 128 MiB of VMEM per TensorCore (JAX's
+# `jax/_src/pallas/mosaic/tpu_info.py`, TPU_V5E entry).  The budget is half of
+# it: every launch passes it to Mosaic as `vmem_limit_bytes`, and the other
+# half stays free for Mosaic's internal scratch and for XLA.
+VMEM_BUDGET_BYTES = {"TPU v5 lite": 64 << 20, "TPU v5e": 64 << 20}
+# Interpret mode has no VMEM; it plans for the chip this repo targets, so a
+# CPU run picks the same plan the chip would.
+PLANNING_DEVICE_KIND = "TPU v5 lite"
+
+
 def resident_vmem_budget() -> int:
-    """VMEM byte budget for the resident-epoch kernel (default 16 MiB ≈ one
-    TPU core); REPRO_RESIDENT_VMEM_BUDGET overrides."""
-    return int(os.environ.get("REPRO_RESIDENT_VMEM_BUDGET", str(16 << 20)))
+    """VMEM byte budget of one kernel launch on this host's device — the
+    planner's feasibility limit AND the `vmem_limit_bytes` every launch
+    hands Mosaic.  REPRO_RESIDENT_VMEM_BUDGET overrides; a TPU kind missing
+    from `VMEM_BUDGET_BYTES` is an error."""
+    env = os.environ.get("REPRO_RESIDENT_VMEM_BUDGET")
+    if env:
+        return int(env)
+    dev = jax.devices()[0]
+    kind = dev.device_kind if dev.platform == "tpu" else PLANNING_DEVICE_KIND
+    if kind not in VMEM_BUDGET_BYTES:
+        raise ValueError(
+            f"no VMEM budget for TPU device kind {kind!r}: add its entry to "
+            "kernels.ga_step.VMEM_BUDGET_BYTES (or set "
+            "REPRO_RESIDENT_VMEM_BUDGET)")
+    return VMEM_BUDGET_BYTES[kind]
+
+
+def _tile_bytes(rows: int, cols: int) -> int:
+    """Bytes of a 32-bit (rows, cols) VMEM array: Mosaic pads the
+    second-minor dim to 8 sublanes and the minor dim to 128 lanes."""
+    return 4 * (-(-rows // _SUBLANES) * _SUBLANES) * (-(-cols // _LANES)
+                                                      * _LANES)
+
+
+def _island_block_bytes(cfg: GAConfig) -> int:
+    """One island's input + output blocks in the kernels' lane-major layout:
+    state x (V, N), sel (2, N), cross (V, N/2), mut (V, N) in and out, plus
+    the y (1, N), best (1, 1)/(V, 1) and elite/worst outputs."""
+    n, v = cfg.n, cfg.v
+    state = (2 * _tile_bytes(v, n) + _tile_bytes(2, n)
+             + _tile_bytes(v, n // 2))
+    extra = _tile_bytes(1, n) + 2 * _tile_bytes(1, 1) + 2 * _tile_bytes(v, 1)
+    return 2 * state + extra
+
+
+def _island_work_bytes(cfg: GAConfig) -> int:
+    """Temporaries of one island's generation.  The kernels step the
+    islands of a block one at a time, so one set is live per launch: the
+    FFM stage on the (N, V) transpose, the LFSR/crossover/mutation rows and
+    the selection lane's working set — four (N, N) masks/one-hots on the
+    onehot lane, chunked (V, N) gathers on the gather lane."""
+    n, v = cfg.n, cfg.v
+    ffm = 8 * _tile_bytes(n, v)
+    rows = 12 * _tile_bytes(v, n) + 8 * _tile_bytes(2, n)
+    if cfg.sel_lane == "gather":
+        sel = 4 * _tile_bytes(v, n) + 4 * _tile_bytes(2, n)
+    else:
+        sel = 4 * _tile_bytes(n, n) + 4 * _tile_bytes(v, n)
+    return ffm + rows + sel
 
 
 def resident_vmem_bytes(cfg: GAConfig, n_islands: int,
                         const_bytes: int = 0) -> int:
-    """Estimated VMEM working set of one resident-epoch program instance:
-    the island state stack (population, LFSR banks, fitness) plus the
-    LANE-DEPENDENT selection working set — on the onehot lane the one-hot
-    tournament matrices, the dominant term, since the in-kernel island vmap
-    materializes the (N, N) iota/one-hot matrices as [I, N, N]; on the
-    gather lane just the O(N) tournament index/fitness vectors — plus
-    offspring temporaries and the hoisted FFM consts."""
-    n, v = cfg.n, cfg.v
-    state = 4 * (n * v + 2 * n + v * (n // 2) + v * n + n)  # x/sel/cross/mut/y
-    if cfg.sel_lane == "gather":
-        sel = 4 * 6 * n                 # i1/i2/y1/y2/winner idx + mask, i32
-    else:
-        sel = 4 * 4 * n * n             # iota + oh1 + oh2 + winner, f32
-    work = 4 * (2 * n * v + 4 * n)      # offspring + tournament temporaries
-    best = 4 * (1 + v)                  # running best fold
-    return n_islands * (state + sel + work + best) + const_bytes
+    """Estimated VMEM of one kernel program instance holding `n_islands`
+    islands, with Mosaic's (8, 128) tile padding: the island blocks, which
+    the Pallas pipeline double-buffers, one island's generation temporaries
+    (see `_island_work_bytes`) and the hoisted FFM consts (one (1, k) row
+    each, double-buffered)."""
+    consts = 2 * _tile_bytes(1, max(1, const_bytes // 4)) if const_bytes else 0
+    return (2 * n_islands * _island_block_bytes(cfg) + _island_work_bytes(cfg)
+            + consts)
 
 
 def resident_fit_reason(cfg: GAConfig, n_islands: int, const_bytes: int = 0,
@@ -340,12 +348,10 @@ def resident_fit_reason(cfg: GAConfig, n_islands: int, const_bytes: int = 0,
 def streamed_tile_islands(cfg: GAConfig, i_local: int, const_bytes: int = 0,
                           budget: int = None) -> int:
     """The streamed lane's VMEM tile estimator: the largest island-tile size
-    T (a divisor of `i_local`) whose DOUBLE-BUFFERED working set fits the
-    budget — the grid pipeline prefetches the next tile's block while the
-    current one computes, so ~2 tiles of state + one-hot scratch (+ the
-    hoisted FFM consts, replicated per buffer: conservative) live in VMEM
-    at once.  None when even a single double-buffered island won't fit —
-    then only the gridded fallback remains."""
+    T (a divisor of `i_local`) with 2× its resident estimate within the
+    budget (a margin over the pipeline's own double buffering, so a
+    streamed tile never sits at the edge of what compiles).  None when even
+    a single island won't fit — then only the gridded fallback remains."""
     budget = resident_vmem_budget() if budget is None else budget
     for t in range(i_local, 0, -1):
         if i_local % t:
@@ -432,169 +438,253 @@ def epoch_mode_candidates(cfg: GAConfig, i_local: int, const_bytes: int = 0,
     return [gridded]
 
 
-def resident_compiler_check(cfg: GAConfig, ffm: FfmStage, i_local: int, *,
-                            budget: int = None, interpret: bool = None
-                            ) -> dict:
-    """Tier-1 cross-check: does the COMPILER agree the resident working set
-    fits?  Lowers a one-generation resident launch with
-    `pltpu.CompilerParams(vmem_limit_bytes=budget)` and reports
-    {"status": "ok" | "exceeds" | "unavailable", "estimator_bytes",
-    "budget_bytes", "estimator_margin"} — the margin is the headroom the
-    byte estimator claims, so an "exceeds" with positive margin means the
-    hand-written model underestimates on this config.  In interpret mode
-    (CPU CI) there is no Mosaic lowering to ask, hence "unavailable"."""
-    budget = resident_vmem_budget() if budget is None else budget
-    est = resident_vmem_bytes(cfg, i_local, ffm_const_bytes(ffm, cfg))
-    out = {"estimator_bytes": est, "budget_bytes": budget,
-           "estimator_margin": round(1.0 - est / budget, 4)}
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if interpret:
-        out.update(status="unavailable",
-                   reason="compiler VMEM accounting needs a real TPU "
-                          "(Mosaic) lowering; interpret mode has none")
-        return out
-    n, v = cfg.n, cfg.v
-    shapes = (jax.ShapeDtypeStruct((1, i_local, n, v), jnp.uint32),
-              jax.ShapeDtypeStruct((1, i_local, 2, n), jnp.uint32),
-              jax.ShapeDtypeStruct((1, i_local, v, n // 2), jnp.uint32),
-              jax.ShapeDtypeStruct((1, i_local, v, n), jnp.uint32))
-    fn = functools.partial(ga_epoch_kernel, cfg=cfg, ffm=ffm,
-                           migrate_every=1, intervals=1, interpret=False,
-                           vmem_limit_bytes=budget)
-    try:
-        jax.jit(lambda *a: fn(*a)).lower(*shapes).compile()
-        out["status"] = "ok"
-    except Exception as e:                  # compiler rejected the budget
-        out.update(status="exceeds", reason=repr(e))
-    return out
+
+# ---------------------------------------------------------------------------
+# Kernel body: one island in the lane-major layout
+# ---------------------------------------------------------------------------
+#
+# Inside the kernels an island is lane-major: x (V, N), sel (2, N),
+# cross (V, N/2), mut (V, N), y (1, N) — the population index runs along the
+# 128 lanes, so every operand is a 2-D (sublane, lane) tile.  The launchers
+# transpose x between the engine's (N, V) layout and (V, N) in XLA, and the
+# FFM stage still sees the (N, V) view the reference executor evaluates.
+# Blocks holding several islands step them one at a time (`fori_loop` over
+# the leading island axis of the refs), so no op is ever 3-D.
 
 
-def _gen_best(x, y, cfg: GAConfig):
-    """First-occurrence generation best — the reference scan's argmin/argmax
-    tie rule: the index is a min-reduction over a masked iota (no argmin
-    inside the kernel), the chromosome pick then runs on the configured
-    selection lane (one-hot matmul gather vs a jnp.take row gather)."""
-    m = jnp.min(y) if cfg.minimize else jnp.max(y)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (cfg.n,), 0)
-    idx = jnp.min(jnp.where(y == m, iota, cfg.n))
+def _lane_take(a: jax.Array, idx: jax.Array) -> jax.Array:
+    """out[:, j] = a[:, idx[:, j]] for a (R, N) array and a (1 | R, N) index
+    row.  Mosaic gathers only within one 128-lane vreg, so a wider row is
+    gathered chunk by chunk from every source chunk and the hit selected."""
+    r, n = a.shape
+    if r == 1:                          # Mosaic's gather wants R > 1
+        return _lane_take(jnp.concatenate([a, a], axis=0), idx)[:1]
+    idx = jnp.broadcast_to(idx, (r, n))
+    if n <= _LANES:
+        return jnp.take_along_axis(a, idx, axis=1)
+    chunks = []
+    for k in range(n // _LANES):
+        ik = idx[:, k * _LANES:(k + 1) * _LANES]
+        lo, src = ik & (_LANES - 1), ik >> _LANE_SHIFT
+        acc = None
+        for j in range(n // _LANES):
+            g = jnp.take_along_axis(a[:, j * _LANES:(j + 1) * _LANES], lo,
+                                    axis=1)
+            acc = g if acc is None else jnp.where(src == j, g, acc)
+        chunks.append(acc)
+    return jnp.concatenate(chunks, axis=1)
+
+
+def _pair_swap(w: jax.Array) -> jax.Array:
+    """w[:, p ^ 1]: each crossover pair's partner (pairs never straddle a
+    128-lane chunk, so each chunk gathers alone)."""
+    n = w.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, min(n, _LANES)), 1)
+    if n <= _LANES:
+        return _lane_take(w, lane ^ 1)
+    return jnp.concatenate(
+        [_lane_take(w[:, k * _LANES:(k + 1) * _LANES], lane ^ 1)
+         for k in range(n // _LANES)], axis=1)
+
+
+def _pair_expand(s: jax.Array) -> jax.Array:
+    """(R, N/2) per-pair values -> (R, N) with s[:, p // 2] at lane p."""
+    h = s.shape[1]
+    n = 2 * h
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, min(n, _LANES)), 1)
+    if n <= _LANES:
+        return _lane_take(jnp.concatenate([s, s], axis=1), lane >> 1)
+    half = _LANES // 2
+    return jnp.concatenate(
+        [_lane_take(s[:, (k // 2) * _LANES:(k // 2 + 1) * _LANES],
+                    (lane >> 1) + (k % 2) * half)
+         for k in range(n // _LANES)], axis=1)
+
+
+def _onehot_gather_u32(x: jax.Array, oh: jax.Array) -> jax.Array:
+    """Exact uint32 column gather x (R, N) @ one-hot (N, N) on the MXU: two
+    16-bit-half f32 matmuls (≤ 2^16 is exact in f32, one nonzero per
+    column).  Casts go through int32 — Mosaic has no uint32<->f32 cast, and
+    both halves fit int32 exactly."""
+    hi = (x >> 16).astype(jnp.int32).astype(jnp.float32)
+    lo = (x & jnp.uint32(0xFFFF)).astype(jnp.int32).astype(jnp.float32)
+    ghi = jax.lax.dot(hi, oh, precision=jax.lax.Precision.HIGHEST)
+    glo = jax.lax.dot(lo, oh, precision=jax.lax.Precision.HIGHEST)
+    return ((ghi.astype(jnp.int32).astype(jnp.uint32) << 16)
+            | glo.astype(jnp.int32).astype(jnp.uint32))
+
+
+def _best_slot(y: jax.Array, minimize: bool) -> jax.Array:
+    """First-occurrence best lane of a (1, N) fitness row -> int32 (1, 1):
+    the reference argmin/argmax tie rule as a min over a masked iota (the
+    same rule as `islands.best_slot`)."""
+    m = (jnp.min(y, axis=1, keepdims=True) if minimize
+         else jnp.max(y, axis=1, keepdims=True))
+    lane = jax.lax.broadcasted_iota(jnp.int32, y.shape, 1)
+    return jnp.min(jnp.where(y == m, lane, y.shape[1]), axis=1, keepdims=True)
+
+
+def _take_column(x: jax.Array, slot: jax.Array) -> jax.Array:
+    """x[:, slot] of a (V, N) population as a (V, 1) column: a masked int32
+    sum (genes are ≤ 31 bits, so the int32 view is exact and the single
+    nonzero term sums exactly; Mosaic has no unsigned reductions)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    hit = jnp.where(lane == slot, x.astype(jnp.int32), 0)
+    return jnp.sum(hit, axis=1, keepdims=True).astype(jnp.uint32)
+
+
+def _splice(x: jax.Array, slot: jax.Array, col: jax.Array) -> jax.Array:
+    """x with column `slot` replaced by `col` (V, 1) — `islands.splice_at`
+    for one lane-major island."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where(lane == slot, col, x)
+
+
+def _fitness_row(ffm: FfmStage, x: jax.Array) -> jax.Array:
+    """The FFM stage on the (N, V) view of a lane-major island -> (1, N)."""
+    return jnp.asarray(ffm(x.T), jnp.float32)[None, :]
+
+
+def _one_generation(x, sel_in, cross_in, mut_in, *, cfg: GAConfig,
+                    ffm: FfmStage):
+    """One GA generation of one lane-major island.  Returns (x', sel',
+    cross', mut', y) with y (1, N) the fitness of the incoming x."""
+    n, c = cfg.n, cfg.c
+    sel, cross, mut = (_lfsr_draw(b, cfg.steps_per_draw)
+                       for b in (sel_in, cross_in, mut_in))
+    y = _fitness_row(ffm, x)
+
+    # ---- SM: tournaments on the configured selection lane -----------------
+    i1 = (sel[0:1] >> jnp.uint32(32 - cfg.idx_bits)).astype(jnp.int32)
+    i2 = (sel[1:2] >> jnp.uint32(32 - cfg.idx_bits)).astype(jnp.int32)
     if cfg.sel_lane == "gather":
-        return m, jnp.take(x, idx[None], axis=0)[0]          # (V,)
-    oh = (iota == idx).astype(jnp.float32)[None, :]          # (1, N)
-    return m, _onehot_gather_u32(oh, x)[0]                   # (V,)
+        # dynamic-indexing lane: chunked lane gathers, O(N·V) scratch
+        y12 = _lane_take(jnp.concatenate([y, y], axis=0),
+                         jnp.concatenate([i1, i2], axis=0))
+        y1, y2 = y12[0:1], y12[1:2]
+        first_wins = (y1 <= y2) if cfg.minimize else (y1 >= y2)
+        w = _lane_take(x, jnp.where(first_wins, i1, i2))
+    else:
+        # one-hot lane: m[c, r] says contestant c is slot r's draw; the
+        # fitness pick is an exact masked max, the chromosome pick an MXU
+        # contraction
+        rows = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+        m1, m2 = rows == i1, rows == i2
+        y_col = jnp.transpose(y)
+        y1 = jnp.max(jnp.where(m1, y_col, -jnp.inf), axis=0, keepdims=True)
+        y2 = jnp.max(jnp.where(m2, y_col, -jnp.inf), axis=0, keepdims=True)
+        first_wins = (y1 <= y2) if cfg.minimize else (y1 >= y2)
+        wi = jnp.where(first_wins, i1, i2)
+        w = _onehot_gather_u32(x, (rows == wi).astype(jnp.float32))
+
+    # ---- CM: mask-shift single-point crossover, one cut per pair ----------
+    # (the clamp runs in int32: Mosaic has no unsigned min; cut < 2^cut_bits)
+    cut = jnp.minimum((cross >> jnp.uint32(32 - cfg.cut_bits))
+                      .astype(jnp.int32), c).astype(jnp.uint32)
+    s = _pair_expand(jnp.uint32(cfg.var_mask) >> cut)
+    z = (w & ~s) | (_pair_swap(w) & s)
+
+    # ---- MM: XOR-mutate the first P --------------------------------------
+    rbits = mut >> jnp.uint32(32 - c)
+    mutate = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1) < cfg.p
+    return jnp.where(mutate, z ^ rbits, z), sel, cross, mut, y
+
+
+def _island_gens(state, best, *, cfg: GAConfig, ffm: FfmStage, gens: int,
+                 track_best: bool, g0=0):
+    """`gens` generations of one island, folding the running best with the
+    reference scan's strict-improvement + first-occurrence rule.  state is
+    (x, sel, cross, mut); best is (by (1, 1), bx (V, 1), bg (1, 1)), bg
+    the generation (counted from `g0`) the best was first seen in.
+    Returns (state', best', y) with y the fitness of the last pre-update
+    population."""
+    mini = cfg.minimize
+
+    def step(i, carry):
+        x, sel, cross, mut, _y, by, bx, bg = carry
+        x2, sel2, cross2, mut2, y = _one_generation(x, sel, cross, mut,
+                                                    cfg=cfg, ffm=ffm)
+        if track_best:
+            slot = _best_slot(y, mini)                # y scores x
+            gb = (jnp.min(y, axis=1, keepdims=True) if mini
+                  else jnp.max(y, axis=1, keepdims=True))
+            better = gb < by if mini else gb > by
+            by = jnp.where(better, gb, by)
+            bx = jnp.where(better, _take_column(x, slot), bx)
+            bg = jnp.where(better, g0 + i, bg)
+        return x2, sel2, cross2, mut2, y, by, bx, bg
+
+    init = tuple(state) + (jnp.zeros((1, cfg.n), jnp.float32),) + tuple(best)
+    out = (jax.lax.fori_loop(0, gens, step, init) if gens > 1
+           else step(0, init))
+    return out[:4], out[5:], out[4]
+
+
+def _best_init(cfg: GAConfig):
+    by = jnp.full((1, 1), jnp.inf if cfg.minimize else -jnp.inf, jnp.float32)
+    return (by, jnp.zeros((cfg.v, 1), jnp.uint32),
+            jnp.zeros((1, 1), jnp.int32))
+
+
+def _bind_consts(ffm, const_shapes, rest):
+    """Split a kernel's trailing refs into (FFM stage with its hoisted
+    consts bound, output refs)."""
+    n_consts = len(const_shapes)
+    const_refs, out_refs = rest[:n_consts], rest[n_consts:]
+    if not n_consts:
+        return ffm, out_refs
+    consts = [r[0].reshape(s) for r, s in zip(const_refs, const_shapes)]
+    return (lambda x: ffm(x, *consts)), out_refs
+
+
+def _compiler_params(interpret: bool) -> dict:
+    """Every launch hands Mosaic the planner's VMEM budget, so a plan the
+    estimator accepts is compiled under the same limit."""
+    if interpret:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=resident_vmem_budget())}
+
+
+def _to_lanes(x):
+    """(..., N, V) engine layout -> the kernels' (..., V, N)."""
+    return jnp.swapaxes(x, -1, -2)
+
+
+# ---------------------------------------------------------------------------
+# Gridded kernel: one island per grid step
+# ---------------------------------------------------------------------------
 
 
 def _kernel(x_ref, sel_ref, cross_ref, mut_ref,              # inputs
             *rest,                                           # consts + outputs
             cfg: GAConfig, ffm, const_shapes=(), gens: int = 1,
             track_best: bool = False):
-    """One or MANY generations per launch.
+    """One or MANY generations per launch for one island.
 
-    gens > 1 is the VMEM-residency optimization (EXPERIMENTS.md §Perf GA
-    iter 2): the FPGA keeps population + LFSRs in registers between clock
-    beats; we keep them in VMEM between generations, so HBM sees one state
-    read + one write per `gens` generations instead of per generation.
+    gens > 1 is the VMEM-residency optimization: the FPGA keeps population
+    + LFSRs in registers between clock beats; we keep them in VMEM between
+    generations, so HBM sees one state read + one write per `gens`
+    generations instead of per generation.
 
     `rest` leads with one VMEM ref per FFM closure constant (arrays the
-    user's fitness captured, hoisted by `jax.closure_convert` in
-    `ga_generation_kernel` — Pallas kernels cannot capture array constants
-    directly); `const_shapes` restores their original shapes.
+    fitness captured, hoisted by `_hoist_ffm` — Pallas kernels cannot
+    capture array constants directly); `const_shapes` restores their shapes.
 
     track_best=True adds two outputs (best_y, best_x) folding the running
     best individual *inside* the launch with the reference scan's strict
     improvement + first-occurrence tie rule — so a gens>1 launch loses no
     best-tracking fidelity, only per-generation trajectory resolution
     (y_out is the fitness of the LAST pre-update population)."""
-    n_consts = len(const_shapes)
-    const_refs, out_refs = rest[:n_consts], rest[n_consts:]
-    if n_consts:
-        consts = [r[0].reshape(s) for r, s in zip(const_refs, const_shapes)]
-        ffm_stage = lambda x: ffm(x, *consts)
-    else:
-        ffm_stage = ffm
-    if track_best:
-        x_out, sel_out, cross_out, mut_out, y_out, by_out, bx_out = out_refs
-    else:
-        x_out, sel_out, cross_out, mut_out, y_out = out_refs
-
-    def step(carry):
-        x, sel, cross, mut, y = carry[:5]
-        out = _one_generation(x, sel, cross, mut, y, cfg=cfg, ffm=ffm_stage)
-        if track_best:
-            by, bx = carry[5], carry[6]
-            y2 = out[4]
-            gb, gx = _gen_best(x, y2, cfg)   # y2 scores x (pre-update)
-            better = gb < by if cfg.minimize else gb > by
-            out = out + (jnp.where(better, gb, by),
-                         jnp.where(better, gx, bx))
-        return out
-
-    init = (x_ref[0], sel_ref[0], cross_ref[0], mut_ref[0],
-            jnp.zeros((cfg.n,), jnp.float32))
-    if track_best:
-        init = init + (jnp.float32(jnp.inf if cfg.minimize else -jnp.inf),
-                       jnp.zeros((cfg.v,), jnp.uint32))
-    if gens > 1:
-        final = jax.lax.fori_loop(0, gens, lambda _, c: step(c), init)
-    else:
-        final = step(init)
-    x_out[0], sel_out[0], cross_out[0], mut_out[0], y_out[0] = final[:5]
-    if track_best:
-        by_out[0], bx_out[0] = final[5], final[6]
-
-
-def _one_generation(x, sel_in, cross_in, mut_in, _y_prev,
-                    *, cfg: GAConfig, ffm: FfmStage):
-    n, v, c = cfg.n, cfg.v, cfg.c
-    var_mask = jnp.uint32((1 << c) - 1)
-
-    # ---- RNG: ONE fused GF(2) leap clocks all three LFSR banks -----------
-    sel, cross, mut = _lfsr_draw_banks((sel_in, cross_in, mut_in),
-                                       cfg.steps_per_draw)
-
-    # ---- FFM (pluggable traced stage: decode + problem expression, VPU) --
-    y = jnp.asarray(ffm(x), jnp.float32)                  # (N,)
-
-    # ---- SM: tournaments on the configured selection lane -----------------
-    i1 = (sel[0] >> jnp.uint32(32 - cfg.idx_bits)).astype(jnp.int32)
-    i2 = (sel[1] >> jnp.uint32(32 - cfg.idx_bits)).astype(jnp.int32)
-    if cfg.sel_lane == "gather":
-        # dynamic-indexing lane: VPU row gathers, O(N·V) scratch — both
-        # lanes read the same indices and tie rules, so they are
-        # bit-identical (the one-hot matmuls below were already exact)
-        y1 = jnp.take(y, i1, axis=0)
-        y2 = jnp.take(y, i2, axis=0)
-        first_wins = (y1 <= y2) if cfg.minimize else (y1 >= y2)
-        wi = jnp.where(first_wins, i1, i2)                # winner index
-        w = jnp.take(x, wi, axis=0)                       # (N, V)
-    else:
-        # one-hot lane: exact gathers as (N, N) MXU contractions
-        iota = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
-        oh1 = (iota == i1[:, None]).astype(jnp.float32)
-        oh2 = (iota == i2[:, None]).astype(jnp.float32)
-        y1 = jax.lax.dot(oh1, y[:, None],
-                         precision=jax.lax.Precision.HIGHEST)[:, 0]
-        y2 = jax.lax.dot(oh2, y[:, None],
-                         precision=jax.lax.Precision.HIGHEST)[:, 0]
-        first_wins = (y1 <= y2) if cfg.minimize else (y1 >= y2)
-        ohw = jnp.where(first_wins[:, None], oh1, oh2)    # winner one-hot
-        w = _onehot_gather_u32(ohw, x)                    # (N, V)
-
-    # ---- CM: mask-shift single-point crossover ----------------------------
-    cut = (cross >> jnp.uint32(32 - cfg.cut_bits)).astype(jnp.uint32)
-    cut = jnp.minimum(cut, jnp.uint32(c))
-    s = (var_mask >> cut).T                               # (N/2, V)
-    wp = w.reshape(n // 2, 2, v)
-    w1, w2 = wp[:, 0], wp[:, 1]
-    z1 = (w1 & ~s) | (w2 & s)
-    z2 = (w2 & ~s) | (w1 & s)
-    z = jnp.stack([z1, z2], axis=1).reshape(n, v)
-
-    # ---- MM: XOR-mutate the first P --------------------------------------
-    rbits = (mut >> jnp.uint32(32 - c)).T                 # (N, V)
-    mut_row = (jax.lax.broadcasted_iota(jnp.int32, (n, 1), 0) < cfg.p)
-    x_new = jnp.where(mut_row, z ^ rbits, z)
-    return x_new, sel, cross, mut, y
+    ffm_stage, out_refs = _bind_consts(ffm, const_shapes, rest)
+    state = (x_ref[0], sel_ref[0], cross_ref[0], mut_ref[0])
+    state, best, y = _island_gens(state, _best_init(cfg), cfg=cfg,
+                                  ffm=ffm_stage, gens=gens,
+                                  track_best=track_best)
+    for ref, val in zip(out_refs, tuple(state) + (y,)
+                        + (tuple(best[:2]) if track_best else ())):
+        ref[0] = val
 
 
 def ga_generation_kernel(x, sel, cross, mut, *, cfg: GAConfig,
@@ -623,140 +713,182 @@ def ga_generation_kernel(x, sel, cross, mut, *, cfg: GAConfig,
     ffm_conv, const_shapes, flat_consts, const_bytes = _hoist_ffm(ffm, n, v)
     _check_const_gate(const_bytes)
 
-    blk = lambda *shape: pl.BlockSpec((1,) + shape, lambda i: (i,) + (0,) * len(shape))
+    blk = lambda *shape: pl.BlockSpec((1,) + shape,
+                                      lambda i: (i,) + (0,) * len(shape))
     cblk = lambda k: pl.BlockSpec((1, k), lambda i: (0, 0))
-    grid = (i_islands,)
     kernel = functools.partial(_kernel, cfg=cfg, ffm=ffm_conv,
                                const_shapes=const_shapes, gens=gens,
                                track_best=track_best)
-    out_specs = [blk(n, v), blk(2, n), blk(v, n // 2), blk(v, n), blk(n)]
-    out_shape = [
-        jax.ShapeDtypeStruct((i_islands, n, v), jnp.uint32),
-        jax.ShapeDtypeStruct((i_islands, 2, n), jnp.uint32),
-        jax.ShapeDtypeStruct((i_islands, v, n // 2), jnp.uint32),
-        jax.ShapeDtypeStruct((i_islands, v, n), jnp.uint32),
-        jax.ShapeDtypeStruct((i_islands, n), jnp.float32),
-    ]
+    state_blks = [blk(v, n), blk(2, n), blk(v, n // 2), blk(v, n)]
+    shape = lambda *s, dt=jnp.uint32: jax.ShapeDtypeStruct((i_islands,) + s,
+                                                           dt)
+    state_shapes = [shape(v, n), shape(2, n), shape(v, n // 2), shape(v, n)]
+    out_specs = state_blks + [blk(1, n)]
+    out_shape = state_shapes + [shape(1, n, dt=jnp.float32)]
     if track_best:
-        out_specs += [blk(), blk(v)]
-        out_shape += [jax.ShapeDtypeStruct((i_islands,), jnp.float32),
-                      jax.ShapeDtypeStruct((i_islands, v), jnp.uint32)]
-    return pl.pallas_call(
+        out_specs += [blk(1, 1), blk(v, 1)]
+        out_shape += [shape(1, 1, dt=jnp.float32), shape(v, 1)]
+    outs = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[blk(n, v), blk(2, n), blk(v, n // 2), blk(v, n)]
-                 + [cblk(c.shape[1]) for c in flat_consts],
+        grid=(i_islands,),
+        in_specs=state_blks + [cblk(c.shape[1]) for c in flat_consts],
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
-    )(x, sel, cross, mut, *flat_consts)
+        name="ga_generation_kernel",
+        **_compiler_params(interpret),
+    )(_to_lanes(x), sel, cross, mut, *flat_consts)
+    res = (_to_lanes(outs[0]),) + tuple(outs[1:4]) + (outs[4][:, 0],)
+    if track_best:
+        res += (outs[5][:, 0, 0], outs[6][..., 0])
+    return res
 
 
 # ---------------------------------------------------------------------------
-# Resident-epoch kernel: whole island shard in VMEM, migration in the loop
+# Epoch kernel: a block of islands, migration intervals in the loop
 # ---------------------------------------------------------------------------
 
 
 def _epoch_body(x_ref, sel_ref, cross_ref, mut_ref,          # inputs
                 *rest,                                       # consts + outputs
                 cfg: GAConfig, ffm, const_shapes=(),
-                migrate_every: int, intervals: int, boundary: bool,
-                migrate: bool = True):
-    """`intervals × migrate_every` generations + in-VMEM ring migration.
+                migrate_every: int, intervals: int, ring: str):
+    """`intervals × migrate_every` generations of a block of islands.
 
-    The block holds a whole island stack [I, N, V] (the grid axis is the
-    replica axis, not the island axis): generations vmap over the islands,
-    and after every `migrate_every` of them the migration fitness is
-    evaluated in-kernel and `islands.ring_migrate_stack` splices the shifted
-    elites — the same masked-iota/select math the XLA path runs between
-    launches, so state stays bit-identical to reference × island_ring.
+    The block holds an island stack [I, ...] (the whole shard for the
+    resident modes, one tile for the streamed mode).  Each interval steps
+    the islands one at a time through `migrate_every` generations (islands
+    are independent between migrations), evaluates the migration fitness
+    and applies `ring`:
 
-    boundary=True is the sharded variant (intervals == 1): the ring wraps
-    across shards, so the kernel performs only the INTRA-shard part (islands
-    1..I-1 receive elites 0..I-2) and instead of splicing island 0 it
-    outputs (boundary elite of island I-1, worst slot of island 0) for the
-    between-launch `lax.ppermute` + splice.
-
-    migrate=False is the migration-free resident mode (`migration="none"`):
-    the interval loop runs the generations and evaluates the interval
-    fitness but skips `ring_migrate_stack` entirely — no ring means no
-    whole-multiple constraint, so one launch can fold ANY number of
-    generations (callers pass intervals=1, migrate_every=the full fold).
+      * "full"     — the in-VMEM ring migration: island i's pre-splice
+        elite replaces island i+1's worst slot, island I-1's lands on
+        island 0 — the rule set `islands.ring_migrate_stack` runs in XLA.
+      * "boundary" — the sharded variant (intervals == 1): islands 1..I-1
+        receive elites 0..I-2 and the outputs carry (boundary elite of
+        island I-1, worst slot of island 0) for the between-launch
+        `lax.ppermute` + splice.
+      * "emit"     — the streamed variant: no splice; every island's elite
+        and worst slot are outputs, the caller splices in XLA.
+      * "none"     — no migration (`migration="none"`): one interval folds
+        the whole launch.
 
     The per-island running best folds every generation with the reference
-    strict-improvement/first-occurrence rule; the y output is the migration
+    strict-improvement/first-occurrence rule and records the generation it
+    was first seen in; the y output is the migration
     fitness of the final (pre-splice) populations — one trajectory sample
-    per launch.
-    """
-    n_consts = len(const_shapes)
-    const_refs, out_refs = rest[:n_consts], rest[n_consts:]
-    if n_consts:
-        consts = [r[0].reshape(s) for r, s in zip(const_refs, const_shapes)]
-        ffm_stage = lambda x: ffm(x, *consts)
-    else:
-        ffm_stage = ffm
-    x_out, sel_out, cross_out, mut_out, y_out, by_out, bx_out = out_refs[:7]
+    per launch."""
+    ffm_stage, out_refs = _bind_consts(ffm, const_shapes, rest)
+    x_o, sel_o, cross_o, mut_o, y_o, by_o, bx_o, bg_o = out_refs[:8]
+    extra = out_refs[8:]
     mini = cfg.minimize
-    i_islands = x_ref.shape[1]
+    n_isl = x_ref.shape[1]
+    for dst, src in ((x_o, x_ref), (sel_o, sel_ref), (cross_o, cross_ref),
+                     (mut_o, mut_ref)):
+        dst[...] = src[...]
+    for ref, val in zip((by_o, bx_o, bg_o), _best_init(cfg)):
+        ref[...] = jnp.broadcast_to(val, ref.shape)
 
-    vgen = jax.vmap(functools.partial(_one_generation, cfg=cfg,
-                                      ffm=ffm_stage))
-    vfit = jax.vmap(lambda xx: jnp.asarray(ffm_stage(xx), jnp.float32))
+    def island(i, carry, g0):
+        state = (x_o[0, i], sel_o[0, i], cross_o[0, i], mut_o[0, i])
+        (x, sel, cross, mut), (by, bx, bg), _ = _island_gens(
+            state, (by_o[0, i], bx_o[0, i], bg_o[0, i]), cfg=cfg,
+            ffm=ffm_stage, gens=migrate_every, track_best=True, g0=g0)
+        ymig = _fitness_row(ffm_stage, x)
+        sel_o[0, i], cross_o[0, i], mut_o[0, i] = sel, cross, mut
+        by_o[0, i], bx_o[0, i], bg_o[0, i] = by, bx, bg
+        y_o[0, i] = ymig
+        if ring == "none":
+            x_o[0, i] = x
+            return carry
+        elite = _take_column(x, _best_slot(ymig, mini))
+        worst = _best_slot(ymig, not mini)
+        if ring == "emit":
+            x_o[0, i] = x
+            extra[0][0, i], extra[1][0, i] = elite, worst
+            return carry
+        # island i takes island i-1's pre-splice elite; island 0's arrives
+        # after the loop (ring="full") or from the previous shard ("boundary")
+        prev_elite, worst0 = carry
+        x_o[0, i] = jnp.where(i > 0, _splice(x, worst, prev_elite), x)
+        return elite, jnp.where(i == 0, worst, worst0)
 
-    def gen_step(carry):
-        x, sel, cross, mut, y, by, bx = carry
-        x2, sel2, cross2, mut2, y2 = vgen(x, sel, cross, mut, y)
-        gx, gb = ISL.elites_stack(x, y2, minimize=mini)  # y2 scores x
-        better = gb < by if mini else gb > by
-        by = jnp.where(better, gb, by)
-        bx = jnp.where(better[:, None], gx, bx)
-        return (x2, sel2, cross2, mut2, y2, by, bx)
+    def interval(k, carry):
+        last_elite, worst0 = jax.lax.fori_loop(
+            0, n_isl, functools.partial(island, g0=k * migrate_every), carry)
+        if ring == "full":
+            x_o[0, 0] = _splice(x_o[0, 0], worst0, last_elite)
+        return last_elite, worst0
 
-    def block(carry):
-        """One migration interval's generations + the migration fitness."""
-        carry = jax.lax.fori_loop(0, migrate_every,
-                                  lambda _, c: gen_step(c), carry)
-        x = carry[0]
-        return carry, vfit(x)                            # scores final pops
+    carry = (jnp.zeros((cfg.v, 1), jnp.uint32), jnp.zeros((1, 1), jnp.int32))
+    carry = (jax.lax.fori_loop(0, intervals, interval, carry)
+             if intervals > 1 else interval(0, carry))
+    if ring == "boundary":
+        extra[0][0], extra[1][0] = carry
 
-    init = (x_ref[0], sel_ref[0], cross_ref[0], mut_ref[0],
-            jnp.zeros((i_islands, cfg.n), jnp.float32),
-            jnp.full((i_islands,), jnp.inf if mini else -jnp.inf,
-                     jnp.float32),
-            jnp.zeros((i_islands, cfg.v), jnp.uint32))
 
-    if boundary:
-        send_out, w0_out = out_refs[7:]
-        carry, ymig = block(init)
-        x, sel, cross, mut, _y, by, bx = carry
-        elite_x, _elite_y = ISL.elites_stack(x, ymig, minimize=mini)
-        widx = ISL.worst_slot(ymig, minimize=mini)
-        # islands 1..I-1 take elites 0..I-2; island 0 waits for the ppermute
-        shifted = jnp.concatenate([elite_x[:1], elite_x[:-1]], axis=0)
-        not_first = (jax.lax.broadcasted_iota(jnp.int32, (i_islands, 1), 0)
-                     >= 1)
-        x = ISL.splice_at(x, widx, shifted, island_mask=not_first)
-        send_out[0], w0_out[0] = elite_x[-1], widx[0]
-    else:
-        def interval(_, carry):
-            carry, ymig = block(carry)
-            x, sel, cross, mut, _y, by, bx = carry
-            if migrate:
-                x, _ex, _ey = ISL.ring_migrate_stack(x, ymig, minimize=mini)
-            return (x, sel, cross, mut, ymig, by, bx)
+def _epoch_call(x, sel, cross, mut, *, cfg: GAConfig, ffm: FfmStage,
+                migrate_every: int, intervals: int, ring: str,
+                tile_islands: int, interpret: bool, name: str):
+    """Launch `_epoch_body` over a (G, I, ...) replica-group stack with
+    `tile_islands` islands per block (grid (G, I // tile_islands)) and
+    return the outputs in the engine's layout."""
+    g_grid, i_islands, n, v = x.shape
+    assert (n, v) == (cfg.n, cfg.v)
+    ffm_conv, const_shapes, flat_consts, const_bytes = _hoist_ffm(ffm, n, v)
+    _check_const_gate(const_bytes)
+    t = tile_islands
 
-        x, sel, cross, mut, ymig, by, bx = jax.lax.fori_loop(
-            0, intervals, interval, init)
+    def blk(*shape, per_island=True):
+        lead = (1, t) if per_island else (1,)
+        if per_island:
+            return pl.BlockSpec(lead + shape,
+                                lambda g, j: (g, j) + (0,) * len(shape))
+        return pl.BlockSpec(lead + shape, lambda g, j: (g,) + (0,) * len(shape))
 
-    x_out[0], sel_out[0], cross_out[0], mut_out[0] = x, sel, cross, mut
-    y_out[0], by_out[0], bx_out[0] = ymig, by, bx
+    shape = lambda *s, dt=jnp.uint32: jax.ShapeDtypeStruct(
+        (g_grid, i_islands) + s, dt)
+    cblk = lambda k: pl.BlockSpec((1, k), lambda g, j: (0, 0))
+    state_blks = [blk(v, n), blk(2, n), blk(v, n // 2), blk(v, n)]
+    out_specs = state_blks + [blk(1, n), blk(1, 1), blk(v, 1), blk(1, 1)]
+    out_shape = [shape(v, n), shape(2, n), shape(v, n // 2), shape(v, n),
+                 shape(1, n, dt=jnp.float32), shape(1, 1, dt=jnp.float32),
+                 shape(v, 1), shape(1, 1, dt=jnp.int32)]
+    if ring == "emit":
+        out_specs += [blk(v, 1), blk(1, 1)]
+        out_shape += [shape(v, 1), shape(1, 1, dt=jnp.int32)]
+    elif ring == "boundary":
+        out_specs += [blk(v, 1, per_island=False),
+                      blk(1, 1, per_island=False)]
+        out_shape += [jax.ShapeDtypeStruct((g_grid, v, 1), jnp.uint32),
+                      jax.ShapeDtypeStruct((g_grid, 1, 1), jnp.int32)]
+    kernel = functools.partial(_epoch_body, cfg=cfg, ffm=ffm_conv,
+                               const_shapes=const_shapes,
+                               migrate_every=migrate_every,
+                               intervals=intervals, ring=ring)
+    outs = pl.pallas_call(
+        kernel,
+        grid=(g_grid, i_islands // t),
+        in_specs=state_blks + [cblk(c.shape[1]) for c in flat_consts],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        interpret=interpret,
+        name=name,
+        **_compiler_params(interpret),
+    )(_to_lanes(x), sel, cross, mut, *flat_consts)
+    res = (_to_lanes(outs[0]),) + tuple(outs[1:4]) + (
+        outs[4][..., 0, :], outs[5][..., 0, 0], outs[6][..., 0])
+    if ring == "emit":
+        res += (outs[8][..., 0], outs[9][..., 0, 0])
+    elif ring == "boundary":
+        res += (outs[8][..., 0], outs[9][:, 0, 0])
+    return res + (outs[7][..., 0, 0],)
 
 
 def ga_epoch_kernel(x, sel, cross, mut, *, cfg: GAConfig, ffm: FfmStage,
                     migrate_every: int, intervals: int = 1,
                     boundary: bool = False, migrate: bool = True,
-                    interpret: bool = False, vmem_limit_bytes: int = None
-                    ) -> Tuple[jax.Array, ...]:
+                    interpret: bool = False) -> Tuple[jax.Array, ...]:
     """Launch the resident-epoch kernel over replica-stacked island shards.
 
     x: uint32[G, I, N, V]; sel: uint32[G, I, 2, N]; cross: uint32[G, I, V,
@@ -768,18 +900,17 @@ def ga_epoch_kernel(x, sel, cross, mut, *, cfg: GAConfig, ffm: FfmStage,
 
     Returns (x', sel', cross', mut', y[G, I, N], best_y[G, I],
     best_x[G, I, V]) — y is the final migration fitness (pre-splice) —
-    plus (send_elite[G, V], worst0[G]) when boundary=True.
+    plus (send_elite[G, V], worst0[G]) when boundary=True, and last
+    best_gen[G, I]: the launch generation each island's best was first
+    seen in (the engine's cross-island tie rule needs it).
 
     migrate=False (migration-free resident mode) skips the in-loop ring
     splice; pass the full generation fold as `migrate_every` with
-    intervals=1.  vmem_limit_bytes threads a
-    `pltpu.CompilerParams(vmem_limit_bytes=...)` into the launch on real
-    TPU lowerings (ignored in interpret mode) — `resident_compiler_check`
-    uses it to make the compiler referee the byte estimator.
+    intervals=1.
 
     Callers should consult `resident_fit_reason` first; this function
-    asserts the budget (and the hoisted-const gate) rather than silently
-    overflowing VMEM.
+    raises on a stack over the budget (and on the hoisted-const gate)
+    rather than handing Mosaic a block it would refuse.
     """
     check_kernel_lane(cfg)
     assert intervals >= 1 and migrate_every >= 1
@@ -788,131 +919,21 @@ def ga_epoch_kernel(x, sel, cross, mut, *, cfg: GAConfig, ffm: FfmStage,
         "migration interval per launch"
     assert migrate or not boundary, \
         "boundary epochs exist to exchange elites: migrate=False has none"
-    g_grid, i_islands, n, v = x.shape
-    assert (n, v) == (cfg.n, cfg.v)
-
-    ffm_conv, const_shapes, flat_consts, const_bytes = _hoist_ffm(ffm, n, v)
-    _check_const_gate(const_bytes)
-    reason = resident_fit_reason(cfg, i_islands, const_bytes)
+    i_islands = x.shape[1]
+    reason = resident_fit_reason(cfg, i_islands, ffm_const_bytes(ffm, cfg))
     if reason is not None:
         raise ValueError(reason)
-
-    blk = lambda *shape: pl.BlockSpec((1,) + shape,
-                                      lambda i: (i,) + (0,) * len(shape))
-    cblk = lambda k: pl.BlockSpec((1, k), lambda i: (0, 0))
-    kernel = functools.partial(_epoch_body, cfg=cfg, ffm=ffm_conv,
-                               const_shapes=const_shapes,
-                               migrate_every=migrate_every,
-                               intervals=intervals, boundary=boundary,
-                               migrate=migrate)
-    state_blks = [blk(i_islands, n, v), blk(i_islands, 2, n),
-                  blk(i_islands, v, n // 2), blk(i_islands, v, n)]
-    state_shapes = [
-        jax.ShapeDtypeStruct((g_grid, i_islands, n, v), jnp.uint32),
-        jax.ShapeDtypeStruct((g_grid, i_islands, 2, n), jnp.uint32),
-        jax.ShapeDtypeStruct((g_grid, i_islands, v, n // 2), jnp.uint32),
-        jax.ShapeDtypeStruct((g_grid, i_islands, v, n), jnp.uint32),
-    ]
-    out_specs = state_blks + [blk(i_islands, n), blk(i_islands),
-                              blk(i_islands, v)]
-    out_shape = state_shapes + [
-        jax.ShapeDtypeStruct((g_grid, i_islands, n), jnp.float32),
-        jax.ShapeDtypeStruct((g_grid, i_islands), jnp.float32),
-        jax.ShapeDtypeStruct((g_grid, i_islands, v), jnp.uint32),
-    ]
-    if boundary:
-        out_specs += [blk(v), blk()]
-        out_shape += [jax.ShapeDtypeStruct((g_grid, v), jnp.uint32),
-                      jax.ShapeDtypeStruct((g_grid,), jnp.int32)]
-    call_kwargs = {}
-    if vmem_limit_bytes is not None and not interpret:
-        from jax.experimental.pallas import tpu as pltpu
-        params_cls = (getattr(pltpu, "CompilerParams", None)
-                      or getattr(pltpu, "TPUCompilerParams"))
-        call_kwargs["compiler_params"] = params_cls(
-            vmem_limit_bytes=int(vmem_limit_bytes))
-    return pl.pallas_call(
-        kernel,
-        grid=(g_grid,),
-        in_specs=state_blks + [cblk(c.shape[1]) for c in flat_consts],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-        **call_kwargs,
-    )(x, sel, cross, mut, *flat_consts)
-
-
-# ---------------------------------------------------------------------------
-# Streamed-epoch kernel: HBM→VMEM island tiles through the grid pipeline
-# ---------------------------------------------------------------------------
-
-
-def _streamed_body(x_ref, sel_ref, cross_ref, mut_ref,       # inputs
-                   *rest,                                    # consts + outputs
-                   cfg: GAConfig, ffm, const_shapes=(),
-                   migrate_every: int, migrate: bool = True):
-    """One migration interval for ONE island tile of a streamed epoch.
-
-    The block holds `tile_islands` islands — a slice of the island axis, not
-    the whole stack — so the working set is bounded by the tile, not the
-    population.  Each tile runs `migrate_every` vmapped generations with the
-    per-generation best fold (identical math to `_epoch_body`), evaluates
-    the migration fitness in-kernel, and — when a ring runs — emits the
-    per-island elites and worst slots so the caller can splice the shifted
-    elites in XLA between kernel passes.  The outputs are therefore
-    PRE-splice; `elites_stack`/`worst_slot` in here and `splice_at` outside
-    are the same rule set `ring_migrate_stack` composes, so the streamed
-    interval stays bit-identical to the resident and gridded plans."""
-    n_consts = len(const_shapes)
-    const_refs, out_refs = rest[:n_consts], rest[n_consts:]
-    if n_consts:
-        consts = [r[0].reshape(s) for r, s in zip(const_refs, const_shapes)]
-        ffm_stage = lambda x: ffm(x, *consts)
-    else:
-        ffm_stage = ffm
-    if migrate:
-        (x_out, sel_out, cross_out, mut_out, y_out, by_out, bx_out,
-         ex_out, w_out) = out_refs
-    else:
-        x_out, sel_out, cross_out, mut_out, y_out, by_out, bx_out = out_refs
-    mini = cfg.minimize
-    t_islands = x_ref.shape[1]
-
-    vgen = jax.vmap(functools.partial(_one_generation, cfg=cfg,
-                                      ffm=ffm_stage))
-    vfit = jax.vmap(lambda xx: jnp.asarray(ffm_stage(xx), jnp.float32))
-
-    def gen_step(carry):
-        x, sel, cross, mut, y, by, bx = carry
-        x2, sel2, cross2, mut2, y2 = vgen(x, sel, cross, mut, y)
-        gx, gb = ISL.elites_stack(x, y2, minimize=mini)   # y2 scores x
-        better = gb < by if mini else gb > by
-        by = jnp.where(better, gb, by)
-        bx = jnp.where(better[:, None], gx, bx)
-        return (x2, sel2, cross2, mut2, y2, by, bx)
-
-    init = (x_ref[0], sel_ref[0], cross_ref[0], mut_ref[0],
-            jnp.zeros((t_islands, cfg.n), jnp.float32),
-            jnp.full((t_islands,), jnp.inf if mini else -jnp.inf,
-                     jnp.float32),
-            jnp.zeros((t_islands, cfg.v), jnp.uint32))
-    carry = jax.lax.fori_loop(0, migrate_every, lambda _, c: gen_step(c),
-                              init)
-    x, sel, cross, mut, _y, by, bx = carry
-    ymig = vfit(x)                                        # scores final pops
-    x_out[0], sel_out[0], cross_out[0], mut_out[0] = x, sel, cross, mut
-    y_out[0], by_out[0], bx_out[0] = ymig, by, bx
-    if migrate:
-        elite_x, _elite_y = ISL.elites_stack(x, ymig, minimize=mini)
-        ex_out[0] = elite_x
-        w_out[0] = ISL.worst_slot(ymig, minimize=mini)
+    ring = "boundary" if boundary else ("full" if migrate else "none")
+    return _epoch_call(x, sel, cross, mut, cfg=cfg, ffm=ffm,
+                       migrate_every=migrate_every, intervals=intervals,
+                       ring=ring, tile_islands=i_islands,
+                       interpret=interpret, name="ga_epoch_kernel")
 
 
 def ga_streamed_epoch_kernel(x, sel, cross, mut, *, cfg: GAConfig,
                              ffm: FfmStage, migrate_every: int,
                              tile_islands: int, migrate: bool = True,
                              interpret: bool = False,
-                             vmem_limit_bytes: int = None
                              ) -> Tuple[jax.Array, ...]:
     """One migration interval streamed through VMEM in island tiles.
 
@@ -925,7 +946,8 @@ def ga_streamed_epoch_kernel(x, sel, cross, mut, *, cfg: GAConfig,
 
     Returns (x', sel', cross', mut', y[G, I, N], best_y[G, I],
     best_x[G, I, V]) plus, when migrate=True, (elite_x[G, I, V],
-    worst_idx[G, I]) — the PRE-splice migration ingredients.  The caller
+    worst_idx[G, I]) — the PRE-splice migration ingredients — and last
+    best_gen[G, I] as in `ga_epoch_kernel`.  The caller
     owns the ring: shift the elites by one island (`ppermute` across shards
     at the boundary) and `islands.splice_at` the worst slots in XLA, then
     feed the spliced state to the next interval's kernel pass (see
@@ -934,65 +956,24 @@ def ga_streamed_epoch_kernel(x, sel, cross, mut, *, cfg: GAConfig,
     skips the splice.
 
     Callers should consult `streamed_tile_islands` first; this function
-    raises on a tile whose double-buffered working set exceeds the REAL
-    budget (env-derived — a planner-forced smaller budget never makes a
-    legitimate tile illegal here).
+    raises on a tile over the REAL budget (env-derived — a planner-forced
+    smaller budget never makes a legitimate tile illegal here).
     """
     check_kernel_lane(cfg)
     assert migrate_every >= 1 and tile_islands >= 1
-    g_grid, i_islands, n, v = x.shape
-    assert (n, v) == (cfg.n, cfg.v)
+    i_islands = x.shape[1]
     assert i_islands % tile_islands == 0, \
         f"tile_islands={tile_islands} must divide the island count {i_islands}"
-
-    ffm_conv, const_shapes, flat_consts, const_bytes = _hoist_ffm(ffm, n, v)
-    _check_const_gate(const_bytes)
-    need = 2 * resident_vmem_bytes(cfg, tile_islands, const_bytes)
+    need = 2 * resident_vmem_bytes(cfg, tile_islands,
+                                   ffm_const_bytes(ffm, cfg))
     real_budget = resident_vmem_budget()
     if need > real_budget:
         raise ValueError(
             f"streamed tile of {tile_islands} island(s) at N={cfg.n} needs "
-            f"~{need} B of VMEM double-buffered (> budget {real_budget} B); "
-            "use streamed_tile_islands to size the tile")
-
-    blk = lambda *shape: pl.BlockSpec(
-        (1, tile_islands) + shape,
-        lambda g, t: (g, t) + (0,) * len(shape))
-    cblk = lambda k: pl.BlockSpec((1, k), lambda g, t: (0, 0))
-    kernel = functools.partial(_streamed_body, cfg=cfg, ffm=ffm_conv,
-                               const_shapes=const_shapes,
-                               migrate_every=migrate_every, migrate=migrate)
-    state_blks = [blk(n, v), blk(2, n), blk(v, n // 2), blk(v, n)]
-    state_shapes = [
-        jax.ShapeDtypeStruct((g_grid, i_islands, n, v), jnp.uint32),
-        jax.ShapeDtypeStruct((g_grid, i_islands, 2, n), jnp.uint32),
-        jax.ShapeDtypeStruct((g_grid, i_islands, v, n // 2), jnp.uint32),
-        jax.ShapeDtypeStruct((g_grid, i_islands, v, n), jnp.uint32),
-    ]
-    out_specs = state_blks + [blk(n), blk(), blk(v)]
-    out_shape = state_shapes + [
-        jax.ShapeDtypeStruct((g_grid, i_islands, n), jnp.float32),
-        jax.ShapeDtypeStruct((g_grid, i_islands), jnp.float32),
-        jax.ShapeDtypeStruct((g_grid, i_islands, v), jnp.uint32),
-    ]
-    if migrate:
-        out_specs += [blk(v), blk()]
-        out_shape += [jax.ShapeDtypeStruct((g_grid, i_islands, v),
-                                           jnp.uint32),
-                      jax.ShapeDtypeStruct((g_grid, i_islands), jnp.int32)]
-    call_kwargs = {}
-    if vmem_limit_bytes is not None and not interpret:
-        from jax.experimental.pallas import tpu as pltpu
-        params_cls = (getattr(pltpu, "CompilerParams", None)
-                      or getattr(pltpu, "TPUCompilerParams"))
-        call_kwargs["compiler_params"] = params_cls(
-            vmem_limit_bytes=int(vmem_limit_bytes))
-    return pl.pallas_call(
-        kernel,
-        grid=(g_grid, i_islands // tile_islands),
-        in_specs=state_blks + [cblk(c.shape[1]) for c in flat_consts],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=interpret,
-        **call_kwargs,
-    )(x, sel, cross, mut, *flat_consts)
+            f"~{need} B of VMEM (> budget {real_budget} B); use "
+            "streamed_tile_islands to size the tile")
+    return _epoch_call(x, sel, cross, mut, cfg=cfg, ffm=ffm,
+                       migrate_every=migrate_every, intervals=1,
+                       ring="emit" if migrate else "none",
+                       tile_islands=tile_islands, interpret=interpret,
+                       name="ga_streamed_epoch_kernel")

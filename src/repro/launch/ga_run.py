@@ -83,6 +83,8 @@ def main():
     from repro.ga.options import EngineOptions
     EngineOptions.add_cli_args(ap)   # --cost-table/--plan-override/--vmem-...
     args = ap.parse_args()
+    from repro.launch.jax_cache import enable_persistent_cache
+    enable_persistent_cache()
 
     from repro import ga
     from repro.core import fitness as F

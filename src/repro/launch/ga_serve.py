@@ -90,6 +90,8 @@ def main():
     from repro.ga.options import EngineOptions
     EngineOptions.add_cli_args(ap)   # --cost-table/--plan-override/--vmem-...
     args = ap.parse_args()
+    from repro.launch.jax_cache import enable_persistent_cache
+    enable_persistent_cache()
 
     if args.jobs is not None and args.demo > 0:
         ap.error("use only one of --jobs FILE or --demo K")

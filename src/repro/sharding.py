@@ -30,27 +30,12 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:                                     # jax >= 0.6: promoted to jax core
-    from jax import shard_map as _shard_map
-except ImportError:                      # jax 0.4/0.5
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
 def shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """Version-compat `shard_map` with replication checking disabled.
-
-    Every shard_map in this repo wraps bodies the checker cannot analyze
-    (Pallas calls, ppermute cascades), so the check is always off; the
-    disabling kwarg was renamed across jax releases (check_rep ->
-    check_vma), hence this single compat point.
-    """
-    for kw in ({"check_rep": False}, {"check_vma": False}, {}):
-        try:
-            return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
-        except TypeError:
-            continue
-    raise RuntimeError("no compatible shard_map signature found")
+    """`jax.shard_map` with the replication check off: every shard_map in
+    this repo wraps bodies the checker cannot analyze (Pallas calls,
+    ppermute cascades)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_rules(mesh: Optional[Mesh], fsdp: bool = True) -> dict:

@@ -177,7 +177,7 @@ def test_ga_epoch_kernel_matches_local_step_oracle():
     for _ in range(3):
         oracle, _ex, _ey = epoch(oracle)
 
-    x, sel, cross, mut, y, by, bx = ops.ga_epoch(
+    x, sel, cross, mut, y, by, bx, bg = ops.ga_epoch(
         states.x[None], states.sel_lfsr[None], states.cross_lfsr[None],
         states.mut_lfsr[None], cfg=cfg, ffm=ffm, migrate_every=5,
         intervals=3)
@@ -190,6 +190,7 @@ def test_ga_epoch_kernel_matches_local_step_oracle():
                                   np.asarray(oracle.mut_lfsr))
     assert by.shape == (1, 4) and bx.shape == (1, 4, 2)
     assert y.shape == (1, 4, cfg.n)
+    assert bg.shape == (1, 4) and 0 <= int(bg.min()) <= int(bg.max()) < 15
 
 
 def test_ga_epoch_kernel_boundary_is_partial_ring():

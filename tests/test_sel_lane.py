@@ -174,7 +174,10 @@ def test_gather_lane_shrinks_the_vmem_estimate():
     on = K.resident_vmem_bytes(dataclasses.replace(cfg, sel_lane="onehot"), 1)
     ga_b = K.resident_vmem_bytes(dataclasses.replace(cfg, sel_lane="gather"),
                                  1)
-    assert ga_b < on / 10     # 4·4·N² vs 4·6·N of selection scratch
+    # the onehot lane's four (N, N) masks/one-hots are gone; what remains
+    # is O(N·V): state, the FFM stage's (N, V) temporaries, gathered rows
+    assert on - ga_b >= 3 * 4 * 512 * 512
+    assert ga_b < on / 2
 
 
 # ---------------------------------------------------------------------------

@@ -25,10 +25,14 @@ def _spec(**kw):
     return ga.GASpec(**base)
 
 
-def _budget(spec, islands):
-    """A planning budget sized to `islands` resident islands of this spec —
-    under the full stack, so the streamed lane engages."""
-    return K.resident_vmem_bytes(spec.ga_config(), islands)
+def _budget(spec, tile=2):
+    """A planning budget that holds a streamed tile of `tile` islands of
+    this spec (the tile rule's 2x margin) but not the full 8-island stack,
+    so the streamed lane engages with that tile."""
+    cfg = spec.ga_config()
+    budget = 2 * K.resident_vmem_bytes(cfg, tile)
+    assert budget < K.resident_vmem_bytes(cfg, spec.n_islands)
+    return budget
 
 
 # ---------------------------------------------------------------------------
@@ -69,7 +73,7 @@ def test_migration_none_keeps_gridded_heuristic():
     cfg = _spec().ga_config()
     cands = K.epoch_mode_candidates(
         cfg, 8, executor="fused", migration="none", gens_per_epoch=16,
-        migrate_every=4, sharded=False, budget=_budget(_spec(), 5))
+        migrate_every=4, sharded=False, budget=_budget(_spec()))
     assert [c["mode"] for c in cands] == ["gridded", "streamed"]
 
 
@@ -95,7 +99,7 @@ def test_streamed_bit_identical_to_islands_reference(problem):
     final population and all three LFSR banks bit-equal the `islands`
     reference backend after 16 generations (4 ring migrations)."""
     spec = _spec(problem=problem)
-    opts = ga.EngineOptions(cost_table=False, vmem_budget=_budget(spec, 5))
+    opts = ga.EngineOptions(cost_table=False, vmem_budget=_budget(spec))
     eng = ga.Engine(spec, "fused-islands", options=opts)
     plan = eng.backend.topology.plan
     assert plan["mode"] == "streamed" and plan["tile_islands"] == 2, plan
@@ -108,10 +112,10 @@ def test_streamed_bit_identical_to_islands_reference(problem):
                                       np.asarray(getattr(seg_r.state, field)),
                                       err_msg=field)
     assert seg_s.best_y == seg_r.best_y
-    # the reported best chromosome must match the RESIDENT lane bit-for-bit
-    # (the fused lanes fold per-island bests island-major, so on an exact
-    # fitness tie they may surface a different equally-fit chromosome than
-    # the gen-major reference fold — a pre-existing fused-lane property)
+    # the best chromosome too: on an exact fitness tie every plan picks the
+    # reference's (first interval, then first island)
+    np.testing.assert_array_equal(np.asarray(seg_s.best_x),
+                                  np.asarray(seg_r.best_x))
     res = ga.Engine(spec, "fused-islands",
                     options=ga.EngineOptions(cost_table=False))
     assert res.backend.topology.plan["mode"] == "resident"
@@ -125,7 +129,7 @@ def test_pinned_tile_is_a_launch_shape_knob_only():
     pins (non-divisor, too big to double-buffer) are rejected with the
     byte math."""
     spec = _spec()
-    budget = _budget(spec, 5)
+    budget = _budget(spec)
     base = ga.solve(spec, backend="fused-islands",
                     options=ga.EngineOptions(cost_table=False,
                                              vmem_budget=budget))
@@ -151,7 +155,7 @@ def test_streamed_migration_none_bit_identical_via_override():
     """The isolated-islands ablation through the streamed lane (forced —
     gridded is its heuristic) matches the gridded run bit-for-bit."""
     spec = _spec(migration="none", generations=16, gens_per_epoch=16)
-    budget = _budget(spec, 5)
+    budget = _budget(spec)
     res = ga.solve(spec, backend="fused-islands",
                    options=ga.EngineOptions(cost_table=False,
                                             vmem_budget=budget,
@@ -184,7 +188,7 @@ mesh = jax.make_mesh((8,), ("islands",))
 spec = ga.GASpec(problem="F3", n=16, bits_per_var=8, mode="arith",
                  mutation_rate=0.02, seed=2, generations=16,
                  n_islands=32, migrate_every=4, gens_per_epoch=8)
-budget = K.resident_vmem_bytes(spec.ga_config(), 3)
+budget = 2 * K.resident_vmem_bytes(spec.ga_config(), 1)
 eng = ga.Engine(spec, "fused-islands",
                 options=ga.EngineOptions(mesh=mesh, cost_table=False,
                                          vmem_budget=budget))
@@ -214,19 +218,20 @@ print("STREAMED_SHARDED_OK", seg_s.best_y)
 # ---------------------------------------------------------------------------
 
 
-def test_fused_bank_leap_matches_per_bank_leaps():
-    """`_lfsr_draw_banks` (one GF(2) leap over the concatenated register
-    file) is bit-identical per element to leaping each bank alone."""
+def test_kernel_lfsr_leap_matches_clocked_banks():
+    """`_lfsr_draw` (the in-kernel GF(2) leap, run on each LFSR bank) is
+    bit-identical to clocking the same bank `steps` times one by one."""
+    from repro.core import lfsr
     rng = np.random.default_rng(0)
     import jax.numpy as jnp
     banks = tuple(jnp.asarray(rng.integers(1, 1 << 32, size=s,
                                            dtype=np.uint32))
                   for s in ((2, 16), (3, 8), (5,)))
     for steps in (1, 3, 17, 45):
-        fused = K._lfsr_draw_banks(banks, steps)
-        for got, bank in zip(fused, banks):
+        for bank in banks:
             np.testing.assert_array_equal(
-                np.asarray(got), np.asarray(K._lfsr_draw(bank, steps)),
+                np.asarray(K._lfsr_draw(bank, steps)),
+                np.asarray(lfsr.steps(bank, steps)),
                 err_msg=f"steps={steps}")
 
 

@@ -114,3 +114,26 @@ def test_serving_engine_end_to_end():
     assert toks.shape == (2, 8)
     assert (toks >= 0).all() and (toks < cfg.vocab_).all()
     assert stats["decode_tok_per_s"] > 0
+
+
+def test_persistent_cache_location(monkeypatch):
+    """`JAX_COMPILATION_CACHE_DIR` wins untouched; without it the cache sits
+    at the fixed `<checkout>/.jax_cache`."""
+    import jax
+    from repro.launch import jax_cache
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert jax_cache.enable_persistent_cache() == "/elsewhere/cache"
+        assert {k: getattr(jax.config, k) for k in keys} == saved
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = jax_cache.enable_persistent_cache()
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)

@@ -267,7 +267,7 @@ def test_resident_sharded_epoch_on_one_device_mesh():
 
 def test_resident_vmem_budget_fallback_decision():
     """The VMEM-budget estimator drives the fallback: an island stack whose
-    one-hot working set exceeds the budget reverts to the STREAMED lane when
+    working set exceeds the budget reverts to the STREAMED lane when
     a double-buffered tile fits, and all the way to the gridded per-interval
     kernel when none does (still bit-identical), never errors."""
     from repro.kernels import ga_step as K
@@ -279,14 +279,19 @@ def test_resident_vmem_budget_fallback_decision():
     assert reason is not None and "VMEM" in reason
     # big captured consts count against the same budget
     assert K.resident_fit_reason(cfg, 4, 1 << 30) is not None
-    # estimator scales with the one-hot term: N=512 x 4 islands > 16 MiB —
-    # but a double-buffered 1-island tile fits, so the HBM-streaming lane
-    # absorbs the oversize case instead of dropping kernel residency
-    big = _spec(n=512, gens_per_epoch=10)
+    # the island blocks scale with the stack, one island's temporaries do
+    # not: a budget that holds a 1-island streamed tile but not the
+    # 16-island stack lets the HBM-streaming lane absorb the oversize case
+    # instead of dropping kernel residency
+    big = _spec(n=64, n_islands=16, gens_per_epoch=10)
+    big_cfg = big.ga_config()
+    budget = 2 * K.resident_vmem_bytes(big_cfg, 1)
+    assert K.resident_fit_reason(big_cfg, 16, 0, budget=budget) is not None
     eng = ga.Engine(big, "fused-islands", options=ga.EngineOptions(
-        cost_table=False))
+        cost_table=False, vmem_budget=budget))
     plan = eng.backend.topology.plan
     assert plan["mode"] == "streamed" and "VMEM" in plan["fallback"]
+    assert plan["tile_islands"] == 1
     # with a budget too small for even a double-buffered 1-island tile the
     # planner still reverts to gridded
     eng_g = ga.Engine(big, "fused-islands", options=ga.EngineOptions(
@@ -620,3 +625,26 @@ def test_metrics_http_endpoint_scrapes_prometheus_text():
         assert render_prometheus(reg.metrics()) == txt
     finally:
         server.shutdown()
+
+
+@pytest.mark.parametrize("plan", ["resident", "streamed"])
+def test_multi_interval_launch_keeps_reference_best_tie_rule(plan):
+    """rastrigin is symmetric, so mirrored chromosomes tie on fitness (here
+    genes 15 and 16 of 5 bits).  A launch folding several migration
+    intervals must still report the chromosome the reference picks: the
+    first interval the best shows up in, then the first island — not the
+    first island over the whole launch."""
+    from repro.kernels import ga_step as K
+    spec = _spec(problem="rastrigin:2", bits_per_var=5, n=16, n_islands=8,
+                 migrate_every=2, gens_per_epoch=8, generations=16, seed=5)
+    opts = dict(cost_table=False)
+    if plan == "streamed":
+        opts["vmem_budget"] = 2 * K.resident_vmem_bytes(spec.ga_config(), 2)
+    eng = ga.Engine(spec, "fused-islands", options=ga.EngineOptions(**opts))
+    assert eng.backend.topology.plan["mode"] == plan
+    seg_f = eng.backend.segment(eng.init_state(), 16)
+    seg_r = _segment(dataclasses.replace(spec, gens_per_epoch=1), "islands",
+                     16)
+    assert seg_f.best_y == seg_r.best_y
+    np.testing.assert_array_equal(np.asarray(seg_f.best_x),
+                                  np.asarray(seg_r.best_x))
