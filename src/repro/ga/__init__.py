@@ -52,8 +52,7 @@ Execution knobs (mesh, interpret, cost table, plan override, the streamed
 mode's tile/budget) ride in one frozen :class:`EngineOptions` shared by
 `Engine`, `PackedEngine`, `GAScheduler` and the CLIs; how a run executed
 comes back as typed :class:`RunTelemetry` (``result.telemetry.plan`` /
-``.topology`` / ``.per_repeat``) — the old ``result.extras`` dict is a
-deprecated view.
+``.topology`` / ``.per_repeat``).
 
 Operator stages are pluggable protocols with registries
 (`ga.SELECTION` / `ga.CROSSOVER` / `ga.MUTATION`; see

@@ -89,7 +89,7 @@ class Segment:
     traj arrays have one entry per generation, except island_ring topologies
     where the unit is one migration epoch (`migrate_every` generations —
     see telemetry.topology.telemetry_unit_gens).  `telemetry` is the typed
-    run telemetry (ga.RunTelemetry); `.extras` is its deprecated dict view.
+    run telemetry (ga.RunTelemetry).
     """
 
     state: Any
@@ -100,11 +100,6 @@ class Segment:
     gens: int
     telemetry: RT.RunTelemetry = dataclasses.field(
         default_factory=RT.RunTelemetry)
-
-    @property
-    def extras(self) -> Dict[str, Any]:
-        """Deprecated legacy dict view of `telemetry`."""
-        return RT.deprecated_extras(self.telemetry, "Segment")
 
 
 def _arg_best(y: np.ndarray, minimize: bool) -> int:

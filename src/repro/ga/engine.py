@@ -111,11 +111,6 @@ class EngineResult:
     telemetry: RT.RunTelemetry = dataclasses.field(
         default_factory=RT.RunTelemetry)
 
-    @property
-    def extras(self) -> Dict[str, Any]:
-        """DEPRECATED dict view of `telemetry` (one release grace)."""
-        return RT.deprecated_extras(self.telemetry, "EngineResult")
-
 
 class Engine:
     """A spec bound to a backend, with cached compiled runners.
@@ -186,7 +181,12 @@ class Engine:
         predecessor; the first chunk after a resume carries
         ``"resumed_from"``).  `fault_tag` rides into every `repro.faults`
         injection-site tag (the scheduler passes its job ids) so armed
-        fault rules can target one run.
+        fault rules can target one run, and labels the run's spans.
+
+        Each chunk's dict carries ``"phases"``: the host seconds of its
+        `RT.phase` spans, keyed by counter (`launch`, `wait`, `readback`,
+        `ckpt_save`, and `seed` on the first chunk).  ``"wall_s"`` is
+        launch plus wait.
 
         Telemetry granularity follows the backend's LAUNCH unit: island
         topologies sample trajectories once per launch, and a resident-epoch
@@ -204,14 +204,16 @@ class Engine:
                                          self.spec.gens_per_epoch)
         scale = self.spec.fitness_scale()
         mini = self.spec.minimize
+        span = _span_args(fault_tag)
+        phases: Dict[str, float] = {}
 
-        state = self.init_state()
         done, chunk_idx, migrations = 0, 0, 0
         resumed_from: Optional[int] = None
         best_y: Optional[float] = None
         best_x = None
-        if ckpt_dir and resume:
-            step = CKPT.latest_step(ckpt_dir)
+        with RT.phase("ga.engine.seed", phases, **span):
+            state = self.init_state()
+            step = CKPT.latest_step(ckpt_dir) if ckpt_dir and resume else None
             if step is not None:
                 resumed_from = int(step)
                 state, extra = CKPT.restore(ckpt_dir, step, state)
@@ -243,60 +245,76 @@ class Engine:
                 "n_vars": self.spec.v,
                 "migrations": migrations,
                 "already_complete": True,
+                "phases": phases,
             }
             return
 
         while done < total:
             tag = f"{fault_tag}|{self.backend_name}|chunk={chunk_idx + 1}"
-            if self.faults is not None:
-                self.faults.inject("slow_chunk", tag)
-            t0 = time.perf_counter()
-            seg = self.backend.segment(state, min(chunk, total - done))
-            jax.block_until_ready(jax.tree.leaves(seg.state))
-            dt = time.perf_counter() - t0
-            if self.faults is not None:
-                # crash AFTER the compute, BEFORE the checkpoint: the
-                # chunk's work is lost, earlier checkpoints are not, and a
-                # retry recomputes it deterministically
-                self.faults.inject("chunk_crash", tag)
-            state = seg.state
-            done += seg.gens
-            chunk_idx += 1
-            migrations += seg.telemetry.topology.migrations
-            if resumed_from is not None:
-                seg.telemetry.resumed_from = resumed_from
-            if best_y is None or (seg.best_y < best_y if mini
-                                  else seg.best_y > best_y):
-                best_y, best_x = seg.best_y, np.asarray(seg.best_x)
-            if ckpt_dir:
-                CKPT.save(ckpt_dir, step=done, tree=state,
-                          extra={"gens_done": done, "chunk_idx": chunk_idx,
-                                 "migrations": migrations,
-                                 "best_y": float(best_y),
-                                 "best_x": [int(v) for v in best_x],
-                                 "backend": self.backend_name},
-                          faults=self.faults, fault_tag=fault_tag)
-            yield {
-                "chunk": chunk_idx,
-                "resumed_from": resumed_from,
-                "gens_done": done,
-                "gens_total": total,
-                "chunk_gens": seg.gens,
-                "chunk_best": seg.best_y / scale,
-                "best_fitness": best_y / scale,
-                "best_params": self.spec.decode(best_x),
-                "traj_best": np.asarray(seg.traj_best) / scale,
-                "wall_s": dt,
-                "gens_per_s": seg.gens / dt if dt > 0 else float("inf"),
-                "backend": self.backend_name,
-                "problem": self.spec.problem or "blackbox",
-                "n_vars": self.spec.v,
-                "migrations": migrations,
-                "telemetry_unit_gens": seg.telemetry.topology
-                                          .telemetry_unit_gens,
-                "telemetry": seg.telemetry,
-            }
+            with RT.phase("ga.chunk", chunk=chunk_idx + 1, **span):
+                if self.faults is not None:
+                    self.faults.inject("slow_chunk", tag)
+                with RT.phase("ga.chunk.launch", phases, **span):
+                    seg = self.backend.segment(state, min(chunk, total - done))
+                with RT.phase("ga.chunk.wait", phases, **span):
+                    jax.block_until_ready(jax.tree.leaves(seg.state))
+                dt = phases["launch"] + phases["wait"]
+                if self.faults is not None:
+                    # crash AFTER the compute, BEFORE the checkpoint: the
+                    # chunk's work is lost, earlier checkpoints are not, and
+                    # a retry recomputes it deterministically
+                    self.faults.inject("chunk_crash", tag)
+                with RT.phase("ga.chunk.readback", phases, **span):
+                    state = seg.state
+                    done += seg.gens
+                    chunk_idx += 1
+                    migrations += seg.telemetry.topology.migrations
+                    if resumed_from is not None:
+                        seg.telemetry.resumed_from = resumed_from
+                    if best_y is None or (seg.best_y < best_y if mini
+                                          else seg.best_y > best_y):
+                        best_y, best_x = seg.best_y, np.asarray(seg.best_x)
+                    tele = {
+                        "chunk": chunk_idx,
+                        "resumed_from": resumed_from,
+                        "gens_done": done,
+                        "gens_total": total,
+                        "chunk_gens": seg.gens,
+                        "chunk_best": seg.best_y / scale,
+                        "best_fitness": best_y / scale,
+                        "best_params": self.spec.decode(best_x),
+                        "traj_best": np.asarray(seg.traj_best) / scale,
+                        "wall_s": dt,
+                        "gens_per_s": (seg.gens / dt if dt > 0
+                                       else float("inf")),
+                        "backend": self.backend_name,
+                        "problem": self.spec.problem or "blackbox",
+                        "n_vars": self.spec.v,
+                        "migrations": migrations,
+                        "telemetry_unit_gens": seg.telemetry.topology
+                                                  .telemetry_unit_gens,
+                        "telemetry": seg.telemetry,
+                    }
+                if ckpt_dir:
+                    with RT.phase("ga.ckpt.save", phases, **span):
+                        CKPT.save(ckpt_dir, step=done, tree=state,
+                                  extra={"gens_done": done,
+                                         "chunk_idx": chunk_idx,
+                                         "migrations": migrations,
+                                         "best_y": float(best_y),
+                                         "best_x": [int(v) for v in best_x],
+                                         "backend": self.backend_name},
+                                  faults=self.faults, fault_tag=fault_tag)
+                tele["phases"] = phases
+            yield tele
+            phases = {}
             resumed_from = None    # only the first post-resume chunk carries it
+
+
+def _span_args(fault_tag: str) -> Dict[str, str]:
+    """The job ids a run's spans carry: the scheduler's comma-joined
+    `fault_tag`, space-separated (a span argument cannot hold a comma)."""
+    return {"jobs": fault_tag.replace(",", " ")} if fault_tag else {}
 
 
 def solve(spec: GASpec, backend: str = "auto", *,
@@ -437,7 +455,7 @@ class PackedEngine:
                        "wall_s": tele["wall_s"],
                        "gens_per_s": tele["gens_per_s"],
                        "backend": self.backend_name, "pack_size": 1,
-                       "jobs": [jt]}
+                       "phases": tele["phases"], "jobs": [jt]}
             return
 
         spec = self.batch_spec
@@ -445,14 +463,16 @@ class PackedEngine:
         chunk = chunk_generations or max(1, total // 10, spec.gens_per_epoch)
         mini = spec.minimize
         L = self.n_slots
+        span = _span_args(fault_tag)
+        phases: Dict[str, float] = {}
 
-        state = self.init_state()
         done, chunk_idx, migrations = 0, 0, 0
         resumed_from: Optional[int] = None
         slot_y = np.full((L,), np.inf if mini else -np.inf, np.float32)
         slot_x = np.zeros((L, spec.v), np.uint32)
-        if ckpt_dir and resume:
-            step = CKPT.latest_step(ckpt_dir)
+        with RT.phase("ga.engine.seed", phases, **span):
+            state = self.init_state()
+            step = CKPT.latest_step(ckpt_dir) if ckpt_dir and resume else None
             if step is not None:
                 resumed_from = int(step)
                 state, extra = CKPT.restore(ckpt_dir, step, state)
@@ -478,67 +498,78 @@ class PackedEngine:
 
         if done >= total:
             # resumed a finished pack: surface the stored per-job results
+            jobs = [self._job_tele(
+                j, chunk_idx=chunk_idx, done=done, total=total, dt=0.0,
+                seg_gens=0, slot_y=slot_y, slot_x=slot_x, chunk_y=slot_y,
+                traj=slot_y[:, None], migrations=migrations, telemetry=None)
+                for j in range(len(self.specs))]
+            for jt in jobs:
+                jt["phases"] = phases
             yield {
                 "chunk": chunk_idx, "gens_done": done, "gens_total": total,
                 "chunk_gens": 0, "wall_s": 0.0, "gens_per_s": 0.0,
                 "backend": self.backend_name, "pack_size": len(self.specs),
-                "already_complete": True,
-                "jobs": [self._job_tele(
-                    j, chunk_idx=chunk_idx, done=done, total=total, dt=0.0,
-                    seg_gens=0, slot_y=slot_y, slot_x=slot_x, chunk_y=slot_y,
-                    traj=slot_y[:, None], migrations=migrations,
-                    telemetry=None)
-                    for j in range(len(self.specs))],
+                "already_complete": True, "phases": phases, "jobs": jobs,
             }
             return
 
         while done < total:
             tag = f"{fault_tag}|{self.backend_name}|chunk={chunk_idx + 1}"
-            if self.faults is not None:
-                self.faults.inject("slow_chunk", tag)
-            t0 = time.perf_counter()
-            seg = self.backend.segment(state, min(chunk, total - done))
-            jax.block_until_ready(jax.tree.leaves(seg.state))
-            dt = time.perf_counter() - t0
-            if self.faults is not None:
-                # crash AFTER the compute, BEFORE the checkpoint (see Engine)
-                self.faults.inject("chunk_crash", tag)
-            state = seg.state
-            done += seg.gens
-            chunk_idx += 1
-            migrations += seg.telemetry.topology.migrations
-            if resumed_from is not None:
-                seg.telemetry.resumed_from = resumed_from
-            rep = seg.telemetry.per_repeat
-            by = np.asarray(rep.best, np.float32).reshape(L)
-            bx = np.asarray(rep.best_x, np.uint32).reshape(L, spec.v)
-            traj = np.asarray(rep.traj_best, np.float32).reshape(L, -1)
-            better = by < slot_y if mini else by > slot_y
-            slot_y = np.where(better, by, slot_y)
-            slot_x = np.where(better[:, None], bx, slot_x)
-            if ckpt_dir:
-                CKPT.save(ckpt_dir, step=done, tree=state,
-                          extra={"gens_done": done, "chunk_idx": chunk_idx,
-                                 "migrations": migrations,
-                                 "slot_y": [float(v) for v in slot_y],
-                                 "slot_x": [[int(v) for v in row]
-                                            for row in slot_x],
-                                 "seeds": [int(s) for s in self.seeds],
-                                 "backend": self.backend_name},
-                          faults=self.faults, fault_tag=fault_tag)
+            with RT.phase("ga.chunk", chunk=chunk_idx + 1, **span):
+                if self.faults is not None:
+                    self.faults.inject("slow_chunk", tag)
+                with RT.phase("ga.chunk.launch", phases, **span):
+                    seg = self.backend.segment(state, min(chunk, total - done))
+                with RT.phase("ga.chunk.wait", phases, **span):
+                    jax.block_until_ready(jax.tree.leaves(seg.state))
+                dt = phases["launch"] + phases["wait"]
+                if self.faults is not None:
+                    # crash AFTER the compute, BEFORE the checkpoint (see
+                    # Engine)
+                    self.faults.inject("chunk_crash", tag)
+                with RT.phase("ga.chunk.readback", phases, **span):
+                    state = seg.state
+                    done += seg.gens
+                    chunk_idx += 1
+                    migrations += seg.telemetry.topology.migrations
+                    if resumed_from is not None:
+                        seg.telemetry.resumed_from = resumed_from
+                    rep = seg.telemetry.per_repeat
+                    by = np.asarray(rep.best, np.float32).reshape(L)
+                    bx = np.asarray(rep.best_x, np.uint32).reshape(L, spec.v)
+                    traj = np.asarray(rep.traj_best, np.float32).reshape(L, -1)
+                    better = by < slot_y if mini else by > slot_y
+                    slot_y = np.where(better, by, slot_y)
+                    slot_x = np.where(better[:, None], bx, slot_x)
+                    jobs = [self._job_tele(
+                        j, chunk_idx=chunk_idx, done=done, total=total, dt=dt,
+                        seg_gens=seg.gens, slot_y=slot_y, slot_x=slot_x,
+                        chunk_y=by, traj=traj, migrations=migrations,
+                        telemetry=seg.telemetry)
+                        for j in range(len(self.specs))]
+                if ckpt_dir:
+                    with RT.phase("ga.ckpt.save", phases, **span):
+                        CKPT.save(ckpt_dir, step=done, tree=state,
+                                  extra={"gens_done": done,
+                                         "chunk_idx": chunk_idx,
+                                         "migrations": migrations,
+                                         "slot_y": [float(v) for v in slot_y],
+                                         "slot_x": [[int(v) for v in row]
+                                                    for row in slot_x],
+                                         "seeds": [int(s) for s in self.seeds],
+                                         "backend": self.backend_name},
+                                  faults=self.faults, fault_tag=fault_tag)
+                for jt in jobs:
+                    jt["phases"] = phases
             yield {
                 "chunk": chunk_idx, "resumed_from": resumed_from,
                 "gens_done": done, "gens_total": total,
                 "chunk_gens": seg.gens, "wall_s": dt,
                 "gens_per_s": seg.gens / dt if dt > 0 else float("inf"),
                 "backend": self.backend_name, "pack_size": len(self.specs),
-                "jobs": [self._job_tele(
-                    j, chunk_idx=chunk_idx, done=done, total=total, dt=dt,
-                    seg_gens=seg.gens, slot_y=slot_y, slot_x=slot_x,
-                    chunk_y=by, traj=traj, migrations=migrations,
-                    telemetry=seg.telemetry)
-                    for j in range(len(self.specs))],
+                "phases": phases, "jobs": jobs,
             }
+            phases = {}
             resumed_from = None
 
     def run(self, *, chunk_generations: Optional[int] = None):

@@ -1,9 +1,7 @@
-"""Typed run telemetry — the structured successor of the `extras` dict.
+"""Typed run telemetry, and the named host phases of a served job.
 
-Segments and engine results used to report how a run executed through a
-stringly-keyed `extras` dict (`epoch_mode`, `plan_source`, `plan_fallback`,
-`per_repeat_best`, ... scattered across every consumer).  `RunTelemetry`
-replaces that contract with a versioned dataclass of three facets:
+How a segment or engine result executed is a versioned dataclass,
+`RunTelemetry`, of three facets:
 
   * `plan: PlanInfo` — the epoch-plan decision (mode, provenance, fallback
     reason, launch fold shape, streamed tile size, VMEM estimate);
@@ -12,17 +10,21 @@ replaces that contract with a versioned dataclass of three facets:
   * `per_repeat: ReplicaStats | None` — per-replica best/trajectory arrays
     when the run stacked `n_repeats` replicas.
 
-`Segment.extras` / `EngineResult.extras` remain as DEPRECATED read-only
-dict views (`to_extras()`) for one release; every in-repo consumer reads
-the typed fields.  `version` is bumped whenever a field changes meaning so
-persisted telemetry (e.g. scheduler job streams) stays interpretable.
+`version` is bumped whenever a field changes meaning so persisted
+telemetry (e.g. scheduler job streams) stays interpretable.
+
+`phase` names where a served job's host time goes: one context manager
+that opens a profiler span and adds the seconds it took to a per-phase
+counter dict (`PHASES` fixes the span names and the counter each feeds).
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-import warnings
-from typing import Any, Dict, Optional
+import threading
+import time
+from typing import Any, Dict, Iterator, Optional
 
 TELEMETRY_VERSION = 1
 
@@ -111,53 +113,49 @@ class RunTelemetry:
         packed job's telemetry carries after its slots are sliced out."""
         return dataclasses.replace(self, per_repeat=None)
 
-    def to_extras(self) -> Dict[str, Any]:
-        """The legacy `extras` dict (exact historical keys).  Deprecated —
-        read the typed fields; this view exists for one release."""
-        d: Dict[str, Any] = {}
-        t, p = self.topology, self.plan
-        if t.executor != "-":
-            d["executor"] = t.executor
-            d["topology"] = t.topology
-        if self.problem is not None:
-            d["problem"] = self.problem
-            d["n_vars"] = self.n_vars
-        if p.mode != "-":
-            d["telemetry_unit_gens"] = t.telemetry_unit_gens
-            d["n_islands"] = t.n_islands
-            d["n_shards"] = t.n_shards
-            d["epoch_mode"] = p.mode
-            d["plan_source"] = p.source
-            d["launches"] = t.launches
-            d["migrations"] = t.migrations
-            if p.tile_islands is not None:
-                d["tile_islands"] = p.tile_islands
-            if p.lane != "-":
-                d["sel_lane"] = p.lane
-            if p.fallback is not None:
-                d["resident_fallback"] = p.fallback
-                d["plan_fallback"] = p.fallback
-            if t.sharded:
-                d["sharded"] = True
-        r = self.per_repeat
-        if r is not None:
-            if r.best is not None:
-                d["per_repeat_best"] = r.best
-            if r.best_x is not None:
-                d["per_repeat_best_x"] = r.best_x
-            if r.traj_best is not None:
-                d["per_repeat_traj_best"] = r.traj_best
-            if r.traj_mean is not None:
-                d["per_repeat_traj_mean"] = r.traj_mean
-        return d
+
+# span name -> the per-job counter (`GAJobStats.phase_s`) it adds to
+PHASES = {
+    "ga.sched.submit": "submit",
+    "ga.sched.build": "build",
+    "ga.sched.finish": "finish",
+    "ga.sched.park": "park",
+    "ga.journal.append": "journal",
+    "ga.engine.seed": "seed",
+    "ga.chunk.launch": "launch",
+    "ga.chunk.wait": "wait",
+    "ga.chunk.readback": "readback",
+    "ga.ckpt.save": "ckpt_save",
+}
+
+_nested = threading.local()
 
 
-def deprecated_extras(telemetry: RunTelemetry, owner: str) -> Dict[str, Any]:
-    """The `.extras` property body: warn once per call site, return the
-    legacy dict view."""
-    warnings.warn(
-        f"{owner}.extras is deprecated; read the typed {owner}.telemetry "
-        "(ga.RunTelemetry: .plan / .topology / .per_repeat) instead — the "
-        "dict view will be removed in the next release",
-        DeprecationWarning, stacklevel=3)
-    return telemetry.to_extras()
+@contextlib.contextmanager
+def phase(name: str, into: Optional[Dict[str, float]] = None,
+          **args) -> Iterator[None]:
+    """Time one step of a run as the span `name` and, with `into`, add its
+    seconds to `into[PHASES[name]]`.
+
+    The span is a `jax.profiler.TraceAnnotation`: it lands on the host
+    plane of a profiler trace, on the device trace's clock, with `args` as
+    its arguments, and records nothing while no profiler session is
+    active.  The counter takes the phase's own time, less that of the
+    phases nested inside it on the same thread, so the counters of one job
+    add up without counting a second twice."""
+    from jax.profiler import TraceAnnotation   # lazy: callers stay jax-free
+
+    stack = _nested.__dict__.setdefault("stack", [])
+    stack.append(0.0)
+    t0 = time.perf_counter()
+    try:
+        with TraceAnnotation(name, **args):
+            yield
+    finally:
+        dt = time.perf_counter() - t0
+        inner = stack.pop()
+        if stack:
+            stack[-1] += dt
+        if into is not None:
+            key = PHASES[name]
+            into[key] = into.get(key, 0.0) + dt - inner

@@ -135,7 +135,14 @@ def serve_queue(engine: Engine, requests: List[Request],
 
 @dataclasses.dataclass
 class GAJobStats:
-    """Aggregated `repro.ga.Engine.run_chunked` telemetry for one job."""
+    """Aggregated `repro.ga.Engine.run_chunked` telemetry for one job.
+
+    `phase_s` holds the host seconds of the job's named phases (the
+    counters of `repro.ga.telemetry.PHASES`, plus the scheduler's `queue`
+    wait).  A pack's phases (build, seed, the chunk phases, park) are
+    charged whole to every job of the pack, as `wall_s` is; `journal` is
+    the job's share: its own events whole, a pack's split evenly among its
+    jobs."""
 
     job_id: str
     backend: str = "?"
@@ -164,6 +171,7 @@ class GAJobStats:
     plan_fallback: Optional[str] = None   # why resident modes were infeasible
     tile_islands: Optional[int] = None    # streamed mode's island tile size
     sel_lane: str = "-"              # fused tournament lane: onehot | gather
+    phase_s: Dict[str, float] = dataclasses.field(default_factory=dict)
 
     @property
     def gens_per_s(self) -> float:
@@ -206,6 +214,7 @@ class GAJobStats:
             "plan_fallback": self.plan_fallback,
             "tile_islands": self.tile_islands,
             "sel_lane": self.sel_lane,
+            "phase_s": dict(self.phase_s),
         }
 
 
@@ -298,6 +307,8 @@ class GAMetricsRegistry:
             job.wall_s += float(tele.get("wall_s", 0.0))
             job.migrations = int(tele.get("migrations", job.migrations))
             job.pack_size = int(tele.get("pack_size", job.pack_size))
+            for k, v in tele.get("phases", {}).items():
+                job.phase_s[k] = job.phase_s.get(k, 0.0) + v
             rt = tele.get("telemetry")
             if rt is not None:
                 job.islands = rt.topology.n_islands
@@ -315,10 +326,19 @@ class GAMetricsRegistry:
             subs = list(self._subs.get(job_id, ()))
         event = {"event": "chunk", "job_id": job_id}
         event.update({k: v for k, v in tele.items()
-                      if k not in ("telemetry", "extras", "best_params",
-                                   "traj_best")})
+                      if k not in ("telemetry", "best_params", "traj_best")})
         for q in subs:
             q.put(event)
+
+    def add_phases(self, job_id: str, phases: Dict[str, float]) -> None:
+        """Add host seconds to a job's phase counters (the scheduler's
+        phases outside the chunk loop)."""
+        with self._lock:
+            job = self._jobs.get(job_id)
+            if job is None:          # evicted meanwhile
+                return
+            for k, v in phases.items():
+                job.phase_s[k] = job.phase_s.get(k, 0.0) + v
 
     def finish_job(self, job_id: str, error: Optional[str] = None,
                    status: Optional[str] = None,
@@ -395,8 +415,11 @@ class GAMetricsRegistry:
             jobs = {jid: j.as_metrics() for jid, j in self._jobs.items()}
             stats_fn = self._scheduler_stats
         by_status = {}
+        phase_total: Dict[str, float] = {}
         for j in jobs.values():
             by_status[j["status"]] = by_status.get(j["status"], 0) + 1
+            for k, v in j["phase_s"].items():
+                phase_total[k] = phase_total.get(k, 0.0) + v
         snap = {
             "jobs": jobs,
             "job_count": len(jobs),
@@ -410,6 +433,7 @@ class GAMetricsRegistry:
                                      for j in jobs.values()),
             "migrations_total": sum(j["migration_count"]
                                     for j in jobs.values()),
+            "phase_seconds_total": phase_total,
         }
         if stats_fn is not None:
             try:

@@ -25,9 +25,10 @@ straight to its final result.  Jobs left queued / preempted / running
 re-enqueue; their latest unit's checkpoint directory lets the pack resume
 bit-identically from its last completed chunk.
 
-Appends are flushed + fsynced — events are per state transition (not per
-chunk), so durability costs nothing measurable.  A torn final line (the
-process died mid-append) is treated as the end of the log, never an error.
+Appends are flushed + fsynced, one per state transition (not per chunk);
+each is a `ga.journal.append` span whose seconds the scheduler charges to
+the event's jobs as their `journal` phase.  A torn final line (the process
+died mid-append) is treated as the end of the log, never an error.
 """
 
 from __future__ import annotations
@@ -79,14 +80,23 @@ class SchedulerJournal:
         self._lock = threading.Lock()
         self._closed = False
 
-    def append(self, event: Dict[str, Any]) -> None:
-        line = json.dumps(event, separators=(",", ":"))
-        with self._lock:
-            if self._closed:
-                return
-            self._f.write(line + "\n")
-            self._f.flush()
-            os.fsync(self._f.fileno())
+    def append(self, event: Dict[str, Any],
+               into: Optional[Dict[str, float]] = None) -> None:
+        """Write, flush and fsync one event; with `into`, add the seconds
+        it took to `into["journal"]`."""
+        from repro.ga.telemetry import phase   # lazy: reads stay light
+        if "job_ids" in event:
+            who = {"jobs": " ".join(event["job_ids"])}
+        else:
+            who = {"job": event["job_id"]}
+        with phase("ga.journal.append", into, ev=event["ev"], **who):
+            line = json.dumps(event, separators=(",", ":"))
+            with self._lock:
+                if self._closed:
+                    return
+                self._f.write(line + "\n")
+                self._f.flush()
+                os.fsync(self._f.fileno())
 
     def close(self) -> None:
         with self._lock:

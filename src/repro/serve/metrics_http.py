@@ -11,8 +11,9 @@ in Prometheus text exposition format plus JSON/SSE job endpoints.
     server.shutdown()
 
 Endpoints:
-  /metrics               Prometheus text (version 0.0.4) — per-job gauges,
-                         fleet totals, and (when a GAScheduler attached its
+  /metrics               Prometheus text (version 0.0.4) — per-job gauges
+                         (with each job's host seconds per phase), fleet
+                         totals, and (when a GAScheduler attached its
                          stats to the registry) queue-depth / jobs-running /
                          compile-cache gauges.
   /healthz               liveness probe.
@@ -147,6 +148,21 @@ def render_prometheus(snapshot: dict) -> str:
             f'{name}{{{label_str(j)},mode="{_esc(j["epoch_mode"])}"'
             f',source="{_esc(j["plan_source"])}"'
             f',lane="{_esc(j.get("sel_lane", "-"))}"}} 1')
+    # host seconds per named phase (repro.ga.telemetry.PHASES + queue)
+    name = f"{_PREFIX}_job_phase_seconds"
+    lines.append(f"# HELP {name} Host seconds the job spent in each phase "
+                 "(a pack's phases count whole for each of its jobs, "
+                 "its journal events in equal shares)")
+    lines.append(f"# TYPE {name} gauge")
+    for j in jobs.values():
+        for ph, sec in sorted(j.get("phase_s", {}).items()):
+            lines.append(f'{name}{{{label_str(j)},phase="{_esc(ph)}"}} '
+                         f"{float(sec):g}")
+    name = f"{_PREFIX}_phase_seconds_total"
+    lines.append(f"# HELP {name} Host seconds per phase, summed over jobs")
+    lines.append(f"# TYPE {name} gauge")
+    for ph, sec in sorted(snapshot.get("phase_seconds_total", {}).items()):
+        lines.append(f'{name}{{phase="{_esc(ph)}"}} {float(sec):g}')
     for key, suffix, help_ in _FLEET_GAUGES:
         name = f"{_PREFIX}_{suffix}"
         lines.append(f"# HELP {name} {help_}")

@@ -74,6 +74,13 @@ Fault tolerance (exercised by `scripts/chaos_smoke.py` and
   (`repro.serve.journal`); `GAScheduler(recover=True)` replays it so a
   restarted server re-enqueues pending jobs (frozen packs resume from
   their checkpoints) and restores finished results.
+
+Where a job's host time goes is named twice over: each step is a
+`repro.ga.telemetry.phase` span (`ga.sched.submit`, `ga.sched.dispatch`
+around a unit's whole run, `ga.sched.build`, `ga.sched.finish`,
+`ga.sched.park`, and the engine's and journal's spans inside), and its
+seconds land in the job's `phase_s` counters on /metrics, with the
+`queue` wait from each enqueue to the dispatch that follows it.
 """
 
 from __future__ import annotations
@@ -89,6 +96,7 @@ import zlib
 from typing import Any, Dict, Iterator, List, Optional
 
 from repro import faults as FLT
+from repro.ga.telemetry import phase
 from repro.serve import journal as JRN
 from repro.serve.engine import GA_METRICS, GAMetricsRegistry
 
@@ -131,6 +139,8 @@ class Job:
     quarantined: bool = False                # failed as the isolated poison
     submitted_at: float = 0.0                # clock() submission stamp
     recovered: bool = False                  # re-enqueued by journal replay
+    queued_at: float = 0.0                   # clock() at the last enqueue
+    dispatched_at: Optional[float] = None    # clock() at the last dispatch
 
 
 @dataclasses.dataclass
@@ -248,33 +258,37 @@ class GAScheduler:
             if self._stop:
                 raise RuntimeError("scheduler is shut down")
         job_id = self.registry.allocate_job_id(spec.problem or "blackbox")
-        job = Job(job_id=job_id, spec=spec,
-                  backend=backend if backend is not None else self.backend,
-                  priority=int(priority),
-                  deadline_s=None if deadline_s is None else float(deadline_s),
-                  max_retries=max_retries,
-                  submitted_at=self._clock())
-        if self.cost_table is not None:
-            from repro.autotune import estimate_gens_per_s
-            try:   # an estimate is a scheduling hint, never a submit error
-                job.est_gens_per_s = estimate_gens_per_s(
-                    spec, self.cost_table, backend=job.backend,
-                    mesh=self.mesh)
-            except Exception:
-                job.est_gens_per_s = None
-        self.registry.queue_job(job_id, problem=spec.problem or "blackbox",
-                                gens_total=spec.generations, n_vars=spec.v,
-                                priority=job.priority, deadline_s=deadline_s)
-        self._journal.append({"ev": "submit", "job_id": job_id,
-                              "spec": JRN.spec_to_json(spec),
-                              "backend": job.backend,
-                              "priority": job.priority,
-                              "deadline_s": job.deadline_s,
-                              "max_retries": job.max_retries})
-        with self._cv:
-            self._jobs[job_id] = job
-            self._queue.append(_Unit(seq=next(self._seq), jobs=[job]))
-            self._cv.notify_all()
+        phases: Dict[str, float] = {}
+        with phase("ga.sched.submit", phases, job=job_id):
+            job = Job(job_id=job_id, spec=spec,
+                      backend=backend if backend is not None else self.backend,
+                      priority=int(priority),
+                      deadline_s=(None if deadline_s is None
+                                  else float(deadline_s)),
+                      max_retries=max_retries,
+                      submitted_at=self._clock())
+            if self.cost_table is not None:
+                from repro.autotune import estimate_gens_per_s
+                try:   # an estimate is a scheduling hint, never a submit error
+                    job.est_gens_per_s = estimate_gens_per_s(
+                        spec, self.cost_table, backend=job.backend,
+                        mesh=self.mesh)
+                except Exception:
+                    job.est_gens_per_s = None
+            self.registry.queue_job(job_id, problem=spec.problem or "blackbox",
+                                    gens_total=spec.generations, n_vars=spec.v,
+                                    priority=job.priority,
+                                    deadline_s=deadline_s)
+            self._journal.append({"ev": "submit", "job_id": job_id,
+                                  "spec": JRN.spec_to_json(spec),
+                                  "backend": job.backend,
+                                  "priority": job.priority,
+                                  "deadline_s": job.deadline_s,
+                                  "max_retries": job.max_retries}, phases)
+            with self._cv:
+                self._jobs[job_id] = job
+            self._enqueue(_Unit(seq=next(self._seq), jobs=[job]))
+        self.registry.add_phases(job_id, phases)
         return job_id
 
     def pause(self) -> None:
@@ -415,6 +429,30 @@ class GAScheduler:
 
     # ---- worker ---------------------------------------------------------
 
+    def _enqueue(self, *units: _Unit) -> None:
+        """Put units (back) on the queue; their jobs' `queue` wait starts."""
+        with self._cv:
+            now = self._clock()
+            for u in units:
+                for j in u.jobs:
+                    j.queued_at = now
+            self._queue.extend(units)
+            self._cv.notify_all()
+
+    def _charge(self, jobs: List[Job], phases: Dict[str, float]) -> None:
+        """Add a unit's phase seconds to each of its jobs: whole, except the
+        journal's, which the jobs share."""
+        for j in jobs:
+            self.registry.add_phases(j.job_id, {
+                k: v / len(jobs) if k == "journal" else v
+                for k, v in phases.items()})
+
+    def _log(self, event: Dict[str, Any], jobs: List[Job]) -> None:
+        """Journal one event, charging its seconds to the event's jobs."""
+        spent: Dict[str, float] = {}
+        self._journal.append(event, spent)
+        self._charge(jobs, spent)
+
     def _pack_sig(self, job: Job):
         return (job.spec.compile_key(), job.spec.generations, job.backend)
 
@@ -492,14 +530,18 @@ class GAScheduler:
                 if self._stop:
                     return
                 if unit is not None:
+                    now = self._clock()
                     for j in unit.live_jobs():
                         j.state = RUNNING
+                        j.dispatched_at = now
                     self._running = unit.live_jobs()
             if unit is None:
                 self.gc_now()
                 continue
             try:
-                self._run_unit(unit)
+                with phase("ga.sched.dispatch", seq=unit.seq,
+                           jobs=" ".join(j.job_id for j in unit.jobs)):
+                    self._run_unit(unit)
             except Exception as e:     # noqa: BLE001 — job-level failure wall
                 self._handle_unit_failure(unit, e)
             finally:
@@ -523,8 +565,8 @@ class GAScheduler:
             self.quarantined_total += 1
         self.registry.finish_job(job.job_id, error=err, status=state,
                                  quarantined=quarantined)
-        self._journal.append({"ev": "state", "job_id": job.job_id,
-                              "state": state, "error": err})
+        self._log({"ev": "state", "job_id": job.job_id, "state": state,
+                   "error": err}, [job])
         job.done.set()
 
     def _handle_unit_failure(self, unit: _Unit, exc: Exception) -> None:
@@ -554,13 +596,11 @@ class GAScheduler:
             self.retries_total += len(live)
             unit.packable = False      # membership freezes with its ckpt
             unit.not_before = self._clock() + delay
-            self._journal.append({"ev": "requeue", "seq": unit.seq,
-                                  "job_ids": [j.job_id for j in unit.jobs],
-                                  "ckpt_dir": unit.ckpt_dir,
-                                  "error": err, "backoff_s": delay})
-            with self._cv:
-                self._queue.append(unit)
-                self._cv.notify_all()
+            self._log({"ev": "requeue", "seq": unit.seq,
+                       "job_ids": [j.job_id for j in unit.jobs],
+                       "ckpt_dir": unit.ckpt_dir,
+                       "error": err, "backoff_s": delay}, live)
+            self._enqueue(unit)
             return
         if len(live) > 1:
             self._split_unit(unit, live, err)
@@ -595,13 +635,10 @@ class GAScheduler:
             self.registry.set_status(j.job_id, QUEUED)
             new_units.append(_Unit(seq=seq, jobs=[j], packable=False,
                                    ckpt_dir=solo_dir, isolated=True))
-            self._journal.append({"ev": "requeue", "seq": seq,
-                                  "job_ids": [j.job_id],
-                                  "ckpt_dir": solo_dir, "error": err,
-                                  "isolated": True})
-        with self._cv:
-            self._queue.extend(new_units)
-            self._cv.notify_all()
+            self._log({"ev": "requeue", "seq": seq, "job_ids": [j.job_id],
+                       "ckpt_dir": solo_dir, "error": err,
+                       "isolated": True}, [j])
+        self._enqueue(*new_units)
 
     # ---- deadlines ------------------------------------------------------
 
@@ -689,13 +726,13 @@ class GAScheduler:
                 # checkpoint (journal order = slot order = seed identity)
                 order = {jid: i for i, jid in enumerate(ids)}
                 jobs = sorted(jobs, key=lambda j: order[j.job_id])
-                self._queue.append(_Unit(seq=seq, jobs=jobs, packable=False,
-                                         ckpt_dir=unit_info["ckpt_dir"]))
+                self._enqueue(_Unit(seq=seq, jobs=jobs, packable=False,
+                                    ckpt_dir=unit_info["ckpt_dir"]))
             else:
                 # membership changed (some members finished) — the pack
                 # checkpoint no longer matches; restart each job fresh
-                for j in jobs:
-                    self._queue.append(_Unit(seq=next(self._seq), jobs=[j]))
+                self._enqueue(*(_Unit(seq=next(self._seq), jobs=[j])
+                                for j in jobs))
             self.recovered_total += len(jobs)
             for j in jobs:
                 self.registry.set_status(j.job_id, QUEUED)
@@ -711,6 +748,9 @@ class GAScheduler:
         from repro.ga.engine import PackedEngine   # lazy: jax import cost
 
         jobs = unit.jobs
+        for j in unit.live_jobs():
+            self.registry.add_phases(j.job_id,
+                                     {"queue": j.dispatched_at - j.queued_at})
         # a queued job can blow its deadline before ever dispatching
         self._expire_deadlines(jobs)
         live = unit.live_jobs()
@@ -722,16 +762,19 @@ class GAScheduler:
         if unit.ckpt_dir is None:
             unit.ckpt_dir = os.path.join(self.ckpt_root, f"pack-{unit.seq}")
         fault_tag = ",".join(j.job_id for j in jobs)
+        ids = " ".join(j.job_id for j in jobs)
         if self.faults is not None:
             # the compile_fail site: a trace/build blow-up before any chunk
             self.faults.inject("compile_fail", fault_tag)
-        pe = PackedEngine(
-            [j.spec for j in jobs], jobs[0].backend,
-            options=dataclasses.replace(
-                self.options, cost_table=self.cost_table,
-                # share THIS injector instance (counters and all); False
-                # stops a disarmed engine re-resolving the ambient env
-                faults=self.faults if self.faults is not None else False))
+        phases: Dict[str, float] = {}
+        with phase("ga.sched.build", phases, jobs=ids):
+            pe = PackedEngine(
+                [j.spec for j in jobs], jobs[0].backend,
+                options=dataclasses.replace(
+                    self.options, cost_table=self.cost_table,
+                    # share THIS injector instance (counters and all); False
+                    # stops a disarmed engine re-resolving the ambient env
+                    faults=self.faults if self.faults is not None else False))
         self.packs_launched += 1
         if len(jobs) > 1:
             self.jobs_packed += len(jobs)
@@ -743,7 +786,8 @@ class GAScheduler:
         self._journal.append({"ev": "dispatch", "seq": unit.seq,
                               "job_ids": [j.job_id for j in jobs],
                               "ckpt_dir": unit.ckpt_dir,
-                              "attempt": unit.attempts})
+                              "attempt": unit.attempts}, phases)
+        self._charge(live, phases)
         priority = unit.priority
         last: Optional[Dict[str, Any]] = None
         for tele in pe.run_chunked(chunk_generations=self.chunk_generations,
@@ -771,36 +815,44 @@ class GAScheduler:
                     and self._higher_priority_waiting(priority)):
                 # park the pack: state is already checkpointed; membership
                 # freezes so the packed checkpoint resumes with these jobs
-                for j in unit.live_jobs():
-                    j.state = PREEMPTED
-                    self.registry.set_status(j.job_id, PREEMPTED)
-                self.preemptions += 1
-                self._journal.append({"ev": "park", "seq": unit.seq,
-                                      "job_ids": [j.job_id for j in jobs],
-                                      "ckpt_dir": unit.ckpt_dir})
-                with self._cv:
+                live = unit.live_jobs()
+                parked: Dict[str, float] = {}
+                with phase("ga.sched.park", parked, jobs=ids):
+                    for j in live:
+                        j.state = PREEMPTED
+                        self.registry.set_status(j.job_id, PREEMPTED)
+                    self.preemptions += 1
+                    self._journal.append({"ev": "park", "seq": unit.seq,
+                                          "job_ids": [j.job_id for j in jobs],
+                                          "ckpt_dir": unit.ckpt_dir}, parked)
                     # jobs stay PREEMPTED while waiting (the informative
                     # state); the unit re-enters the queue and flips them
                     # back to RUNNING when re-dispatched
-                    self._queue.append(_Unit(seq=unit.seq, jobs=jobs,
-                                             packable=False,
-                                             ckpt_dir=unit.ckpt_dir,
-                                             attempts=unit.attempts,
-                                             isolated=unit.isolated))
-                    self._cv.notify_all()
+                    self._enqueue(_Unit(seq=unit.seq, jobs=jobs,
+                                        packable=False,
+                                        ckpt_dir=unit.ckpt_dir,
+                                        attempts=unit.attempts,
+                                        isolated=unit.isolated))
+                self._charge(live, parked)
                 return
         now = self._clock()
         for j, jt in zip(jobs, last["jobs"]):
             if j.state in TERMINAL_STATES:
                 continue
-            j.result = dict(jt)
-            j.result["best_params"] = [float(v) for v in jt["best_params"]]
-            j.state = DONE
-            j.finished_at = now
-            self.registry.finish_job(j.job_id)
-            safe = {k: j.result[k] for k in self._RESULT_JSON_KEYS
-                    if k in j.result}
-            safe["best_params"] = j.result["best_params"]
-            self._journal.append({"ev": "done", "job_id": j.job_id,
-                                  "result": safe})
+            # each job's own part of the done loop, so its phases end at
+            # its release and not at the last job's
+            finished: Dict[str, float] = {}
+            with phase("ga.sched.finish", finished, job=j.job_id):
+                j.result = dict(jt)
+                j.result["best_params"] = [float(v)
+                                           for v in jt["best_params"]]
+                j.state = DONE
+                j.finished_at = now
+                self.registry.finish_job(j.job_id)
+                safe = {k: j.result[k] for k in self._RESULT_JSON_KEYS
+                        if k in j.result}
+                safe["best_params"] = j.result["best_params"]
+                self._journal.append({"ev": "done", "job_id": j.job_id,
+                                      "result": safe}, finished)
+            self.registry.add_phases(j.job_id, finished)
             j.done.set()

@@ -2,7 +2,7 @@
 feasibility boundaries around the VMEM budget, bit-identity of the streamed
 kernel to the `islands` reference (single device, pinned tiles, sharded
 8-fake-device mesh), forced-override validation, the fused multi-bank LFSR
-leap, `EngineOptions` resolution and the deprecated `.extras` views."""
+leap, `EngineOptions` resolution and the telemetry job view."""
 
 import dataclasses
 import os
@@ -236,7 +236,7 @@ def test_kernel_lfsr_leap_matches_clocked_banks():
 
 
 # ---------------------------------------------------------------------------
-# EngineOptions resolution + the deprecated extras views
+# EngineOptions resolution + the telemetry job view
 # ---------------------------------------------------------------------------
 
 
@@ -259,24 +259,14 @@ def test_engine_options_validation_and_clash():
         resolve_options({"mesh": None})
 
 
-def test_deprecated_extras_views_warn_and_match_typed_fields():
+def test_job_view_keeps_the_plan_without_replica_arrays():
     spec = _spec(n_islands=2, generations=8)
-    res = ga.solve(spec, backend="fused-islands",
-                   options=ga.EngineOptions(cost_table=False))
-    with pytest.warns(DeprecationWarning, match="EngineResult.extras"):
-        legacy = res.extras
-    assert legacy["epoch_mode"] == res.telemetry.plan.mode
-    assert legacy["migrations"] == res.telemetry.topology.migrations
-    eng = ga.Engine(spec, "fused-islands",
-                    options=ga.EngineOptions(cost_table=False))
-    seg = eng.backend.segment(eng.init_state(), 8)
-    with pytest.warns(DeprecationWarning, match="Segment.extras"):
-        legacy = seg.extras
-    assert legacy["executor"] == seg.telemetry.topology.executor == "fused"
     # the job view strips the replica payload, keeps the plan
     rep = ga.solve(dataclasses.replace(spec, n_repeats=2),
                    backend="fused-islands",
                    options=ga.EngineOptions(cost_table=False))
+    assert rep.telemetry.per_repeat is not None
     view = rep.telemetry.job_view()
     assert view.per_repeat is None
-    assert view.plan.mode == rep.telemetry.plan.mode
+    assert view.plan == rep.telemetry.plan
+    assert view.topology == rep.telemetry.topology
