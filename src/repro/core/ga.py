@@ -123,20 +123,40 @@ def fitness_for_problem(problem, cfg: GAConfig) -> FitnessFn:
 # ---------------------------------------------------------------------------
 
 
+def seed_bank_size(n: int, v: int) -> int:
+    """LFSR seeds one population draws: sel + cross + mut + init banks."""
+    return 2 * n + v * (n // 2) + v * n + v * n
+
+
+def split_seed_banks(s: np.ndarray, n: int, v: int, c: int):
+    """Slice host seed rows (..., seed_bank_size(n, v)) into the sel, cross
+    and mut banks and the initial population (x, sel, cross, mut): the init
+    bank runs a few warmup clocks, then is MSB-truncated to c bits per gene."""
+    lead = s.shape[:-1]
+    a, b, d = 2 * n, 2 * n + v * (n // 2), 2 * n + v * (n // 2) + v * n
+    sel = s[..., :a].reshape(*lead, 2, n)
+    cross = s[..., a:b].reshape(*lead, v, n // 2)
+    mut = s[..., b:d].reshape(*lead, v, n)
+    init_bank = s[..., d:].reshape(*lead, n, v)
+    x = lfsr.truncate(lfsr.np_steps(init_bank, 8), c)
+    return x, sel, cross, mut
+
+
+def init_state_host(cfg: GAConfig) -> GAState:
+    """`init_state` as numpy arrays, computed on the host."""
+    x, sel, cross, mut = split_seed_banks(
+        lfsr.np_seeds(cfg.seed, seed_bank_size(cfg.n, cfg.v)),
+        cfg.n, cfg.v, cfg.c)
+    return GAState(x=x, sel_lfsr=sel, cross_lfsr=cross, mut_lfsr=mut,
+                   k=np.int32(0))
+
+
 def init_state(cfg: GAConfig) -> GAState:
     """Seed every LFSR distinctly (the paper's CCseed) and draw the initial
-    random population from a dedicated LFSR bank."""
-    n, v = cfg.n, cfg.v
-    total = 2 * n + v * (n // 2) + v * n + v * n  # sel + cross + mut + init
-    s = lfsr.seeds(cfg.seed, total)
-    sel = s[: 2 * n].reshape(2, n)
-    cross = s[2 * n: 2 * n + v * (n // 2)].reshape(v, n // 2)
-    mut = s[2 * n + v * (n // 2): 2 * n + v * (n // 2) + v * n].reshape(v, n)
-    init_bank = s[-v * n:].reshape(n, v)
-    # a few warmup clocks, then MSB-truncate to c bits per gene
-    x = lfsr.truncate(lfsr.steps(init_bank, 8), cfg.c)
-    return GAState(x=x, sel_lfsr=sel, cross_lfsr=cross, mut_lfsr=mut,
-                   k=jnp.int32(0))
+    random population from a dedicated LFSR bank.  Built on the host and put
+    on the device in one transfer (uncommitted), so seeding a job traces and
+    dispatches nothing; under a trace the values become constants."""
+    return jax.device_put(init_state_host(cfg))
 
 
 # ---------------------------------------------------------------------------

@@ -26,6 +26,7 @@ from typing import Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh
 
 from repro.core import ga as G
@@ -49,18 +50,20 @@ def init_islands(cfg: IslandConfig) -> G.GAState:
     return jax.tree.map(lambda *xs: jnp.stack(xs), *states)
 
 
-def init_islands_fast(cfg: IslandConfig) -> G.GAState:
-    """Vectorized init (no per-island python loop) for large I."""
+def init_islands_host(cfg: IslandConfig) -> G.GAState:
+    """Vectorized init (no per-island python loop) for large I, as numpy
+    arrays computed on the host: island i takes row i of one seed stream."""
     I, n, v = cfg.n_islands, cfg.ga.n, cfg.ga.v
-    per = 2 * n + v * (n // 2) + 2 * v * n
-    s = lfsr.seeds(cfg.ga.seed, I * per).reshape(I, per)
-    sel = s[:, : 2 * n].reshape(I, 2, n)
-    cross = s[:, 2 * n: 2 * n + v * (n // 2)].reshape(I, v, n // 2)
-    mut = s[:, 2 * n + v * (n // 2): 2 * n + v * (n // 2) + v * n].reshape(I, v, n)
-    init_bank = s[:, -v * n:].reshape(I, n, v)
-    x = lfsr.truncate(lfsr.steps(init_bank, 8), cfg.ga.c)
+    per = G.seed_bank_size(n, v)
+    s = lfsr.np_seeds(cfg.ga.seed, I * per).reshape(I, per)
+    x, sel, cross, mut = G.split_seed_banks(s, n, v, cfg.ga.c)
     return G.GAState(x=x, sel_lfsr=sel, cross_lfsr=cross, mut_lfsr=mut,
-                     k=jnp.zeros((I,), jnp.int32))
+                     k=np.zeros((I,), np.int32))
+
+
+def init_islands_fast(cfg: IslandConfig) -> G.GAState:
+    """`init_islands_host` put on the device in one transfer."""
+    return jax.device_put(init_islands_host(cfg))
 
 
 # ---------------------------------------------------------------------------

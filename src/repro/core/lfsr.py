@@ -66,7 +66,13 @@ def truncate(r: jax.Array, bits: int) -> jax.Array:
 
 
 def seeds(key_or_int, n: int) -> jax.Array:
-    """n distinct non-zero 32-bit seeds (CCseed in the paper).
+    """n distinct non-zero 32-bit seeds (CCseed in the paper), on the device.
+    See `np_seeds`."""
+    return jnp.asarray(np_seeds(key_or_int, n))
+
+
+def np_seeds(key_or_int, n: int) -> np.ndarray:
+    """n distinct non-zero 32-bit seeds (CCseed in the paper), on the host.
 
     Deterministic: derived with a splitmix-style integer hash so tests and
     hardware-style reproducibility do not depend on jax.random.
@@ -78,8 +84,7 @@ def seeds(key_or_int, n: int) -> jax.Array:
     z = z * np.uint64(0x94D049BB133111EB)
     z ^= z >> np.uint64(27)
     out = (z & np.uint64(0xFFFFFFFF)).astype(np.uint32)
-    out = np.where(out == 0, np.uint32(0xDEADBEEF), out)  # LFSR must not be 0
-    return jnp.asarray(out)
+    return np.where(out == 0, np.uint32(0xDEADBEEF), out)  # LFSR must not be 0
 
 
 # ---------------------------------------------------------------------------

@@ -111,8 +111,9 @@ def _stack_states_seeded(cfg: G.GAConfig, seeds):
     Replica i is bit-identical to a solo run seeded `seeds[i]` — the
     contract job packing relies on: a packed slot reproduces the job it
     came from exactly."""
-    states = [G.init_state(dataclasses.replace(cfg, seed=s)) for s in seeds]
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *states)
+    states = [G.init_state_host(dataclasses.replace(cfg, seed=s))
+              for s in seeds]
+    return jax.device_put(jax.tree.map(lambda *xs: np.stack(xs), *states))
 
 
 def _stack_states(cfg: G.GAConfig, n_replicas: int):
@@ -123,13 +124,13 @@ def _stack_states(cfg: G.GAConfig, n_replicas: int):
 
 
 def _stack_island_replicas_seeded(icfg: ISL.IslandConfig, seeds):
-    """[R, I, ...] stack with one island set per seed (see
+    """[R, I, ...] host stack with one island set per seed (see
     `_stack_states_seeded` for the per-slot bit-identity contract)."""
     reps = []
     for s in seeds:
         ga_r = dataclasses.replace(icfg.ga, seed=s)
-        reps.append(ISL.init_islands_fast(dataclasses.replace(icfg, ga=ga_r)))
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *reps)
+        reps.append(ISL.init_islands_host(dataclasses.replace(icfg, ga=ga_r)))
+    return jax.tree.map(lambda *xs: np.stack(xs), *reps)
 
 
 def _stack_island_replicas(icfg: ISL.IslandConfig, n_replicas: int):
@@ -736,9 +737,10 @@ class IslandRingTopology(Topology):
         return None
 
     def _place(self, states, lead: int):
-        """Shard the island axis of a fresh state stack over the mesh."""
+        """Put a fresh host state stack on the device, its island axis
+        sharded over the mesh."""
         if self.mesh is None:
-            return states
+            return jax.device_put(states)
         from jax.sharding import NamedSharding, PartitionSpec as P
         axes = self.icfg.axis_names
         return jax.tree.map(
@@ -751,7 +753,7 @@ class IslandRingTopology(Topology):
             states = _stack_island_replicas(self.icfg, self.spec.n_repeats)
             lead = 1
         else:
-            states = ISL.init_islands_fast(self.icfg)
+            states = ISL.init_islands_host(self.icfg)
             lead = 0
         return self._place(states, lead)
 
@@ -762,7 +764,7 @@ class IslandRingTopology(Topology):
         lead = 1 if self.spec.n_repeats > 1 else 0
         if lead == 0:
             ga_s = dataclasses.replace(self.icfg.ga, seed=seeds[0])
-            states = ISL.init_islands_fast(
+            states = ISL.init_islands_host(
                 dataclasses.replace(self.icfg, ga=ga_s))
         else:
             states = _stack_island_replicas_seeded(self.icfg, seeds)

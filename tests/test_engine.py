@@ -5,11 +5,13 @@ import dataclasses
 import warnings
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import ga
 from repro.core import ga as G
+from repro.core import lfsr
 
 
 def _spec(**kw):
@@ -258,6 +260,80 @@ def test_repeats_match_across_backends():
     r_fus = ga.solve(spec, backend="fused")
     np.testing.assert_array_equal(r_ref.telemetry.per_repeat.best,
                                   r_fus.telemetry.per_repeat.best)
+
+
+# ---------------------------------------------------------------------------
+# Seeding: the initial state is built on the host, bit-identical to the
+# device formula it replaced, and a warm process seeds without tracing
+# ---------------------------------------------------------------------------
+
+
+def _device_seeded(seed, n, v, c, n_islands=None):
+    """The device formula seeding used before it moved to the host: one
+    seed stream sliced into banks, the init bank warmed 8 clocks on the
+    device (`lfsr.steps`, a fori_loop), MSB-truncated to c bits."""
+    lead = () if n_islands is None else (n_islands,)
+    per = 2 * n + v * (n // 2) + 2 * v * n
+    s = lfsr.seeds(seed, per * (n_islands or 1)).reshape(*lead, per)
+    a, b, d = 2 * n, 2 * n + v * (n // 2), 2 * n + v * (n // 2) + v * n
+    sel = s[..., :a].reshape(*lead, 2, n)
+    cross = s[..., a:b].reshape(*lead, v, n // 2)
+    mut = s[..., b:d].reshape(*lead, v, n)
+    init_bank = s[..., -v * n:].reshape(*lead, n, v)
+    x = lfsr.truncate(lfsr.steps(init_bank, 8), c)
+    k = jnp.int32(0) if n_islands is None else jnp.zeros(lead, jnp.int32)
+    return G.GAState(x=x, sel_lfsr=sel, cross_lfsr=cross, mut_lfsr=mut, k=k)
+
+
+@pytest.mark.parametrize("kind,n,v,c,seed", [
+    ("solo", 64, 2, 10, 0),
+    ("solo", 64, 2, 10, 1),
+    ("solo", 64, 2, 10, 2147483704),
+    ("solo", 64, 2, 10, 2**32 - 1),
+    ("solo", 48, 5, 28, 7),
+    ("packed", 64, 2, 10, 2147483704),
+    ("islands", 64, 2, 10, 13),
+])
+def test_host_seeding_matches_device_formula(kind, n, v, c, seed):
+    from repro.core import islands as ISL
+    from repro.ga import backends as B
+    cfg = G.GAConfig(n=n, c=c, v=v, seed=seed, mode="arith")
+    if kind == "solo":
+        got, want = G.init_state(cfg), _device_seeded(seed, n, v, c)
+    elif kind == "packed":
+        seeds = [seed, seed + 1, seed + 7]
+        got = B._stack_states_seeded(cfg, seeds)
+        want = jax.tree.map(lambda *xs: jnp.stack(xs),
+                            *[_device_seeded(s, n, v, c) for s in seeds])
+    else:
+        got = ISL.init_islands_fast(ISL.IslandConfig(ga=cfg, n_islands=8))
+        want = _device_seeded(seed, n, v, c, n_islands=8)
+    for name, g, w in zip(G.GAState._fields, got, want):
+        assert isinstance(g, jax.Array), name
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        # the warm segment executables are keyed on these: a committed or
+        # weak-typed state would build new cache entries
+        assert not g.committed and not g.weak_type, name
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=name)
+
+
+def test_second_engine_seeds_without_tracing():
+    spec = _spec(n=64, generations=100)
+    ga.Engine(spec, "fused").init_state()
+    eng = ga.Engine(dataclasses.replace(spec, seed=12), "fused")
+    traces = []
+
+    def listen(event, duration, **_):
+        if event == "/jax/core/compile/jaxpr_trace_duration":
+            traces.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        jax.block_until_ready(eng.init_state())
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert traces == []
 
 
 # ---------------------------------------------------------------------------
