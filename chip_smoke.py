@@ -1,6 +1,7 @@
 """Run the GA main path once on a TPU and check it against the reference.
 
-    python chip_smoke.py                # one chip: paper, resident, streamed, serve
+    python chip_smoke.py                # one chip: paper, resident, streamed,
+                                        # bbob, serve
     python chip_smoke.py --four-chips   # island ring over a 4-device mesh only
 
 Every phase drives the user entry points (`ga.solve`, `ga.Engine
@@ -14,6 +15,9 @@ chromosome and fitness.
             gens_per_epoch 1 and 10 (the corners of configs/ga_paper.py)
   resident  rastrigin:10, N=256, 16 islands on `fused-islands`: plan resident
   streamed  rastrigin:10, N=2048, 64 islands on `fused-islands`: plan streamed
+  bbob      bbob_f24:40 (BBOB f24, its 40x40 rotation hoisted into the kernel
+            as a 2-D constant), N=256, 16 islands, 16 bits a variable on
+            `fused-islands`: plan resident
   serve     16 F3 jobs through GAScheduler(max_pack=8) on `fused`; each
             job's result equals its solo run, and every job ends DONE
   --four-chips  rastrigin:10, N=256, 16 islands over a 4-device island
@@ -147,16 +151,16 @@ class Smoke:
         return rows
 
     def islands(self, n: int, n_islands: int, generations: int, plan: str,
-                mesh=None):
-        spec = self.ga.GASpec(problem="rastrigin:10", n=n, bits_per_var=10,
+                mesh=None, problem: str = "rastrigin:10", bits: int = 10):
+        spec = self.ga.GASpec(problem=problem, n=n, bits_per_var=bits,
                               mode="arith", generations=generations, seed=3,
                               n_islands=n_islands, migrate_every=16,
                               gens_per_epoch=64 if mesh is None else 16)
         fused = self.run(spec, "fused-islands", mesh=mesh, expect_plan=plan)
         ref = self.run(dataclasses.replace(spec, gens_per_epoch=1),
                        "islands")
-        self.same_run(fused, ref, f"rastrigin:10 N={n} I={n_islands}")
-        extra = f" N={n} islands={n_islands}"
+        self.same_run(fused, ref, f"{problem} N={n} I={n_islands}")
+        extra = f" problem={problem} N={n} islands={n_islands}"
         if fused[4].tile_islands:
             extra += f" tile={fused[4].tile_islands}"
         if mesh is not None:
@@ -245,6 +249,9 @@ def main() -> int:
                     256, 16, 512, "resident"))
                 smoke.phase("streamed", lambda: smoke.islands(
                     2048, 64, 256, "streamed"))
+                smoke.phase("bbob", lambda: smoke.islands(
+                    256, 16, 256, "resident", problem="bbob_f24:40",
+                    bits=16))
                 smoke.phase("serve", smoke.serve)
         except Exception:   # any phase failure: traceback, no result line
             traceback.print_exc()

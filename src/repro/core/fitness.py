@@ -27,6 +27,7 @@ v = lo + u * (hi - lo) / (2^c - 1), per variable.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
@@ -209,6 +210,100 @@ register_problem(ProblemDef(
         - jnp.exp(vsum(jnp.cos(2.0 * np.pi * v)) / v.shape[-1])
         + 20.0 + np.e),
     domain=(-32.768, 32.768),
+))
+
+
+# --- COCO/BBOB noiseless testbed (Hansen, Finck, Ros & Auger 2009) ---------
+
+
+@dataclasses.dataclass(frozen=True)
+class BbobF24Instance:
+    """Instance arrays of f24 at D variables, each rounded once to float32.
+
+    `mt` is M^T for M = Q diag(lambda) R (the fold reads one row of M^T per
+    variable), `a` the row 2 * sign(x_opt); the scalars are s, mu1 and
+    d * D of RR-6829."""
+
+    mt: np.ndarray          # float32 (D, D)
+    a: np.ndarray           # float32 (1, D)
+    s: np.float32
+    mu1: np.float32
+    d_dim: np.float32
+
+
+BBOB_MU0 = 2.5
+BBOB_F_OPT = 0.0
+
+
+def _orthogonal(rng: np.random.Generator, d: int) -> np.ndarray:
+    """Q of the QR of a standard-normal draw, each column's sign set so
+    that diag(r) > 0 (a Haar-random orthogonal matrix)."""
+    q, r = np.linalg.qr(rng.standard_normal((d, d)))
+    return q * np.sign(np.diag(r))
+
+
+def bbob_f24_instance(d: int) -> BbobF24Instance:
+    """Instance 1 of f24 at D=d, from `numpy.random.default_rng(1)`: R, then
+    Q, then the signs of x_opt, built in float64.  COCO's own instance
+    generator is not used, so x_opt and the rotations differ from COCO's
+    instance 1; f_opt is 0."""
+    if d < 2:
+        raise ValueError(f"bbob_f24 needs at least 2 variables, got {d}")
+    rng = np.random.default_rng(1)
+    r = _orthogonal(rng, d)
+    q = _orthogonal(rng, d)
+    sign = np.where(rng.standard_normal(d) < 0.0, -1.0, 1.0)
+    lam = 100.0 ** (0.5 * np.arange(d) / (d - 1))
+    m = q @ np.diag(lam) @ r
+    s = 1.0 - 1.0 / (2.0 * np.sqrt(d + 20.0) - 8.2)
+    mu1 = -np.sqrt((BBOB_MU0 ** 2 - 1.0) / s)
+    return BbobF24Instance(mt=np.ascontiguousarray(m.T).astype(np.float32),
+                           a=(2.0 * sign).astype(np.float32)[None, :],
+                           s=np.float32(s), mu1=np.float32(mu1),
+                           d_dim=np.float32(1.0 * d))
+
+
+@functools.lru_cache(maxsize=8)
+def bbob_f24(d: int) -> Callable[[jax.Array], jax.Array]:
+    """f24, the Lunacek bi-Rastrigin function of RR-6829, at D=d:
+
+        x^ = 2 sign(x_opt) * x,   u = x^ - mu0,   z = M u
+        f = min(sum u^2, d D + s sum (x^ - mu1)^2)
+            + 10 (D - sum cos(2 pi z)) + 1e4 sum max(0, |x| - 5)^2 + f_opt
+
+    in float32, in the order written: z[..., i] is a left fold over j of
+    M[i, j] u[..., j] (one row of M^T per step, so every element rounds in
+    the same order on every compiler), each sum is `vsum`, and the final
+    sum runs left to right.  The rotation stays on the VPU: a matmul's
+    pass split and accumulation order belong to the compiler.  The two
+    arrays are traced as one (D, D) and one (1, D) constant.  Cached per
+    D, so every program of one D shares one instance and one evaluator."""
+    inst = bbob_f24_instance(d)
+
+    def f(x):
+        mt, a = jnp.asarray(inst.mt), jnp.asarray(inst.a)
+        xh = a * x
+        u = xh - BBOB_MU0
+        z = u[..., 0:1] * mt[0:1]
+        for j in range(1, d):
+            z = z + u[..., j:j + 1] * mt[j:j + 1]
+        w = xh - inst.mu1
+        near = vsum(u * u)
+        far = inst.d_dim + inst.s * vsum(w * w)
+        ras = 10.0 * (np.float32(d) - vsum(jnp.cos(2.0 * np.pi * z)))
+        out = jnp.maximum(jnp.abs(x) - 5.0, 0.0)
+        return (jnp.minimum(near, far) + ras + 1e4 * vsum(out * out)
+                + BBOB_F_OPT)
+
+    return f
+
+
+register_problem(ProblemDef(
+    name="bbob_f24",
+    fn=lambda v: bbob_f24(v.shape[-1])(v),
+    domain=(-5.0, 5.0),
+    default_vars=40,
+    min_vars=2,
 ))
 
 

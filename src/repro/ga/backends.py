@@ -617,10 +617,10 @@ class IslandRingTopology(Topology):
         spec = self.spec
         cfg = (self.cfg if lane == self.cfg.sel_lane
                else dataclasses.replace(self.cfg, sel_lane=lane))
-        const_bytes = (_ga_step.ffm_const_bytes(self.executor.fit, cfg)
-                       if self.executor.name == "fused" else 0)
+        const_vmem = (_ga_step.ffm_const_vmem_bytes(self.executor.fit, cfg)
+                      if self.executor.name == "fused" else 0)
         return _ga_step.epoch_mode_candidates(
-            cfg, self.i_local, const_bytes,
+            cfg, self.i_local, const_vmem,
             executor=self.executor.name, migration=spec.migration,
             gens_per_epoch=spec.gens_per_epoch,
             migrate_every=spec.migrate_every,
@@ -686,14 +686,14 @@ class IslandRingTopology(Topology):
         if plan.get("lane", plan_cfg.sel_lane) != plan_cfg.sel_lane:
             plan_cfg = dataclasses.replace(plan_cfg, sel_lane=plan["lane"])
         if plan["mode"] == "streamed":
-            const_bytes = _ga_step.ffm_const_bytes(self.executor.fit,
-                                                   plan_cfg)
+            const_vmem = _ga_step.ffm_const_vmem_bytes(self.executor.fit,
+                                                       plan_cfg)
             if self.stream_tile_islands is not None:
                 t = int(self.stream_tile_islands)
                 budget = (self.vmem_budget if self.vmem_budget is not None
                           else _ga_step.resident_vmem_budget())
                 need = 2 * _ga_step.resident_vmem_bytes(plan_cfg, t,
-                                                        const_bytes)
+                                                        const_vmem)
                 if self.i_local % t or need > budget:
                     raise ValueError(
                         f"stream_tile_islands={t} is not a feasible tile: "
@@ -704,20 +704,20 @@ class IslandRingTopology(Topology):
             # the double-buffered working set of one tile — what actually
             # occupies VMEM while the grid pipeline streams the stack
             plan["vmem_estimate_bytes"] = 2 * _ga_step.resident_vmem_bytes(
-                plan_cfg, plan["tile_islands"], const_bytes)
+                plan_cfg, plan["tile_islands"], const_vmem)
         elif plan["mode"].startswith("resident"):
-            const_bytes = _ga_step.ffm_const_bytes(self.executor.fit,
-                                                   plan_cfg)
+            const_vmem = _ga_step.ffm_const_vmem_bytes(self.executor.fit,
+                                                       plan_cfg)
             plan["vmem_estimate_bytes"] = _ga_step.resident_vmem_bytes(
-                plan_cfg, self.i_local, const_bytes)
+                plan_cfg, self.i_local, const_vmem)
         elif self.executor.name == "fused":
             # gridded fused launches hold ONE island per program instance —
             # report its lane-aware working set so benches can show the
             # selection lane's VMEM drop, not just gens/s
-            const_bytes = _ga_step.ffm_const_bytes(self.executor.fit,
-                                                   plan_cfg)
+            const_vmem = _ga_step.ffm_const_vmem_bytes(self.executor.fit,
+                                                       plan_cfg)
             plan["vmem_estimate_bytes"] = _ga_step.resident_vmem_bytes(
-                plan_cfg, 1, const_bytes)
+                plan_cfg, 1, const_vmem)
         return plan
 
     @staticmethod

@@ -133,12 +133,24 @@ class Engine:
                                        interpret=interpret,
                                        cost_table=cost_table,
                                        plan_override=plan_override)
-        self.backend_name = resolve_backend(spec, backend, self.options.mesh)
-        # resolved ONCE and shared with every checkpoint write: fault-rule
-        # occurrence counters live on the injector instance
-        self.faults = FLT.resolve_faults(self.options.faults)
-        self.backend: Backend = BACKENDS[self.backend_name](
-            spec, options=self.options)
+        # the construction's host seconds, handed to the first run
+        self._build_s: Dict[str, float] = {}
+        with RT.phase("ga.engine.build", self._build_s):
+            with RT.phase("ga.problem.build", self._build_s):
+                spec.program()
+            self.backend_name = resolve_backend(spec, backend,
+                                                self.options.mesh)
+            # resolved ONCE and shared with every checkpoint write:
+            # fault-rule occurrence counters live on the injector instance
+            self.faults = FLT.resolve_faults(self.options.faults)
+            self.backend: Backend = BACKENDS[self.backend_name](
+                spec, options=self.options)
+
+    def _take_build_phases(self) -> Dict[str, float]:
+        """The construction's phases, once: the first run after it counts
+        them, so a job's phases add up to its host time."""
+        phases, self._build_s = self._build_s, {}
+        return phases
 
     def init_state(self):
         return self.backend.init()
@@ -160,13 +172,25 @@ class Engine:
 
     def run(self, generations: Optional[int] = None,
             state=None) -> EngineResult:
+        """Run `generations` (default the spec's) in one segment.  The
+        result's `telemetry.phase_s` holds the host seconds of the run's
+        spans, under the names `run_chunked` uses, and of the engine's
+        construction on its first run."""
         gens = generations or self.spec.generations
+        phases = self._take_build_phases()
         t0 = time.perf_counter()
         if state is None:
-            state = self.init_state()
-        seg = self.backend.segment(state, gens)
-        jax.block_until_ready(jax.tree.leaves(seg.state))
-        return self._result(seg, time.perf_counter() - t0)
+            with RT.phase("ga.engine.seed", phases):
+                state = self.init_state()
+        with RT.phase("ga.chunk.launch", phases):
+            seg = self.backend.segment(state, gens)
+        with RT.phase("ga.chunk.wait", phases):
+            jax.block_until_ready(jax.tree.leaves(seg.state))
+        wall_s = time.perf_counter() - t0
+        with RT.phase("ga.chunk.readback", phases):
+            res = self._result(seg, wall_s)
+        res.telemetry.phase_s = phases
+        return res
 
     def run_chunked(self, *, chunk_generations: Optional[int] = None,
                     generations: Optional[int] = None,
@@ -185,8 +209,8 @@ class Engine:
 
         Each chunk's dict carries ``"phases"``: the host seconds of its
         `RT.phase` spans, keyed by counter (`launch`, `wait`, `readback`,
-        `ckpt_save`, and `seed` on the first chunk).  ``"wall_s"`` is
-        launch plus wait.
+        `ckpt_save`, and `seed` on the first chunk, with `build` when the
+        engine has not run before).  ``"wall_s"`` is launch plus wait.
 
         Telemetry granularity follows the backend's LAUNCH unit: island
         topologies sample trajectories once per launch, and a resident-epoch
@@ -205,7 +229,7 @@ class Engine:
         scale = self.spec.fitness_scale()
         mini = self.spec.minimize
         span = _span_args(fault_tag)
-        phases: Dict[str, float] = {}
+        phases = self._take_build_phases()
 
         done, chunk_idx, migrations = 0, 0, 0
         resumed_from: Optional[int] = None

@@ -11,11 +11,13 @@ How a segment or engine result executed is a versioned dataclass,
     when the run stacked `n_repeats` replicas.
 
 `version` is bumped whenever a field changes meaning so persisted
-telemetry (e.g. scheduler job streams) stays interpretable.
+telemetry (e.g. scheduler job streams) stays interpretable.  An engine
+result's `phase_s` holds the host seconds of its run's named phases.
 
-`phase` names where a served job's host time goes: one context manager
-that opens a profiler span and adds the seconds it took to a per-phase
-counter dict (`PHASES` fixes the span names and the counter each feeds).
+`phase` names where a job's host time goes, served or solved: one context
+manager that opens a profiler span and adds the seconds it took to a
+per-phase counter dict (`PHASES` fixes the span names and the counter each
+feeds).
 """
 
 from __future__ import annotations
@@ -107,6 +109,9 @@ class RunTelemetry:
     n_vars: Optional[int] = None
     resumed_from: Optional[int] = None   # ckpt step (gens) this segment
                                          # resumed from, first chunk only
+    # host seconds of an `Engine.run`'s named phases, keyed by counter
+    # (`PHASES`): build, seed, launch, wait, readback
+    phase_s: Dict[str, float] = dataclasses.field(default_factory=dict)
 
     def job_view(self) -> "RunTelemetry":
         """Plan/topology facets without the per-repeat arrays — what a
@@ -118,6 +123,8 @@ class RunTelemetry:
 PHASES = {
     "ga.sched.submit": "submit",
     "ga.sched.build": "build",
+    "ga.engine.build": "build",
+    "ga.problem.build": "build",
     "ga.sched.finish": "finish",
     "ga.sched.park": "park",
     "ga.journal.append": "journal",

@@ -209,22 +209,32 @@ def ffm_trace_cache_info():
     return _ffm_jaxpr.cache_info()
 
 
+def _const_block(shape: Tuple[int, ...]) -> Tuple[int, int]:
+    """The 2-D VMEM block a hoisted FFM constant rides in: a matrix as
+    itself (a rotation stays (D, D): turning a lane row back into a matrix
+    would move data from lanes to sublanes), anything else flattened to one
+    (1, size) lane row."""
+    if len(shape) == 2:
+        return tuple(int(d) for d in shape)
+    return (1, max(int(np.prod(shape, dtype=np.int64)), 1))
+
+
 def _hoist_ffm(ffm: FfmStage, n: int, v: int):
     """Lower the FFM stage to a jaxpr and hoist its captured array constants
     into explicit kernel inputs (Pallas kernels cannot capture non-scalar
     constants; `jax.closure_convert` only hoists autodiff-perturbed consts).
-    Returns (conv_fn(x, *consts), const_shapes, flat_consts, const_bytes):
-    each const rides in flattened to one 2-D (1, size) lane row for TPU
-    friendliness and is reshaped back inside the kernel."""
+    Returns (conv_fn(x, *consts), const_shapes, blocks, const_bytes): each
+    const rides in as its `_const_block` and `_bind_consts` restores its
+    shape inside the kernel."""
     closed = _ffm_jaxpr(ffm, n, v)
     consts = closed.consts
     conv = lambda xx, *cs: jax.core.eval_jaxpr(closed.jaxpr, cs, xx)[0]
     const_shapes = tuple(np.shape(c) for c in consts)
-    flat = [jnp.reshape(jnp.asarray(c), (1, max(int(np.size(c)), 1)))
-            for c in consts]
+    blocks = [jnp.reshape(jnp.asarray(c), _const_block(np.shape(c)))
+              for c in consts]
     nbytes = int(sum(int(np.size(c)) * np.dtype(jnp.asarray(c).dtype).itemsize
                      for c in consts))
-    return conv, const_shapes, flat, nbytes
+    return conv, const_shapes, blocks, nbytes
 
 
 def ffm_const_bytes(ffm: FfmStage, cfg: GAConfig) -> int:
@@ -236,6 +246,15 @@ def ffm_const_bytes(ffm: FfmStage, cfg: GAConfig) -> int:
     MB-scale captured arrays)."""
     closed = _ffm_jaxpr(ffm, cfg.n, cfg.v)
     return int(sum(int(np.size(c)) * np.dtype(c.dtype).itemsize
+                   for c in closed.consts))
+
+
+def ffm_const_vmem_bytes(ffm: FfmStage, cfg: GAConfig) -> int:
+    """VMEM bytes of one copy of the FFM stage's hoisted constants, as the
+    kernels lay them out: each in its `_const_block`, padded to (8, 128)
+    tiles of 32-bit words."""
+    closed = _ffm_jaxpr(ffm, cfg.n, cfg.v)
+    return int(sum(_tile_bytes(*_const_block(np.shape(c)))
                    for c in closed.consts))
 
 
@@ -320,23 +339,22 @@ def _island_work_bytes(cfg: GAConfig) -> int:
 
 
 def resident_vmem_bytes(cfg: GAConfig, n_islands: int,
-                        const_bytes: int = 0) -> int:
+                        const_vmem: int = 0) -> int:
     """Estimated VMEM of one kernel program instance holding `n_islands`
     islands, with Mosaic's (8, 128) tile padding: the island blocks, which
     the Pallas pipeline double-buffers, one island's generation temporaries
-    (see `_island_work_bytes`) and the hoisted FFM consts (one (1, k) row
-    each, double-buffered)."""
-    consts = 2 * _tile_bytes(1, max(1, const_bytes // 4)) if const_bytes else 0
+    (see `_island_work_bytes`) and the hoisted FFM consts (`const_vmem`, one
+    copy as `ffm_const_vmem_bytes` lays them out, double-buffered)."""
     return (2 * n_islands * _island_block_bytes(cfg) + _island_work_bytes(cfg)
-            + consts)
+            + 2 * const_vmem)
 
 
-def resident_fit_reason(cfg: GAConfig, n_islands: int, const_bytes: int = 0,
+def resident_fit_reason(cfg: GAConfig, n_islands: int, const_vmem: int = 0,
                         budget: int = None) -> str:
     """None when `n_islands` VMEM-resident islands fit the budget, else the
     reason string — the epoch planner's fallback-to-gridded decision."""
     budget = resident_vmem_budget() if budget is None else budget
-    need = resident_vmem_bytes(cfg, n_islands, const_bytes)
+    need = resident_vmem_bytes(cfg, n_islands, const_vmem)
     if need > budget:
         return (f"resident epoch needs ~{need} B of VMEM for {n_islands} "
                 f"island(s) at N={cfg.n} (> budget {budget} B); falling "
@@ -345,7 +363,7 @@ def resident_fit_reason(cfg: GAConfig, n_islands: int, const_bytes: int = 0,
     return None
 
 
-def streamed_tile_islands(cfg: GAConfig, i_local: int, const_bytes: int = 0,
+def streamed_tile_islands(cfg: GAConfig, i_local: int, const_vmem: int = 0,
                           budget: int = None) -> int:
     """The streamed lane's VMEM tile estimator: the largest island-tile size
     T (a divisor of `i_local`) with 2× its resident estimate within the
@@ -356,12 +374,12 @@ def streamed_tile_islands(cfg: GAConfig, i_local: int, const_bytes: int = 0,
     for t in range(i_local, 0, -1):
         if i_local % t:
             continue
-        if 2 * resident_vmem_bytes(cfg, t, const_bytes) <= budget:
+        if 2 * resident_vmem_bytes(cfg, t, const_vmem) <= budget:
             return t
     return None
 
 
-def epoch_mode_candidates(cfg: GAConfig, i_local: int, const_bytes: int = 0,
+def epoch_mode_candidates(cfg: GAConfig, i_local: int, const_vmem: int = 0,
                           *, executor: str, migration: str,
                           gens_per_epoch: int, migrate_every: int,
                           sharded: bool, budget: int = None) -> list:
@@ -393,9 +411,9 @@ def epoch_mode_candidates(cfg: GAConfig, i_local: int, const_bytes: int = 0,
     if executor != "fused":
         return [gridded]
     if migration == "ring" and gens_per_epoch >= migrate_every:
-        reason = resident_fit_reason(cfg, i_local, const_bytes, budget)
+        reason = resident_fit_reason(cfg, i_local, const_vmem, budget)
         if reason is not None:
-            tile = streamed_tile_islands(cfg, i_local, const_bytes, budget)
+            tile = streamed_tile_islands(cfg, i_local, const_vmem, budget)
             if tile is None:
                 return [dict(gridded, fallback=reason)]
             k = max(1, gens_per_epoch // migrate_every)
@@ -417,12 +435,12 @@ def epoch_mode_candidates(cfg: GAConfig, i_local: int, const_bytes: int = 0,
         # one launch (satellite of the autotune PR).  Gridded stays the
         # heuristic default — resident-free is selected by measurement (or
         # forced via plan_override), never silently.
-        reason = resident_fit_reason(cfg, i_local, const_bytes, budget)
+        reason = resident_fit_reason(cfg, i_local, const_vmem, budget)
         if reason is not None:
             # gridded stays the heuristic for migration="none" (matching the
             # fitting case below); a feasible streamed tile is offered for
             # measurement/plan_override to pick.
-            tile = streamed_tile_islands(cfg, i_local, const_bytes, budget)
+            tile = streamed_tile_islands(cfg, i_local, const_vmem, budget)
             out = [dict(gridded, fallback=reason)]
             if tile is not None:
                 k = max(1, gens_per_epoch // migrate_every)
@@ -633,7 +651,8 @@ def _bind_consts(ffm, const_shapes, rest):
     const_refs, out_refs = rest[:n_consts], rest[n_consts:]
     if not n_consts:
         return ffm, out_refs
-    consts = [r[0].reshape(s) for r, s in zip(const_refs, const_shapes)]
+    consts = [r[...] if len(s) == 2 else r[0].reshape(s)
+              for r, s in zip(const_refs, const_shapes)]
     return (lambda x: ffm(x, *consts)), out_refs
 
 
@@ -710,12 +729,12 @@ def ga_generation_kernel(x, sel, cross, mut, *, cfg: GAConfig,
     # cannot capture non-scalar constants.  Every const rides in replicated
     # (block index 0 on every grid step), which is why oversized consts are
     # rejected by the VMEM gate — see the module docstring.
-    ffm_conv, const_shapes, flat_consts, const_bytes = _hoist_ffm(ffm, n, v)
+    ffm_conv, const_shapes, consts, const_bytes = _hoist_ffm(ffm, n, v)
     _check_const_gate(const_bytes)
 
     blk = lambda *shape: pl.BlockSpec((1,) + shape,
                                       lambda i: (i,) + (0,) * len(shape))
-    cblk = lambda k: pl.BlockSpec((1, k), lambda i: (0, 0))
+    cblk = lambda c: pl.BlockSpec(c.shape, lambda i: (0, 0))
     kernel = functools.partial(_kernel, cfg=cfg, ffm=ffm_conv,
                                const_shapes=const_shapes, gens=gens,
                                track_best=track_best)
@@ -731,13 +750,13 @@ def ga_generation_kernel(x, sel, cross, mut, *, cfg: GAConfig,
     outs = pl.pallas_call(
         kernel,
         grid=(i_islands,),
-        in_specs=state_blks + [cblk(c.shape[1]) for c in flat_consts],
+        in_specs=state_blks + [cblk(c) for c in consts],
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
         name="ga_generation_kernel",
         **_compiler_params(interpret),
-    )(_to_lanes(x), sel, cross, mut, *flat_consts)
+    )(_to_lanes(x), sel, cross, mut, *consts)
     res = (_to_lanes(outs[0]),) + tuple(outs[1:4]) + (outs[4][:, 0],)
     if track_best:
         res += (outs[5][:, 0, 0], outs[6][..., 0])
@@ -835,7 +854,7 @@ def _epoch_call(x, sel, cross, mut, *, cfg: GAConfig, ffm: FfmStage,
     return the outputs in the engine's layout."""
     g_grid, i_islands, n, v = x.shape
     assert (n, v) == (cfg.n, cfg.v)
-    ffm_conv, const_shapes, flat_consts, const_bytes = _hoist_ffm(ffm, n, v)
+    ffm_conv, const_shapes, consts, const_bytes = _hoist_ffm(ffm, n, v)
     _check_const_gate(const_bytes)
     t = tile_islands
 
@@ -848,7 +867,7 @@ def _epoch_call(x, sel, cross, mut, *, cfg: GAConfig, ffm: FfmStage,
 
     shape = lambda *s, dt=jnp.uint32: jax.ShapeDtypeStruct(
         (g_grid, i_islands) + s, dt)
-    cblk = lambda k: pl.BlockSpec((1, k), lambda g, j: (0, 0))
+    cblk = lambda c: pl.BlockSpec(c.shape, lambda g, j: (0, 0))
     state_blks = [blk(v, n), blk(2, n), blk(v, n // 2), blk(v, n)]
     out_specs = state_blks + [blk(1, n), blk(1, 1), blk(v, 1), blk(1, 1)]
     out_shape = [shape(v, n), shape(2, n), shape(v, n // 2), shape(v, n),
@@ -869,13 +888,13 @@ def _epoch_call(x, sel, cross, mut, *, cfg: GAConfig, ffm: FfmStage,
     outs = pl.pallas_call(
         kernel,
         grid=(g_grid, i_islands // t),
-        in_specs=state_blks + [cblk(c.shape[1]) for c in flat_consts],
+        in_specs=state_blks + [cblk(c) for c in consts],
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
         name=name,
         **_compiler_params(interpret),
-    )(_to_lanes(x), sel, cross, mut, *flat_consts)
+    )(_to_lanes(x), sel, cross, mut, *consts)
     res = (_to_lanes(outs[0]),) + tuple(outs[1:4]) + (
         outs[4][..., 0, :], outs[5][..., 0, 0], outs[6][..., 0])
     if ring == "emit":
@@ -920,7 +939,8 @@ def ga_epoch_kernel(x, sel, cross, mut, *, cfg: GAConfig, ffm: FfmStage,
     assert migrate or not boundary, \
         "boundary epochs exist to exchange elites: migrate=False has none"
     i_islands = x.shape[1]
-    reason = resident_fit_reason(cfg, i_islands, ffm_const_bytes(ffm, cfg))
+    reason = resident_fit_reason(cfg, i_islands,
+                                 ffm_const_vmem_bytes(ffm, cfg))
     if reason is not None:
         raise ValueError(reason)
     ring = "boundary" if boundary else ("full" if migrate else "none")
@@ -965,7 +985,7 @@ def ga_streamed_epoch_kernel(x, sel, cross, mut, *, cfg: GAConfig,
     assert i_islands % tile_islands == 0, \
         f"tile_islands={tile_islands} must divide the island count {i_islands}"
     need = 2 * resident_vmem_bytes(cfg, tile_islands,
-                                   ffm_const_bytes(ffm, cfg))
+                                   ffm_const_vmem_bytes(ffm, cfg))
     real_budget = resident_vmem_budget()
     if need > real_budget:
         raise ValueError(

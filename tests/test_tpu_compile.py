@@ -115,6 +115,20 @@ def test_planned_resident_epoch_compiles(one_chip):
     _compile(runner, _island_states(spec, one_chip))
 
 
+def test_planned_bbob_resident_epoch_compiles(one_chip):
+    """BBOB f24 at D=40, 16 islands x N=256, 16 bits a variable (the
+    `bbob-f24-d40` configuration): resident, and the kernel binds the
+    (40, 40) rotation as a 2-D block — Mosaic refuses to reshape a
+    (1, 1600) lane row back into it."""
+    spec = _spec(problem="bbob_f24:40", n=256, n_islands=16,
+                 bits_per_var=16, generations=1024)
+    eng, plan = _plan(spec)
+    assert plan["mode"] == "resident" and plan["lane"] == "onehot"
+    assert plan["gens_per_launch"] == 64
+    runner = eng.backend.topology._resident_runner(plan["epochs_per_launch"])
+    _compile(runner, _island_states(spec, one_chip))
+
+
 def test_planned_streamed_tile_compiles(one_chip):
     """64 islands x N=2048 exceed the budget: the planner streams tiles on
     the gather lane, and the streamed runner compiles."""
