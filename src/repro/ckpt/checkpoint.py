@@ -32,6 +32,7 @@ manifest format are multi-host from day one.
 from __future__ import annotations
 
 import dataclasses
+import io
 import json
 import os
 import shutil
@@ -82,9 +83,12 @@ def save(ckpt_dir: str, step: int, tree, extra: Optional[Dict] = None,
     tmp = path + ".tmp"
     os.makedirs(tmp, exist_ok=True)
     flat = _flatten(tree)
+    # one device_get of every leaf: their copies to the host overlap, so
+    # the save waits one round trip, not one per leaf
+    host = jax.device_get(list(flat.values()))
     arrays, meta = {}, {}
-    for k, v in flat.items():
-        arr = np.asarray(jax.device_get(v))
+    for k, v in zip(flat, host):
+        arr = np.asarray(v)
         logical_dtype = str(arr.dtype)
         if logical_dtype not in ("float64", "float32", "float16", "int64",
                                  "int32", "int16", "int8", "uint64", "uint32",
@@ -95,9 +99,14 @@ def save(ckpt_dir: str, step: int, tree, extra: Optional[Dict] = None,
         meta[k] = {"shape": list(arr.shape), "dtype": logical_dtype}
     shard_name = f"shard_{host_id}.npz"
     shard_path = os.path.join(tmp, shard_name)
-    np.savez(shard_path, **arrays)
-    shards = {shard_name: {"crc32": _crc32_file(shard_path),
-                           "bytes": os.path.getsize(shard_path)}}
+    # the npz is built in memory: its checksum comes from the bytes written,
+    # with no second read of the file
+    buf = io.BytesIO()
+    np.savez(buf, **arrays)
+    data = buf.getbuffer()
+    with open(shard_path, "wb") as f:
+        f.write(data)
+    shards = {shard_name: {"crc32": zlib.crc32(data), "bytes": len(data)}}
     injector = FLT.resolve_faults(faults)
     if injector is not None:
         rule = injector.fires("ckpt_corrupt",
@@ -126,7 +135,7 @@ class AsyncCheckpointer:
         # device_get on the main thread (cheap on CPU; on TPU this is the
         # D2H copy we want off the critical path — but values must be
         # snapshotted before the optimizer mutates them).
-        host_tree = jax.tree.map(lambda x: np.asarray(jax.device_get(x)), tree)
+        host_tree = jax.tree.map(np.asarray, jax.device_get(tree))
 
         def work():
             self.last_path = save(ckpt_dir, step, host_tree, extra)
