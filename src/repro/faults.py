@@ -9,8 +9,10 @@ inert (a dict lookup returning None) when disarmed.
 Injection sites (`SITES`):
 
   * ``chunk_crash``  — raised between a chunk's compute and its checkpoint
-    in `Engine.run_chunked` / `PackedEngine.run_chunked` (a worker dying
-    mid-run; the chunk's work is lost but the previous checkpoint is not);
+    in the engine's one chunk loop, which `Engine.run` (so `ga.solve`),
+    `Engine.run_chunked` and `PackedEngine.run_chunked` all drive (a worker
+    dying mid-run; the chunk's work is lost but the previous checkpoint is
+    not);
   * ``compile_fail`` — raised in the scheduler worker before the packed
     engine is built (a trace/compile blow-up, e.g. a transient OOM);
   * ``ckpt_corrupt`` — not an exception: `repro.ckpt.checkpoint.save`
@@ -35,8 +37,9 @@ Rule grammar — rules separated by ``;``, fields by ``:``::
   * ``delay``   seconds ``slow_chunk`` sleeps (default 0.05).
 
 Arming: pass a rule string / `FaultInjector` through
-``ga.EngineOptions(faults=...)`` (shared by `Engine`, `PackedEngine`,
-`GAScheduler` and the ``--faults`` CLI flag), or set the ambient
+``ga.EngineOptions(faults=...)`` (shared by `Engine` and `PackedEngine`,
+which run one chunk loop, `GAScheduler` and the ``--faults`` CLI flag), or
+set the ambient
 ``REPRO_GA_FAULTS`` environment variable.  `resolve_faults(None)` reads
 the env (memoized per rule string so occurrence counters persist across
 call sites); ``False`` disarms even against the env.

@@ -56,8 +56,9 @@ comes back as typed :class:`RunTelemetry` (``result.telemetry.plan`` /
 
 Operator stages are pluggable protocols with registries
 (`ga.SELECTION` / `ga.CROSSOVER` / `ga.MUTATION`; see
-:mod:`repro.ga.operators`), chunked streaming + checkpoint/resume live on
-:meth:`Engine.run_chunked`.
+:mod:`repro.ga.operators`), chunked streaming + checkpoint/resume live in
+the one chunk loop behind :meth:`Engine.run`, :meth:`Engine.run_chunked` and
+:meth:`PackedEngine.run_chunked`.
 
 The pre-engine entry points (`core.ga.run`/`run_unjitted`,
 `islands.run_local`/`run_sharded`, `kernels.ops.ga_run_kernel`) have been

@@ -21,13 +21,14 @@ Streaming + checkpointing:
 
 Each chunk persists the full backend-native GAState through
 `repro.ckpt.checkpoint`, so a killed run resumes from the last chunk
-(`resume=True`, the default).
+(`resume=True`, the default).  `Engine.run`, `Engine.run_chunked` and
+`PackedEngine.run_chunked` drive one chunk loop (`_Run._chunks`): `run` is
+one chunk with no checkpoint, and an Engine is a pack of one job.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import time
 import warnings
 from typing import Any, Dict, Iterator, Optional
 
@@ -112,8 +113,250 @@ class EngineResult:
         default_factory=RT.RunTelemetry)
 
 
-class Engine:
-    """A spec bound to a backend, with cached compiled runners.
+class _SoloBests:
+    """The bests of one job on the unstacked layout (an `Engine`, or a pack
+    of a single one-repeat job): the segment's best, replaced across chunks
+    only by a strictly better one.  Checkpointed as `best_y` / `best_x`."""
+
+    def __init__(self, spec: GASpec):
+        self.specs = [spec]
+        self.y: Optional[float] = None
+        self.x = None
+
+    def update(self, seg: Segment) -> None:
+        mini = self.specs[0].minimize
+        if self.y is None or (seg.best_y < self.y if mini
+                              else seg.best_y > self.y):
+            self.y, self.x = seg.best_y, np.asarray(seg.best_x)
+        self.chunk_y, self.traj = seg.best_y, np.asarray(seg.traj_best)
+
+    def job(self, j: int):
+        """Job j's best and chromosome, and its best and trajectory in the
+        last segment (none when a resumed run had already finished)."""
+        return self.y, self.x, self.chunk_y, self.traj
+
+    def extra(self) -> Dict[str, Any]:
+        return {"best_y": float(self.y), "best_x": [int(v) for v in self.x]}
+
+    def load(self, extra: Dict[str, Any], where: str) -> None:
+        self.y = float(extra["best_y"])
+        self.x = np.asarray(extra["best_x"], np.uint32)
+        self.chunk_y, self.traj = self.y, np.empty((0,))
+
+    def fill(self, y: np.ndarray, x: np.ndarray) -> None:
+        """Take the bests of the one slot sliced out of a pack."""
+        self.y, self.x = float(y[0]), x[0]
+
+
+class _SlotBests:
+    """The bests of a pack on the stacked layout: one per slot, from the
+    segment's per-replica stats, each replaced only by a strictly better
+    one; a job's best is the best of its slots.  Checkpointed as `slot_y` /
+    `slot_x` with the slot `seeds`, which a resume must match."""
+
+    def __init__(self, pe: "PackedEngine"):
+        self.specs, self.slots, self.seeds = pe.specs, pe.slots, pe.seeds
+        self.mini = pe.batch_spec.minimize
+        self.y = np.full((pe.n_slots,), np.inf if self.mini else -np.inf,
+                         np.float32)
+        self.x = np.zeros((pe.n_slots, pe.batch_spec.v), np.uint32)
+
+    def update(self, seg: Segment) -> None:
+        rep = seg.telemetry.per_repeat
+        by = np.asarray(rep.best, np.float32).reshape(self.y.shape)
+        bx = np.asarray(rep.best_x, np.uint32).reshape(self.x.shape)
+        better = by < self.y if self.mini else by > self.y
+        self.y = np.where(better, by, self.y)
+        self.x = np.where(better[:, None], bx, self.x)
+        self.chunk_y = by
+        self.traj = np.asarray(rep.traj_best,
+                               np.float32).reshape(len(by), -1)
+
+    def job(self, j: int):
+        off, cnt = self.slots[j]
+        yj = self.y[off:off + cnt]
+        r = off + (int(np.argmin(yj)) if self.mini else int(np.argmax(yj)))
+        reduce = np.min if self.mini else np.max
+        return (float(self.y[r]), self.x[r],
+                float(reduce(self.chunk_y[off:off + cnt])),
+                reduce(self.traj[off:off + cnt], axis=0))
+
+    def extra(self) -> Dict[str, Any]:
+        return {"slot_y": [float(v) for v in self.y],
+                "slot_x": [[int(v) for v in row] for row in self.x],
+                "seeds": [int(s) for s in self.seeds]}
+
+    def load(self, extra: Dict[str, Any], where: str) -> None:
+        ck_seeds = [int(s) for s in extra.get("seeds", [])]
+        if ck_seeds and ck_seeds != [int(s) for s in self.seeds]:
+            raise ValueError(
+                f"checkpoint in {where} holds a pack with slot seeds "
+                f"{ck_seeds}, not {list(self.seeds)} — a pack must resume "
+                "with the same jobs in the same order")
+        self.y = np.asarray(extra["slot_y"], np.float32)
+        self.x = np.asarray(extra["slot_x"], np.uint32).reshape(self.x.shape)
+        self.chunk_y, self.traj = self.y, self.y[:, None]
+
+    def fill(self, y: np.ndarray, x: np.ndarray) -> None:
+        """Take the bests of the slots sliced out of a pack."""
+        self.y, self.x = y, x
+
+
+class _Run:
+    """The backend over the replica slots of one or more jobs, and the one
+    chunk loop that `Engine` and `PackedEngine` both drive: seeding or
+    restoring the state, each chunk's launch, wait, readback and
+    checkpoint, and the spans and fault sites around them.  How the bests
+    are kept is the subclass's `_bests()`."""
+
+    def _build(self, spec: GASpec, backend: str) -> None:
+        """Resolve and construct the backend for `spec`; the seconds this
+        takes are handed to the first run."""
+        self._build_s: Dict[str, float] = {}
+        with RT.phase("ga.engine.build", self._build_s):
+            with RT.phase("ga.problem.build", self._build_s):
+                spec.program()
+            self.backend_name = resolve_backend(spec, backend,
+                                                self.options.mesh)
+            # resolved ONCE and shared with every checkpoint write:
+            # fault-rule occurrence counters live on the injector instance
+            self.faults = FLT.resolve_faults(self.options.faults)
+            self.backend: Backend = BACKENDS[self.backend_name](
+                spec, options=self.options)
+
+    def _take_build_phases(self) -> Dict[str, float]:
+        """The construction's phases, once: the first run after it counts
+        them, so a job's phases add up to its host time."""
+        phases, self._build_s = self._build_s, {}
+        return phases
+
+    def _restore(self, ckpt_dir: str, step: int, state, bests):
+        """Load checkpoint `step` into `state` and `bests`; returns the state
+        and the run's counters (gens done, chunk index, migrations)."""
+        state, extra = CKPT.restore(ckpt_dir, step, state)
+        ck_backend = extra.get("backend")
+        if ck_backend is not None and ck_backend != self.backend_name:
+            raise ValueError(
+                f"checkpoint in {ckpt_dir} was written by the "
+                f"{ck_backend!r} backend; resuming it with "
+                f"{self.backend_name!r} would load a mismatched state "
+                "layout — rerun with the original backend or a fresh "
+                "ckpt_dir")
+        bests.load(extra, ckpt_dir)
+        return (state, int(extra["gens_done"]),
+                int(extra.get("chunk_idx", 0)),
+                int(extra.get("migrations", 0)))
+
+    def _extra(self, done: int, chunk_idx: int, migrations: int,
+               bests) -> Dict[str, Any]:
+        return {"gens_done": done, "chunk_idx": chunk_idx,
+                "migrations": migrations, **bests.extra(),
+                "backend": self.backend_name}
+
+    def _chunks(self, total: int, chunk_generations: Optional[int], *,
+                ckpt_dir: Optional[str] = None, resume: bool = True,
+                fault_tag: str = "", state=None):
+        """Run `total` generations chunk by chunk.  Yields `(pack, jobs,
+        seg)` per chunk: the pack-level telemetry, one telemetry dict per
+        job (the pack's fields and the job's bests) and the chunk's
+        Segment.  A resumed run that had already finished yields its stored
+        result once, with `seg` None."""
+        # the default chunk never undercuts gens_per_epoch: a chunk smaller
+        # than one resident launch would cap the interval folding the spec
+        # asked for (an explicit chunk_generations is honored as given)
+        chunk = chunk_generations or max(1, total // 10,
+                                         self.backend.spec.gens_per_epoch)
+        span = _span_args(fault_tag)
+        phases = self._take_build_phases()
+        bests = self._bests()
+        done = chunk_idx = migrations = 0
+        resumed_from: Optional[int] = None
+        if state is None:
+            with RT.phase("ga.engine.seed", phases, **span):
+                state = self.init_state()
+                step = (CKPT.latest_step(ckpt_dir) if ckpt_dir and resume
+                        else None)
+                if step is not None:
+                    resumed_from = int(step)
+                    state, done, chunk_idx, migrations = self._restore(
+                        ckpt_dir, step, state, bests)
+
+        def pack_tele(gens, dt, rate):
+            return {"chunk": chunk_idx, "resumed_from": resumed_from,
+                    "gens_done": done, "gens_total": total,
+                    "chunk_gens": gens, "wall_s": dt, "gens_per_s": rate,
+                    "backend": self.backend_name, "phases": phases}
+
+        if done >= total:
+            # resumed a finished run: surface the stored result instead of
+            # yielding nothing
+            pack = dict(pack_tele(0, 0.0, 0.0), already_complete=True)
+            yield pack, self._jobs(bests, pack, None, migrations), None
+            return
+
+        while done < total:
+            tag = f"{fault_tag}|{self.backend_name}|chunk={chunk_idx + 1}"
+            with RT.phase("ga.chunk", chunk=chunk_idx + 1, **span):
+                if self.faults is not None:
+                    self.faults.inject("slow_chunk", tag)
+                with RT.phase("ga.chunk.launch", phases, **span):
+                    seg = self.backend.segment(state, min(chunk, total - done))
+                with RT.phase("ga.chunk.wait", phases, **span):
+                    jax.block_until_ready(jax.tree.leaves(seg.state))
+                dt = phases["launch"] + phases["wait"]
+                if self.faults is not None:
+                    # crash AFTER the compute, BEFORE the checkpoint: the
+                    # chunk's work is lost, earlier checkpoints are not, and
+                    # a retry recomputes it deterministically
+                    self.faults.inject("chunk_crash", tag)
+                with RT.phase("ga.chunk.readback", phases, **span):
+                    state = seg.state
+                    done += seg.gens
+                    chunk_idx += 1
+                    migrations += seg.telemetry.topology.migrations
+                    if resumed_from is not None:
+                        seg.telemetry.resumed_from = resumed_from
+                    bests.update(seg)
+                    pack = pack_tele(seg.gens, dt, seg.gens / dt if dt > 0
+                                     else float("inf"))
+                    jobs = self._jobs(bests, pack, seg, migrations)
+                if ckpt_dir:
+                    with RT.phase("ga.ckpt.save", phases, **span):
+                        CKPT.save(ckpt_dir, step=done, tree=state,
+                                  extra=self._extra(done, chunk_idx,
+                                                    migrations, bests),
+                                  faults=self.faults, fault_tag=fault_tag)
+            yield pack, jobs, seg
+            phases = {}
+            resumed_from = None    # only the first post-resume chunk carries it
+
+    @staticmethod
+    def _jobs(bests, pack, seg: Optional[Segment], migrations: int):
+        """One telemetry dict per job: the pack's fields and its bests."""
+        rt = seg.telemetry if seg is not None else None
+        jobs = []
+        for j, spec in enumerate(bests.specs):
+            scale = spec.fitness_scale()
+            y, x, chunk_y, traj = bests.job(j)
+            jobs.append({
+                **pack,
+                "chunk_best": chunk_y / scale,
+                "best_fitness": y / scale,
+                "best_params": spec.decode(x),
+                "traj_best": traj / scale,
+                "problem": spec.problem or "blackbox",
+                "n_vars": spec.v,
+                "migrations": migrations,
+                "telemetry_unit_gens": (rt.topology.telemetry_unit_gens
+                                        if rt is not None else 1),
+                "telemetry": rt,
+            })
+        return jobs
+
+
+class Engine(_Run):
+    """A spec bound to a backend, with cached compiled runners: a pack of
+    one job on the unstacked layout.
 
     Execution knobs ride in one frozen `ga.EngineOptions` (`options=`);
     the legacy `mesh= / interpret= / cost_table= / plan_override=` kwargs
@@ -133,64 +376,35 @@ class Engine:
                                        interpret=interpret,
                                        cost_table=cost_table,
                                        plan_override=plan_override)
-        # the construction's host seconds, handed to the first run
-        self._build_s: Dict[str, float] = {}
-        with RT.phase("ga.engine.build", self._build_s):
-            with RT.phase("ga.problem.build", self._build_s):
-                spec.program()
-            self.backend_name = resolve_backend(spec, backend,
-                                                self.options.mesh)
-            # resolved ONCE and shared with every checkpoint write:
-            # fault-rule occurrence counters live on the injector instance
-            self.faults = FLT.resolve_faults(self.options.faults)
-            self.backend: Backend = BACKENDS[self.backend_name](
-                spec, options=self.options)
-
-    def _take_build_phases(self) -> Dict[str, float]:
-        """The construction's phases, once: the first run after it counts
-        them, so a job's phases add up to its host time."""
-        phases, self._build_s = self._build_s, {}
-        return phases
+        self._build(spec, backend)
 
     def init_state(self):
         return self.backend.init()
 
-    def _result(self, seg: Segment, wall_s: float) -> EngineResult:
-        scale = self.spec.fitness_scale()
-        tele = seg.telemetry
-        if tele.problem is None:
-            tele.problem = self.spec.problem or "blackbox"
-            tele.n_vars = self.spec.v
-        return EngineResult(
-            spec=self.spec, backend=self.backend_name,
-            best_fitness=seg.best_y / scale,
-            best_x=np.asarray(seg.best_x, np.uint32),
-            best_params=self.spec.decode(seg.best_x),
-            traj_best=np.asarray(seg.traj_best) / scale,
-            traj_mean=np.asarray(seg.traj_mean) / scale,
-            generations=seg.gens, wall_s=wall_s, telemetry=tele)
+    def _bests(self):
+        return _SoloBests(self.spec)
 
     def run(self, generations: Optional[int] = None,
             state=None) -> EngineResult:
-        """Run `generations` (default the spec's) in one segment.  The
-        result's `telemetry.phase_s` holds the host seconds of the run's
-        spans, under the names `run_chunked` uses, and of the engine's
-        construction on its first run."""
+        """Run `generations` (default the spec's) as one chunk with no
+        checkpoint.  The result's `telemetry.phase_s` holds the host seconds
+        of the run's spans, under the names `run_chunked` uses, and of the
+        engine's construction on its first run."""
         gens = generations or self.spec.generations
-        phases = self._take_build_phases()
-        t0 = time.perf_counter()
-        if state is None:
-            with RT.phase("ga.engine.seed", phases):
-                state = self.init_state()
-        with RT.phase("ga.chunk.launch", phases):
-            seg = self.backend.segment(state, gens)
-        with RT.phase("ga.chunk.wait", phases):
-            jax.block_until_ready(jax.tree.leaves(seg.state))
-        wall_s = time.perf_counter() - t0
-        with RT.phase("ga.chunk.readback", phases):
-            res = self._result(seg, wall_s)
-        res.telemetry.phase_s = phases
-        return res
+        [(_, [tele], seg)] = self._chunks(gens, gens, state=state)
+        rt = seg.telemetry
+        if rt.problem is None:
+            rt.problem, rt.n_vars = tele["problem"], tele["n_vars"]
+        rt.phase_s = tele["phases"]
+        return EngineResult(
+            spec=self.spec, backend=self.backend_name,
+            best_fitness=tele["best_fitness"],
+            best_x=np.asarray(seg.best_x, np.uint32),
+            best_params=tele["best_params"], traj_best=tele["traj_best"],
+            traj_mean=np.asarray(seg.traj_mean) / self.spec.fitness_scale(),
+            generations=seg.gens,
+            wall_s=rt.phase_s.get("seed", 0.0) + tele["wall_s"],
+            telemetry=rt)
 
     def run_chunked(self, *, chunk_generations: Optional[int] = None,
                     generations: Optional[int] = None,
@@ -220,119 +434,10 @@ class Engine:
         `migrations` counts every ring migration including the ones folded
         inside resident launches.
         """
-        total = generations or self.spec.generations
-        # the default chunk never undercuts gens_per_epoch: a chunk smaller
-        # than one resident launch would cap the interval folding the spec
-        # asked for (an explicit chunk_generations is honored as given)
-        chunk = chunk_generations or max(1, total // 10,
-                                         self.spec.gens_per_epoch)
-        scale = self.spec.fitness_scale()
-        mini = self.spec.minimize
-        span = _span_args(fault_tag)
-        phases = self._take_build_phases()
-
-        done, chunk_idx, migrations = 0, 0, 0
-        resumed_from: Optional[int] = None
-        best_y: Optional[float] = None
-        best_x = None
-        with RT.phase("ga.engine.seed", phases, **span):
-            state = self.init_state()
-            step = CKPT.latest_step(ckpt_dir) if ckpt_dir and resume else None
-            if step is not None:
-                resumed_from = int(step)
-                state, extra = CKPT.restore(ckpt_dir, step, state)
-                ck_backend = extra.get("backend")
-                if ck_backend is not None and ck_backend != self.backend_name:
-                    raise ValueError(
-                        f"checkpoint in {ckpt_dir} was written by the "
-                        f"{ck_backend!r} backend; resuming it with "
-                        f"{self.backend_name!r} would load a mismatched "
-                        "state layout — rerun with the original backend or "
-                        "a fresh ckpt_dir")
-                done = int(extra["gens_done"])
-                chunk_idx = int(extra.get("chunk_idx", 0))
-                migrations = int(extra.get("migrations", 0))
-                best_y = float(extra["best_y"])
-                best_x = np.asarray(extra["best_x"], np.uint32)
-
-        if done >= total and best_y is not None:
-            # resumed a finished run: surface the stored result instead of
-            # yielding nothing
-            yield {
-                "chunk": chunk_idx, "gens_done": done, "gens_total": total,
-                "chunk_gens": 0, "chunk_best": best_y / scale,
-                "best_fitness": best_y / scale,
-                "best_params": self.spec.decode(best_x),
-                "traj_best": np.empty((0,)), "wall_s": 0.0,
-                "gens_per_s": 0.0, "backend": self.backend_name,
-                "problem": self.spec.problem or "blackbox",
-                "n_vars": self.spec.v,
-                "migrations": migrations,
-                "already_complete": True,
-                "phases": phases,
-            }
-            return
-
-        while done < total:
-            tag = f"{fault_tag}|{self.backend_name}|chunk={chunk_idx + 1}"
-            with RT.phase("ga.chunk", chunk=chunk_idx + 1, **span):
-                if self.faults is not None:
-                    self.faults.inject("slow_chunk", tag)
-                with RT.phase("ga.chunk.launch", phases, **span):
-                    seg = self.backend.segment(state, min(chunk, total - done))
-                with RT.phase("ga.chunk.wait", phases, **span):
-                    jax.block_until_ready(jax.tree.leaves(seg.state))
-                dt = phases["launch"] + phases["wait"]
-                if self.faults is not None:
-                    # crash AFTER the compute, BEFORE the checkpoint: the
-                    # chunk's work is lost, earlier checkpoints are not, and
-                    # a retry recomputes it deterministically
-                    self.faults.inject("chunk_crash", tag)
-                with RT.phase("ga.chunk.readback", phases, **span):
-                    state = seg.state
-                    done += seg.gens
-                    chunk_idx += 1
-                    migrations += seg.telemetry.topology.migrations
-                    if resumed_from is not None:
-                        seg.telemetry.resumed_from = resumed_from
-                    if best_y is None or (seg.best_y < best_y if mini
-                                          else seg.best_y > best_y):
-                        best_y, best_x = seg.best_y, np.asarray(seg.best_x)
-                    tele = {
-                        "chunk": chunk_idx,
-                        "resumed_from": resumed_from,
-                        "gens_done": done,
-                        "gens_total": total,
-                        "chunk_gens": seg.gens,
-                        "chunk_best": seg.best_y / scale,
-                        "best_fitness": best_y / scale,
-                        "best_params": self.spec.decode(best_x),
-                        "traj_best": np.asarray(seg.traj_best) / scale,
-                        "wall_s": dt,
-                        "gens_per_s": (seg.gens / dt if dt > 0
-                                       else float("inf")),
-                        "backend": self.backend_name,
-                        "problem": self.spec.problem or "blackbox",
-                        "n_vars": self.spec.v,
-                        "migrations": migrations,
-                        "telemetry_unit_gens": seg.telemetry.topology
-                                                  .telemetry_unit_gens,
-                        "telemetry": seg.telemetry,
-                    }
-                if ckpt_dir:
-                    with RT.phase("ga.ckpt.save", phases, **span):
-                        CKPT.save(ckpt_dir, step=done, tree=state,
-                                  extra={"gens_done": done,
-                                         "chunk_idx": chunk_idx,
-                                         "migrations": migrations,
-                                         "best_y": float(best_y),
-                                         "best_x": [int(v) for v in best_x],
-                                         "backend": self.backend_name},
-                                  faults=self.faults, fault_tag=fault_tag)
-                tele["phases"] = phases
+        for _, [tele], _ in self._chunks(
+                generations or self.spec.generations, chunk_generations,
+                ckpt_dir=ckpt_dir, resume=resume, fault_tag=fault_tag):
             yield tele
-            phases = {}
-            resumed_from = None    # only the first post-resume chunk carries it
 
 
 def _span_args(fault_tag: str) -> Dict[str, str]:
@@ -352,7 +457,7 @@ def solve(spec: GASpec, backend: str = "auto", *,
                   plan_override=plan_override).run(generations)
 
 
-class PackedEngine:
+class PackedEngine(_Run):
     """K shape-compatible GASpecs multiplexed through ONE backend run.
 
     The engine already vmaps `n_repeats` independent replicas down a stack
@@ -361,14 +466,16 @@ class PackedEngine:
     would use alone — so every slot, and therefore every job's result, is
     bit-identical to running that job solo (the per-replica bit-identity the
     repeat tests already pin down).  Specs must share `compile_key()` and
-    `generations`; only seeds and repeat counts may differ.
+    `generations`; only seeds and repeat counts may differ.  A single
+    one-repeat job has no stack axis to pack: it runs the unstacked layout,
+    and keeps and checkpoints its bests as an `Engine` does.
 
         pe = PackedEngine([spec_a, spec_b, spec_c])
         for tele in pe.run_chunked(ckpt_dir="/tmp/pack"):
             for jt in tele["jobs"]:
                 print(jt["job_index"], jt["best_fitness"])
 
-    `run_chunked` mirrors `Engine.run_chunked` (chunked telemetry +
+    `run_chunked` is `Engine.run_chunked`'s loop (chunked telemetry +
     checkpoint/resume — the scheduler's preemption primitive) but yields a
     pack-level dict whose `"jobs"` list carries one Engine-style telemetry
     dict per job, unpacked from the segment's per-replica telemetry."""
@@ -396,67 +503,28 @@ class PackedEngine:
                     "stack lock-step); submit unequal-length jobs separately")
         self.specs = specs
         self.slots, self.seeds = [], []
-        off = 0
         for s in specs:
-            self.slots.append((off, s.n_repeats))
+            self.slots.append((len(self.seeds), s.n_repeats))
             self.seeds.extend(s.seed + r for r in range(s.n_repeats))
-            off += s.n_repeats
-        self.n_slots = off
-        self.batch_spec = dataclasses.replace(specs[0], n_repeats=self.n_slots)
-        self.backend_name = resolve_backend(self.batch_spec, backend,
-                                            self.options.mesh)
-        self.faults = FLT.resolve_faults(self.options.faults)
+        self.n_slots = len(self.seeds)
+        self.batch_spec = (specs[0] if len(specs) == 1 else
+                           dataclasses.replace(specs[0],
+                                               n_repeats=self.n_slots))
+        self._build(self.batch_spec, backend)
         if self.backend_name == "eager":
             raise BackendUnsupported(
                 "the eager backend steps replicas in a host loop — nothing "
                 "to pack; run eager jobs singly")
-        # a single 1-repeat job has no stack axis to pack: delegate to the
-        # plain Engine (same result layout, zero packing overhead)
-        self._solo: Optional[Engine] = None
-        if self.n_slots == 1:
-            self._solo = Engine(specs[0], self.backend_name,
-                                options=self.options)
-            self.backend = self._solo.backend
-        else:
-            self.backend = BACKENDS[self.backend_name](
-                self.batch_spec, options=self.options)
 
     def init_state(self):
-        if self._solo is not None:
-            return self._solo.init_state()
+        if self.n_slots == 1:
+            return self.backend.init()
         return self.backend.init_packed(list(self.seeds))
 
-    def _job_tele(self, j: int, *, chunk_idx, done, total, dt, seg_gens,
-                  slot_y, slot_x, chunk_y, traj, migrations, telemetry):
-        off, cnt = self.slots[j]
-        spec = self.specs[j]
-        scale = spec.fitness_scale()
-        mini = spec.minimize
-        yj = slot_y[off:off + cnt]
-        r = off + (int(np.argmin(yj)) if mini else int(np.argmax(yj)))
-        cyj = chunk_y[off:off + cnt]
-        tj = traj[off:off + cnt]                     # [r_j, T]
-        return {
-            "chunk": chunk_idx, "gens_done": done, "gens_total": total,
-            "chunk_gens": seg_gens,
-            "chunk_best": float(np.min(cyj) if mini else np.max(cyj)) / scale,
-            "best_fitness": float(slot_y[r]) / scale,
-            "best_params": spec.decode(slot_x[r]),
-            "traj_best": (np.min(tj, axis=0) if mini
-                          else np.max(tj, axis=0)) / scale,
-            "wall_s": dt,
-            "gens_per_s": seg_gens / dt if dt > 0 else float("inf"),
-            "backend": self.backend_name,
-            "problem": spec.problem or "blackbox",
-            "n_vars": spec.v,
-            "migrations": migrations,
-            "telemetry_unit_gens": (telemetry.topology.telemetry_unit_gens
-                                    if telemetry is not None else 1),
-            "job_index": j, "pack_size": len(self.specs),
-            "slots": (off, cnt),
-            "telemetry": (telemetry.job_view()
-                          if telemetry is not None else None),
-        }
+    def _bests(self):
+        if self.n_slots == 1:
+            return _SoloBests(self.specs[0])
+        return _SlotBests(self)
 
     def run_chunked(self, *, chunk_generations: Optional[int] = None,
                     ckpt_dir: Optional[str] = None,
@@ -467,134 +535,15 @@ class PackedEngine:
         chunk checkpoints the whole packed state + per-slot bests, so an
         abandoned run (preemption) resumes bit-identically — the checkpoint
         records the slot seeds and refuses a mismatched pack composition."""
-        if self._solo is not None:
-            for tele in self._solo.run_chunked(
-                    chunk_generations=chunk_generations,
-                    ckpt_dir=ckpt_dir, resume=resume, fault_tag=fault_tag):
-                jt = dict(tele)
-                jt.update(job_index=0, pack_size=1, slots=(0, 1))
-                yield {"chunk": tele["chunk"], "gens_done": tele["gens_done"],
-                       "gens_total": tele["gens_total"],
-                       "chunk_gens": tele["chunk_gens"],
-                       "wall_s": tele["wall_s"],
-                       "gens_per_s": tele["gens_per_s"],
-                       "backend": self.backend_name, "pack_size": 1,
-                       "phases": tele["phases"], "jobs": [jt]}
-            return
-
-        spec = self.batch_spec
-        total = spec.generations
-        chunk = chunk_generations or max(1, total // 10, spec.gens_per_epoch)
-        mini = spec.minimize
-        L = self.n_slots
-        span = _span_args(fault_tag)
-        phases: Dict[str, float] = {}
-
-        done, chunk_idx, migrations = 0, 0, 0
-        resumed_from: Optional[int] = None
-        slot_y = np.full((L,), np.inf if mini else -np.inf, np.float32)
-        slot_x = np.zeros((L, spec.v), np.uint32)
-        with RT.phase("ga.engine.seed", phases, **span):
-            state = self.init_state()
-            step = CKPT.latest_step(ckpt_dir) if ckpt_dir and resume else None
-            if step is not None:
-                resumed_from = int(step)
-                state, extra = CKPT.restore(ckpt_dir, step, state)
-                ck_backend = extra.get("backend")
-                if ck_backend is not None and ck_backend != self.backend_name:
-                    raise ValueError(
-                        f"checkpoint in {ckpt_dir} was written by the "
-                        f"{ck_backend!r} backend; resuming it with "
-                        f"{self.backend_name!r} would load a mismatched "
-                        "state layout")
-                ck_seeds = [int(s) for s in extra.get("seeds", [])]
-                if ck_seeds and ck_seeds != [int(s) for s in self.seeds]:
-                    raise ValueError(
-                        f"checkpoint in {ckpt_dir} holds a pack with slot "
-                        f"seeds {ck_seeds}, not {list(self.seeds)} — a pack "
-                        "must resume with the same jobs in the same order")
-                done = int(extra["gens_done"])
-                chunk_idx = int(extra.get("chunk_idx", 0))
-                migrations = int(extra.get("migrations", 0))
-                slot_y = np.asarray(extra["slot_y"], np.float32)
-                slot_x = np.asarray(extra["slot_x"],
-                                    np.uint32).reshape(L, spec.v)
-
-        if done >= total:
-            # resumed a finished pack: surface the stored per-job results
-            jobs = [self._job_tele(
-                j, chunk_idx=chunk_idx, done=done, total=total, dt=0.0,
-                seg_gens=0, slot_y=slot_y, slot_x=slot_x, chunk_y=slot_y,
-                traj=slot_y[:, None], migrations=migrations, telemetry=None)
-                for j in range(len(self.specs))]
-            for jt in jobs:
-                jt["phases"] = phases
-            yield {
-                "chunk": chunk_idx, "gens_done": done, "gens_total": total,
-                "chunk_gens": 0, "wall_s": 0.0, "gens_per_s": 0.0,
-                "backend": self.backend_name, "pack_size": len(self.specs),
-                "already_complete": True, "phases": phases, "jobs": jobs,
-            }
-            return
-
-        while done < total:
-            tag = f"{fault_tag}|{self.backend_name}|chunk={chunk_idx + 1}"
-            with RT.phase("ga.chunk", chunk=chunk_idx + 1, **span):
-                if self.faults is not None:
-                    self.faults.inject("slow_chunk", tag)
-                with RT.phase("ga.chunk.launch", phases, **span):
-                    seg = self.backend.segment(state, min(chunk, total - done))
-                with RT.phase("ga.chunk.wait", phases, **span):
-                    jax.block_until_ready(jax.tree.leaves(seg.state))
-                dt = phases["launch"] + phases["wait"]
-                if self.faults is not None:
-                    # crash AFTER the compute, BEFORE the checkpoint (see
-                    # Engine)
-                    self.faults.inject("chunk_crash", tag)
-                with RT.phase("ga.chunk.readback", phases, **span):
-                    state = seg.state
-                    done += seg.gens
-                    chunk_idx += 1
-                    migrations += seg.telemetry.topology.migrations
-                    if resumed_from is not None:
-                        seg.telemetry.resumed_from = resumed_from
-                    rep = seg.telemetry.per_repeat
-                    by = np.asarray(rep.best, np.float32).reshape(L)
-                    bx = np.asarray(rep.best_x, np.uint32).reshape(L, spec.v)
-                    traj = np.asarray(rep.traj_best, np.float32).reshape(L, -1)
-                    better = by < slot_y if mini else by > slot_y
-                    slot_y = np.where(better, by, slot_y)
-                    slot_x = np.where(better[:, None], bx, slot_x)
-                    jobs = [self._job_tele(
-                        j, chunk_idx=chunk_idx, done=done, total=total, dt=dt,
-                        seg_gens=seg.gens, slot_y=slot_y, slot_x=slot_x,
-                        chunk_y=by, traj=traj, migrations=migrations,
-                        telemetry=seg.telemetry)
-                        for j in range(len(self.specs))]
-                if ckpt_dir:
-                    with RT.phase("ga.ckpt.save", phases, **span):
-                        CKPT.save(ckpt_dir, step=done, tree=state,
-                                  extra={"gens_done": done,
-                                         "chunk_idx": chunk_idx,
-                                         "migrations": migrations,
-                                         "slot_y": [float(v) for v in slot_y],
-                                         "slot_x": [[int(v) for v in row]
-                                                    for row in slot_x],
-                                         "seeds": [int(s) for s in self.seeds],
-                                         "backend": self.backend_name},
-                                  faults=self.faults, fault_tag=fault_tag)
-                for jt in jobs:
-                    jt["phases"] = phases
-            yield {
-                "chunk": chunk_idx, "resumed_from": resumed_from,
-                "gens_done": done, "gens_total": total,
-                "chunk_gens": seg.gens, "wall_s": dt,
-                "gens_per_s": seg.gens / dt if dt > 0 else float("inf"),
-                "backend": self.backend_name, "pack_size": len(self.specs),
-                "phases": phases, "jobs": jobs,
-            }
-            phases = {}
-            resumed_from = None
+        for pack, jobs, _ in self._chunks(
+                self.batch_spec.generations, chunk_generations,
+                ckpt_dir=ckpt_dir, resume=resume, fault_tag=fault_tag):
+            for j, jt in enumerate(jobs):
+                rt = jt["telemetry"]
+                jt.update(job_index=j, pack_size=len(self.specs),
+                          slots=self.slots[j],
+                          telemetry=rt.job_view() if rt is not None else None)
+            yield {**pack, "pack_size": len(self.specs), "jobs": jobs}
 
     def run(self, *, chunk_generations: Optional[int] = None):
         """Run the pack to completion; returns the final per-job telemetry
@@ -626,19 +575,9 @@ def repack_checkpoint(old_dir: str, specs, keep, new_dir: str,
     step = CKPT.latest_step(old_dir)
     if step is None:
         return None
-    state, extra = CKPT.restore(old_dir, step, pe_old.init_state())
-    ck_backend = extra.get("backend")
-    if ck_backend is not None and ck_backend != pe_old.backend_name:
-        raise ValueError(
-            f"checkpoint in {old_dir} was written by the {ck_backend!r} "
-            f"backend, not {pe_old.backend_name!r}; repack with the "
-            "original backend")
-    ck_seeds = [int(s) for s in extra.get("seeds", [])]
-    if ck_seeds and ck_seeds != [int(s) for s in pe_old.seeds]:
-        raise ValueError(
-            f"checkpoint in {old_dir} holds slot seeds {ck_seeds}, but the "
-            f"given specs produce {list(pe_old.seeds)} — pass the pack's "
-            "original specs in their original order")
+    old = pe_old._bests()
+    state, done, chunk_idx, migrations = pe_old._restore(
+        old_dir, step, pe_old.init_state(), old)
 
     pe_new = PackedEngine([specs[j] for j in keep], backend, options=options)
     idx = []
@@ -664,32 +603,11 @@ def repack_checkpoint(old_dir: str, specs, keep, new_dir: str,
             f"{pe_old.n_slots}-slot axis")
 
     new_state = jax.tree.map(_slice, pe_new.init_state(), state)
-
-    done = int(extra["gens_done"])
-    slot_y = np.asarray(extra["slot_y"], np.float32)
-    slot_x = np.asarray(extra["slot_x"], np.uint32).reshape(
-        pe_old.n_slots, specs[0].v)
-    if pe_new.n_slots > 1:
-        new_extra = {"gens_done": done,
-                     "chunk_idx": int(extra.get("chunk_idx", 0)),
-                     "migrations": int(extra.get("migrations", 0)),
-                     "slot_y": [float(v) for v in slot_y[idx_arr]],
-                     "slot_x": [[int(v) for v in row]
-                                for row in slot_x[idx_arr]],
-                     "seeds": [int(s) for s in pe_new.seeds],
-                     "backend": pe_new.backend_name}
-    else:
-        # a 1-slot pack delegates to the plain Engine, whose resume reads
-        # the solo extra format
-        r = idx[0]
-        new_extra = {"gens_done": done,
-                     "chunk_idx": int(extra.get("chunk_idx", 0)),
-                     "migrations": int(extra.get("migrations", 0)),
-                     "best_y": float(slot_y[r]),
-                     "best_x": [int(v) for v in slot_x[r]],
-                     "backend": pe_new.backend_name}
+    new = pe_new._bests()
+    new.fill(old.y[idx_arr], old.x[idx_arr])
     # recovery machinery is not an injection site: faults=False keeps an
     # ambient ckpt_corrupt rule from eating the repacked checkpoint
-    CKPT.save(new_dir, step=done, tree=new_state, extra=new_extra,
+    CKPT.save(new_dir, step=done, tree=new_state,
+              extra=pe_new._extra(done, chunk_idx, migrations, new),
               faults=False)
     return done

@@ -28,7 +28,8 @@ Three mechanisms carry the multiplexing:
   higher-priority queued work, and if present parks the pack (jobs →
   PREEMPTED, state already on disk) and requeues it.  Resume restores the
   packed state bit-identically — `run_chunked`'s checkpoint/resume path IS
-  the preemption primitive, no new state format.
+  the preemption primitive, no new state format.  A pack of one job runs
+  the chunk loop and writes the checkpoint a solo `Engine` does.
 
 Job states: QUEUED → RUNNING → DONE, with RUNNING → PREEMPTED → QUEUED
 loops and any state → FAILED on error.  Telemetry flows through a

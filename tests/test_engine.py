@@ -374,6 +374,32 @@ def test_islands_backend_chunks_by_epoch():
     assert len(r.traj_best) == 4   # one telemetry entry per migration epoch
 
 
+@pytest.mark.parametrize("backend,kw", [
+    ("reference", {}),
+    ("islands", dict(n_islands=4, migrate_every=5)),
+    ("reference", dict(n_repeats=2)),
+], ids=["reference", "islands", "reference-repeats2"])
+def test_every_entry_runs_the_same_chunk_loop(backend, kw):
+    """Engine.run, Engine.run_chunked and a pack of one or of two jobs agree
+    on a job's best, its chromosome and its last chunk's trajectory."""
+    spec = _spec(generations=30, **kw)
+    other = dataclasses.replace(spec, seed=spec.seed + 100)
+    straight = ga.Engine(spec, backend).run()
+    chunked = list(ga.Engine(spec, backend).run_chunked(
+        chunk_generations=10))[-1]
+    alone = ga.PackedEngine([spec], backend).run(chunk_generations=10)[0]
+    paired = ga.PackedEngine([spec, other], backend).run(
+        chunk_generations=10)[0]
+    n_last = len(chunked["traj_best"])
+    assert 0 < n_last < len(straight.traj_best)
+    want = (straight.best_fitness, straight.best_params,
+            straight.traj_best[-n_last:])
+    for got in (chunked, alone, paired):
+        assert got["best_fitness"] == want[0]
+        np.testing.assert_array_equal(got["best_params"], want[1])
+        np.testing.assert_array_equal(got["traj_best"], want[2])
+
+
 # ---------------------------------------------------------------------------
 # Result semantics
 # ---------------------------------------------------------------------------

@@ -280,6 +280,62 @@ def test_repack_checkpoint_slices_bit_identically(tmp_path):
             spec, backend="reference").best_fitness
 
 
+def _engine_chunks(spec, ckpt_dir):
+    return ga.Engine(spec, "reference").run_chunked(chunk_generations=10,
+                                                    ckpt_dir=ckpt_dir)
+
+
+def _pack_chunks(spec, ckpt_dir):
+    for tele in ga.PackedEngine([spec], "reference").run_chunked(
+            chunk_generations=10, ckpt_dir=ckpt_dir):
+        yield dict(tele["jobs"][0], resumed_from=tele["resumed_from"])
+
+
+@pytest.mark.parametrize("first,then", [(_engine_chunks, _pack_chunks),
+                                        (_pack_chunks, _engine_chunks)],
+                         ids=["engine-then-pack", "pack-then-engine"])
+def test_solo_checkpoint_resumes_across_entries(tmp_path, first, then):
+    """An Engine and a one-job pack write the same checkpoint: either one
+    resumes the other's and finishes bit-identical to ga.solve."""
+    spec = _spec(generations=40)
+    want = ga.solve(spec, backend="reference")
+    it = first(spec, str(tmp_path))
+    next(it), next(it)      # 20 generations, then "crash"
+    del it
+    teles = list(then(spec, str(tmp_path)))
+    assert [t["gens_done"] for t in teles] == [30, 40]
+    assert teles[0]["resumed_from"] == 20
+    assert teles[-1]["best_fitness"] == want.best_fitness
+    np.testing.assert_array_equal(np.asarray(teles[-1]["best_params"]),
+                                  np.asarray(want.best_params))
+
+
+def test_solo_checkpoint_with_exactly_the_solo_keys_resumes(tmp_path):
+    """A checkpoint whose extra holds only the solo keys still resumes."""
+    spec = _spec(generations=40)
+    want = ga.solve(spec, backend="reference")
+    eng = ga.Engine(spec, "reference")
+    seg = eng.backend.segment(eng.init_state(), 20)
+    CKPT.save(str(tmp_path), step=20, tree=seg.state,
+              extra={"gens_done": 20, "chunk_idx": 2, "migrations": 0,
+                     "best_y": float(seg.best_y),
+                     "best_x": [int(v) for v in seg.best_x],
+                     "backend": "reference"})
+    teles = list(_engine_chunks(spec, str(tmp_path)))
+    assert [(t["chunk"], t["gens_done"]) for t in teles] == [(3, 30),
+                                                             (4, 40)]
+    assert teles[0]["resumed_from"] == 20
+    assert teles[-1]["best_fitness"] == want.best_fitness
+    np.testing.assert_array_equal(np.asarray(teles[-1]["best_params"]),
+                                  np.asarray(want.best_params))
+
+
+def test_solve_reaches_the_chunk_crash_site():
+    with pytest.raises(FLT.ChunkCrash):
+        ga.solve(_spec(), backend="reference",
+                 options=ga.EngineOptions(faults="chunk_crash:at=1"))
+
+
 # ---------------------------------------------------------------------------
 # Scheduler: retry, quarantine, deadlines, recovery
 # ---------------------------------------------------------------------------

@@ -1,7 +1,7 @@
 """Named host phases of a served job: the `ga.*` spans on the profiler's
 host plane and the per-job `phase_s` counters on /metrics, through the
-scheduler and both chunk loops (`Engine` for a pack of one, `PackedEngine`
-for a pack of two)."""
+scheduler and the engine's one chunk loop, for a pack of one and a pack of
+two."""
 
 import glob
 import time

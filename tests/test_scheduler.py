@@ -59,8 +59,19 @@ def test_packed_engine_bit_identical_to_solo_islands():
 
 
 def test_packed_engine_single_job_delegates():
+    """A one-job pack runs the chunk loop as an Engine does: its job dict
+    matches Engine.run_chunked's last one on every key a journaled result
+    keeps but the host timings, and its best is ga.solve's."""
     spec = _spec(seed=3)
-    packed = ga.PackedEngine([spec], "reference").run()
+    packed = ga.PackedEngine([spec], "reference").run(chunk_generations=5)
+    last = list(ga.Engine(spec, "reference").run_chunked(
+        chunk_generations=5))[-1]
+    keys = [k for k in GAScheduler._RESULT_JSON_KEYS
+            if k in last and k not in ("wall_s", "gens_per_s")]
+    assert "best_fitness" in keys and "migrations" in keys
+    for k in keys:
+        assert packed[0][k] == last[k], k
+    assert packed[0]["pack_size"] == 1 and packed[0]["job_index"] == 0
     solo = ga.solve(spec, backend="reference")
     assert packed[0]["best_fitness"] == solo.best_fitness
 
