@@ -89,7 +89,7 @@ from repro.ga.telemetry import (TELEMETRY_VERSION, PlanInfo, ReplicaStats,
                                 RunTelemetry, TopologyInfo)
 from repro.ga.backends import (BACKENDS, EXECUTORS, TOPOLOGIES, Backend,
                                Executor, Segment, Topology)
-from repro.ga.compile_cache import RUNNER_CACHE, CompileCache
+from repro.ga.compile_cache import PROGRAM_CACHE, RUNNER_CACHE, CompileCache
 from repro.ga.engine import (BackendUnsupported, Engine, EngineResult,
                              PackedEngine, capability_matrix,
                              repack_checkpoint, resolve_backend, solve)
@@ -103,7 +103,7 @@ __all__ = [
     "EngineOptions", "resolve_options",
     "RunTelemetry", "PlanInfo", "TopologyInfo", "ReplicaStats",
     "TELEMETRY_VERSION",
-    "RUNNER_CACHE", "CompileCache",
+    "RUNNER_CACHE", "PROGRAM_CACHE", "CompileCache",
     "BACKENDS", "Backend", "Segment",
     "EXECUTORS", "TOPOLOGIES", "Executor", "Topology",
     "SELECTION", "CROSSOVER", "MUTATION", "PAPER_PIPELINE",

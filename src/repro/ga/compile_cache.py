@@ -15,9 +15,19 @@ of an identical spec shape pays neither trace nor compile.  Safe because
 `cfg.seed` is consumed only by `init_state` (never inside a traced runner
 body), so runners are seed-independent by construction.
 
-Counters (`hits` / `misses` / `evictions`) are exported through the serving
-scheduler's `/metrics` gauges and asserted by tests; `RUNNER_CACHE` is the
-global instance.
+A second instance, `PROGRAM_CACHE`, interns the compiled fitness itself:
+`GASpec.program()` looks up `GASpec.program_key()` (the problem definition
+or blackbox callable identity, V, bits_per_var, mode, minimize — the fields
+`compile_program` reads, nothing else), so every spec of one problem shape
+shares ONE `FitnessProgram` object.  Its bound `.stage` then hashes equal
+across jobs, and the FFM trace cache in `kernels.ga_step` (keyed by that
+bound method) traces a problem's stage once, not once per job's fresh spec.
+Safe for the same reason as the runner cache: a program is a pure, frozen
+function of its key, and the runners a `RUNNER_CACHE` hit hands back
+already close over the first job's program.
+
+Counters (`hits` / `misses` / `evictions`) of both are exported through the
+serving scheduler's `/metrics` gauges and asserted by tests.
 """
 
 from __future__ import annotations
@@ -38,7 +48,8 @@ def mesh_fingerprint(mesh) -> Optional[tuple]:
 
 
 class CompileCache:
-    """Thread-safe LRU of compiled segment runners with hit/miss counters."""
+    """Thread-safe LRU of built objects (compiled segment runners, fitness
+    programs) with hit/miss counters."""
 
     def __init__(self, max_entries: int = 128):
         self._lock = threading.Lock()
@@ -83,6 +94,9 @@ class CompileCache:
 
 
 RUNNER_CACHE = CompileCache()
+# sized like the FFM trace cache in kernels.ga_step, whose keys are these
+# programs' bound stages
+PROGRAM_CACHE = CompileCache(max_entries=64)
 
 
 def runner_key(spec, topology_name: str, executor_name: str,

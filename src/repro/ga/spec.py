@@ -24,6 +24,7 @@ import numpy as np
 
 from repro.core import fitness as F
 from repro.core import ga as G
+from repro.ga import compile_cache as CC
 from repro.ga import operators as OPS
 
 
@@ -237,20 +238,39 @@ class GASpec:
         """The spec's fitness compiled for every executor (LUT ROMs when
         mode='lut', the shared XLA/in-kernel arith stage always).
 
-        Cached per spec instance: every caller (capability checks, epoch
-        planning, executor construction) sees the SAME FitnessProgram, so
-        its bound `.stage` method hashes/compares equal across calls and
-        downstream trace caches (kernels.ga_step) key on one object instead
-        of re-tracing a fresh program each time."""
+        Shared process-wide by `program_key()`: every spec whose fitness
+        compiles identically — specs that differ in seed, generations,
+        repeats, N, islands or operators, as a stream of solve jobs does —
+        gets the SAME FitnessProgram from `compile_cache.PROGRAM_CACHE`, so
+        its bound `.stage` method hashes/compares equal across specs and the
+        downstream trace caches (kernels.ga_step `_ffm_jaxpr`) trace the FFM
+        stage once per problem shape, not once per job.  Safe because a
+        program is a pure function of the key's fields (`compile_program`
+        reads nothing else) and is immutable.  A per-instance memo sits in
+        front, so repeat calls on one spec skip the cache's lock."""
         cached = self.__dict__.get("_program")
         if cached is None:
-            cached = F.compile_program(problem=self.problem,
-                                       fitness=self.fitness,
-                                       bounds=self.bounds, n_vars=self.v,
-                                       bits_per_var=self.bits_per_var,
-                                       mode=self.mode, minimize=self.minimize)
+            cached = CC.PROGRAM_CACHE.get_or_build(
+                self.program_key(),
+                lambda: F.compile_program(
+                    problem=self.problem, fitness=self.fitness,
+                    bounds=self.bounds, n_vars=self.v,
+                    bits_per_var=self.bits_per_var, mode=self.mode,
+                    minimize=self.minimize))
             object.__setattr__(self, "_program", cached)
         return cached
+
+    def program_key(self) -> tuple:
+        """Hashable identity of the compiled fitness: exactly the fields
+        `compile_program` reads.  A registered problem is keyed by its
+        `ProblemDef` (a frozen value that carries the name, so a name
+        re-registered with another definition does not alias the old
+        program); a blackbox by callable identity — safe for the reason
+        `compile_key` gives: the cached program holds the callable alive,
+        so its id cannot be recycled while the entry exists."""
+        fit_id = (self.problem_def() if self.problem is not None
+                  else ("blackbox", id(self.fitness), self.bounds))
+        return (fit_id, self.v, self.bits_per_var, self.mode, self.minimize)
 
     def compile_key(self) -> tuple:
         """Hashable trace-shape identity: two specs with equal keys lower to

@@ -195,11 +195,14 @@ def _ffm_jaxpr(ffm: FfmStage, n: int, v: int):
     A fused engine build consults this trace up to three times — the
     `supports` const gate, the epoch planner's VMEM budget check and
     `_hoist_ffm` at kernel-build time — so a slow-to-trace blackbox fitness
-    must not pay 3×.  `ffm` is a bound `FitnessProgram.stage` method (the
-    spec caches its program, so the SAME bound method arrives each call) or
-    a user callable; both hash by identity, and the cached jaxpr's consts
-    keep any captured arrays (and the callable itself) alive, so id-keyed
-    entries can't go stale."""
+    must not pay 3×.  `ffm` is a bound `FitnessProgram.stage` method or a
+    user callable; both hash by identity.  `GASpec.program()` interns
+    programs process-wide (`compile_cache.PROGRAM_CACHE`), so every spec of
+    one problem shape — each job of a stream of fresh seed-only specs —
+    passes the SAME bound method and the stage is traced once per problem
+    shape, not once per job.  The cached jaxpr's consts keep any captured
+    arrays (and the callable itself) alive, so id-keyed entries can't go
+    stale."""
     return jax.make_jaxpr(lambda xx: jnp.asarray(ffm(xx), jnp.float32))(
         jax.ShapeDtypeStruct((n, v), jnp.uint32))
 

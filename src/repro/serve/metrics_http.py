@@ -83,6 +83,10 @@ _SCHED_GAUGES = (
     ("cache_hits", "compile_cache_hits", "Compiled-runner cache hits"),
     ("cache_misses", "compile_cache_misses", "Compiled-runner cache misses"),
     ("cache_entries", "compile_cache_entries", "Compiled runners cached"),
+    ("program_cache_hits", "program_cache_hits",
+     "Fitness-program cache hits"),
+    ("program_cache_misses", "program_cache_misses",
+     "Fitness-program cache misses"),
     ("jobs_evicted", "sched_evicted_total",
      "Finished jobs TTL-evicted from the registry"),
     ("plans_measured", "plan_measured_total",

@@ -358,13 +358,14 @@ class GAScheduler:
                 raise TimeoutError(f"job {job.job_id} still {job.state}")
 
     def stats(self) -> Dict[str, Any]:
-        """Scheduler gauges for /metrics (queue depth, running, packing and
-        compile-cache counters)."""
-        from repro.ga.compile_cache import RUNNER_CACHE
+        """Scheduler gauges for /metrics (queue depth, running, packing,
+        compiled-runner and fitness-program cache counters)."""
+        from repro.ga.compile_cache import PROGRAM_CACHE, RUNNER_CACHE
         with self._cv:
             depth = sum(len(u.jobs) for u in self._queue)
             running = len(self._running)
         cache = RUNNER_CACHE.stats()
+        programs = PROGRAM_CACHE.stats()
         return {"queue_depth": depth, "jobs_running": running,
                 "packs_launched": self.packs_launched,
                 "preemptions": self.preemptions,
@@ -373,6 +374,8 @@ class GAScheduler:
                 "cache_hits": cache["hits"],
                 "cache_misses": cache["misses"],
                 "cache_entries": cache["entries"],
+                "program_cache_hits": programs["hits"],
+                "program_cache_misses": programs["misses"],
                 "jobs_evicted": self.jobs_evicted,
                 "plans_measured": self.plans_measured,
                 "plans_heuristic": self.plans_heuristic,

@@ -337,6 +337,86 @@ def test_second_engine_seeds_without_tracing():
 
 
 # ---------------------------------------------------------------------------
+# One FitnessProgram per problem shape (compile_cache.PROGRAM_CACHE)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("field,value", [
+    ("seed", 12), ("generations", 7), ("n_repeats", 3), ("n", 64),
+    ("n_islands", 2)])
+def test_specs_of_one_problem_shape_share_a_program(field, value):
+    spec = _spec()
+    other = dataclasses.replace(spec, **{field: value})
+    assert other.program() is spec.program()
+
+
+@pytest.mark.parametrize("a,b", [
+    (dict(problem="F3"), dict(problem="F1")),
+    (dict(problem="rastrigin:4"), dict(problem="rastrigin:6")),
+    (dict(bits_per_var=10), dict(bits_per_var=12)),
+    (dict(problem="rastrigin:4", mode="arith"),
+     dict(problem="rastrigin:4", mode="lut")),
+    (dict(minimize=True), dict(minimize=False))])
+def test_specs_of_another_problem_shape_get_their_own_program(a, b):
+    assert _spec(**a).program() is not _spec(**b).program()
+
+
+def test_distinct_blackboxes_get_their_own_programs():
+    def f(x):
+        return jnp.sum(x * x, axis=-1)
+
+    def g(x):
+        return jnp.sum(x * x, axis=-1)
+
+    box = ((-1.0, 1.0), (-1.0, 1.0))
+    a = _spec(problem=None, fitness=f, bounds=box)
+    assert dataclasses.replace(a, seed=3).program() is a.program()
+    assert _spec(problem=None, fitness=g, bounds=box).program() \
+        is not a.program()
+
+
+def test_second_fused_islands_build_reuses_the_ffm_trace():
+    """A fresh seed-only spec (one job of a solve stream) finds its FFM
+    stage already traced: no `_ffm_jaxpr` miss, the blackbox traced once
+    across both builds."""
+    from repro.kernels.ga_step import ffm_trace_cache_info
+
+    calls = []
+
+    def fit(x):
+        calls.append(1)
+        return -((x[:, 0] - 0.5) ** 2 + (x[:, 1] + 0.25) ** 2)
+
+    spec = _spec(fitness=fit, problem=None,
+                 bounds=((-1.0, 1.0), (-1.0, 1.0)),
+                 n_islands=2, migrate_every=4, migration="none",
+                 generations=8)
+    ga.Engine(spec, "fused-islands")
+    misses = ffm_trace_cache_info().misses
+    ga.Engine(dataclasses.replace(spec, seed=12), "fused-islands")
+    assert ffm_trace_cache_info().misses == misses
+    assert sum(calls) == 1
+
+
+def test_shared_program_runs_bit_identical_to_a_fresh_one():
+    spec = _spec(problem="rastrigin:4", n_islands=2, migrate_every=4,
+                 generations=8)
+    ga.Engine(spec, "fused-islands")
+    again = dataclasses.replace(spec, seed=12)
+    shared = ga.Engine(again, "fused-islands").run()
+    assert again.program() is spec.program()
+    ga.PROGRAM_CACHE.reset()
+    ga.RUNNER_CACHE.reset()
+    fresh_spec = dataclasses.replace(spec, seed=12)
+    fresh = ga.Engine(fresh_spec, "fused-islands").run()
+    assert fresh_spec.program() is not spec.program()
+    assert ga.PROGRAM_CACHE.stats()["misses"] == 1
+    assert fresh.best_fitness == shared.best_fitness
+    np.testing.assert_array_equal(fresh.best_x, shared.best_x)
+    np.testing.assert_array_equal(fresh.traj_best, shared.traj_best)
+
+
+# ---------------------------------------------------------------------------
 # Chunked streaming + checkpoint/resume
 # ---------------------------------------------------------------------------
 

@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from repro import ga
-from repro.ga.compile_cache import RUNNER_CACHE
+from repro.ga.compile_cache import PROGRAM_CACHE, RUNNER_CACHE
 from repro.serve.engine import GAMetricsRegistry
 from repro.serve.scheduler import (DONE, PREEMPTED, GAScheduler)
 
@@ -279,6 +279,22 @@ def test_scheduler_compile_cache_hit_on_resubmit(tmp_path):
         h0 = sched.stats()["cache_hits"]
         sched.result(sched.submit(_spec(seed=2)), timeout=120)
         assert sched.stats()["cache_hits"] == h0 + 1
+    finally:
+        sched.shutdown()
+
+
+def test_scheduler_reports_program_cache_gauges(tmp_path):
+    """Two jobs of one problem shape compile one FitnessProgram: one miss,
+    and every later lookup a hit, reported by `stats()`."""
+    PROGRAM_CACHE.reset()
+    sched = GAScheduler(registry=GAMetricsRegistry(),
+                        ckpt_root=str(tmp_path))
+    try:
+        sched.result(sched.submit(_spec(seed=1)), timeout=120)
+        sched.result(sched.submit(_spec(seed=2)), timeout=120)
+        stats = sched.stats()
+        assert stats["program_cache_misses"] == 1
+        assert stats["program_cache_hits"] >= 1
     finally:
         sched.shutdown()
 
