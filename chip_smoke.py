@@ -1,7 +1,7 @@
 """Run the GA main path once on a TPU and check it against the reference.
 
     python chip_smoke.py                # one chip: paper, resident, streamed,
-                                        # bbob, serve
+                                        # bbob, maxsat, serve
     python chip_smoke.py --four-chips   # island ring over a 4-device mesh only
 
 Every phase drives the user entry points (`ga.solve`, `ga.Engine
@@ -17,6 +17,10 @@ chromosome and fitness.
   streamed  rastrigin:10, N=2048, 64 islands on `fused-islands`: plan streamed
   bbob      bbob_f24:40 (BBOB f24, its 40x40 rotation hoisted into the kernel
             as a 2-D constant), N=256, 16 islands, 16 bits a variable on
+            `fused-islands`: plan resident
+  maxsat    maxsat_uf250 (MAX-3SAT at SATLIB's uf250-1065 shape: 25 words
+            of 10 bits, the clause contraction on the MXU against its
+            hoisted (1065, 250) incidence matrix), N=256, 16 islands on
             `fused-islands`: plan resident
   serve     16 F3 jobs through GAScheduler(max_pack=8) on `fused`; each
             job's result equals its solo run, and every job ends DONE
@@ -252,6 +256,8 @@ def main() -> int:
                 smoke.phase("bbob", lambda: smoke.islands(
                     256, 16, 256, "resident", problem="bbob_f24:40",
                     bits=16))
+                smoke.phase("maxsat", lambda: smoke.islands(
+                    256, 16, 256, "resident", problem="maxsat_uf250"))
                 smoke.phase("serve", smoke.serve)
         except Exception:   # any phase failure: traceback, no result line
             traceback.print_exc()

@@ -21,7 +21,9 @@ evaluation mode the engine's executors consume:
     reference × fused bit-identity hold for every registered problem.
 
 All modes share the domain mapping: a c-bit unsigned gene u decodes to
-v = lo + u * (hi - lo) / (2^c - 1), per variable.
+v = lo + u * (hi - lo) / (2^c - 1), per variable.  A bitstring genome
+(``ProblemDef.genome="bits"``, MAX-3SAT) uses the domain (0, 2^c - 1),
+whose step is exactly 1, so its objective sees each word's own value.
 """
 
 from __future__ import annotations
@@ -50,17 +52,24 @@ class ProblemDef:
     numpy, evaluated at ROM-synthesis time) enables the LUT lowering; leave
     it None for non-separable problems (rosenbrock, ackley, blackboxes),
     which then run mode='arith' only.
+
+    ``genome`` says what a chromosome means to its user: ``"real"`` — V
+    variables in ``domain``; ``"bits"`` — a V·c-bit string (``fixed_bits``
+    pins c, and ``domain`` is (0, 2^c - 1), so ``fn`` reads each word as
+    its own value), which `GASpec.decode` returns as its 0/1 assignment.
     """
 
     name: str
     fn: Callable[[jax.Array], jax.Array]
     domain: Tuple[float, float]          # per-variable decode box
-    fixed_vars: Optional[int] = None     # paper problems pin V
+    fixed_vars: Optional[int] = None     # paper and bitstring problems pin V
     default_vars: int = 2
     min_vars: int = 1
     minimize: bool = True
     term: Optional[Callable[[np.ndarray, int], np.ndarray]] = None
     gamma: Optional[Callable[[np.ndarray], np.ndarray]] = None  # None = id
+    genome: str = "real"                 # "real" | "bits"
+    fixed_bits: Optional[int] = None     # bitstring problems pin c
 
     @property
     def separable(self) -> bool:
@@ -101,19 +110,27 @@ def resolve_problem(problem: str) -> Tuple[ProblemDef, Optional[int]]:
 
 def resolve_vars(pdef: ProblemDef, n_vars: Optional[int]) -> int:
     """Validate a requested variable count against a problem's shape rules
-    (fixed paper layout, minimum V) and return the effective V.  THE shared
+    (a pinned V, minimum V) and return the effective V.  THE shared
     rule set — `GASpec` validation and `compile_program` both call this."""
     if pdef.fixed_vars is not None:
         if n_vars is not None and n_vars != pdef.fixed_vars:
             raise ValueError(f"problem {pdef.name!r} is defined at "
-                             f"V={pdef.fixed_vars} (paper layout); "
-                             f"got n_vars={n_vars}")
+                             f"V={pdef.fixed_vars}; got n_vars={n_vars}")
         return pdef.fixed_vars
     v = n_vars if n_vars is not None else pdef.default_vars
     if v < pdef.min_vars:
         raise ValueError(f"problem {pdef.name!r} needs at least "
                          f"{pdef.min_vars} variables; got n_vars={v}")
     return v
+
+
+def check_bits(pdef: ProblemDef, bits_per_var: int) -> None:
+    """Reject a word width a bitstring problem is not defined at (shared by
+    `GASpec` validation and `compile_program`)."""
+    if pdef.fixed_bits is not None and bits_per_var != pdef.fixed_bits:
+        raise ValueError(f"problem {pdef.name!r} is a bitstring of "
+                         f"{pdef.fixed_bits}-bit words; got "
+                         f"bits_per_var={bits_per_var}")
 
 
 def check_mode(pdef: ProblemDef, mode: str) -> None:
@@ -305,6 +322,105 @@ register_problem(ProblemDef(
     default_vars=40,
     min_vars=2,
 ))
+
+
+# --- MAX-3SAT at SATLIB's uf250-1065 shape (a bitstring genome) -----------
+
+MAXSAT_VARS = 250
+MAXSAT_CLAUSES = 1065
+MAXSAT_WORDS = 25             # V: 25 words ...
+MAXSAT_WORD_BITS = 10         # ... of c = 10 bits; variable j is bit j % 10
+                              # (from the least significant) of word j // 10
+
+
+@dataclasses.dataclass(frozen=True)
+class MaxSatInstance:
+    """A uniform random 3-SAT formula: clause k is the disjunction of
+    literals (var[k, l], neg[k, l]), l < 3, over three distinct variables;
+    neg 1 marks a negated literal."""
+
+    var: np.ndarray         # int64 (m, 3)
+    neg: np.ndarray         # int64 (m, 3)
+
+
+def maxsat_instance() -> MaxSatInstance:
+    """The uf250-1065-shaped instance, drawn by the uniform model of
+    Mitchell, Selman & Levesque (AAAI 1992) from
+    `numpy.random.default_rng(1)`: each clause's three distinct variables,
+    then every clause's negation bits.  Not one of SATLIB's files, and not
+    filtered to satisfiable formulas."""
+    rng = np.random.default_rng(1)
+    var = np.stack([rng.choice(MAXSAT_VARS, size=3, replace=False)
+                    for _ in range(MAXSAT_CLAUSES)])
+    neg = rng.integers(0, 2, size=(MAXSAT_CLAUSES, 3))
+    return MaxSatInstance(var=var, neg=neg)
+
+
+@functools.lru_cache(maxsize=1)
+def maxsat_uf250() -> Callable[[jax.Array], jax.Array]:
+    """The number of unsatisfied clauses of the uf250-1065-shaped instance
+    as a clause contraction on the MXU: the words' values ``f32[..., 25]``
+    (decoded with the domain (0, 1023), whose step is exactly 1.0, so each
+    is its word, exact in float32) -> ``f32[...]``, minimised (0: the
+    assignment satisfies the formula).
+
+    The population turns lane-major, the kernels' own layout (N along the
+    lanes), and its words unpack into ten shift-and-mask bit planes stacked
+    down the sublanes: row b * 25 + j of the (250, N) bit matrix holds
+    variable 10 j + b.  Column r of the signed incidence
+    matrix W (1065, 250) is that variable: W[k, r] is +1 where clause k
+    holds it plainly, -1 where negated, 0 elsewhere.  W @ bits + b, with
+    b[k] the count of clause k's negated literals, is the number of true
+    literals of each clause; a clause with none is unsatisfied.
+
+    Exact at any MXU precision and in any accumulation order: every
+    product is 0 or +-1 (exact in bfloat16, where both operands live) and
+    each clause's sum has at most three nonzero terms, so every partial
+    sum is an integer of magnitude <= 3; the count of unsatisfied clauses
+    is an integer <= 1065, exact in float32 whatever order adds it.  So the
+    kernel, XLA and a literal-by-literal reference agree bit for bit, and
+    padding W with zero columns, or with clauses of b = 1, would change
+    nothing.
+
+    Built once per process (the instance, W and b); both ride into the
+    fused kernels as hoisted 2-D constants."""
+    inst = maxsat_instance()
+    c, v = MAXSAT_WORD_BITS, MAXSAT_WORDS
+    w = np.zeros((MAXSAT_CLAUSES, MAXSAT_VARS), np.float32)
+    cols = (inst.var % c) * v + inst.var // c       # plane-major unpack
+    rows = np.broadcast_to(np.arange(MAXSAT_CLAUSES)[:, None], cols.shape)
+    w[rows, cols] = np.where(inst.neg == 1, -1.0, 1.0)
+    w = w.astype(jnp.bfloat16)
+    b = inst.neg.sum(axis=1).astype(np.float32)[:, None]
+
+    def f(vals):
+        if vals.ndim == 1:                                 # one genome
+            return f(vals[None])[0]
+        xt = jnp.swapaxes(vals.astype(jnp.int32), -1, -2)  # (..., V, N)
+        planes = [((xt >> k) & 1).astype(jnp.float32) for k in range(c)]
+        bits = jnp.concatenate(planes, axis=-2).astype(jnp.bfloat16)
+        true_lits = jnp.matmul(jnp.asarray(w), bits,
+                               preferred_element_type=jnp.float32)
+        true_lits = true_lits + jnp.asarray(b)             # (..., m, N)
+        return jnp.sum(jnp.where(true_lits < 0.5, 1.0, 0.0), axis=-2)
+
+    return f
+
+
+register_problem(ProblemDef(
+    name="maxsat_uf250",
+    fn=lambda x: maxsat_uf250()(x),
+    domain=(0.0, float((1 << MAXSAT_WORD_BITS) - 1)),
+    fixed_vars=MAXSAT_WORDS,
+    genome="bits",
+    fixed_bits=MAXSAT_WORD_BITS,
+))
+
+# The builders of the instance arrays that registered objectives close
+# over, by problem name.  Each is lru_cached, so it builds once per
+# process; the engine's build calls it inside the span
+# ``ga.problem.instance``, before anything traces the objective.
+INSTANCES: Dict[str, Callable[[], object]] = {"maxsat_uf250": maxsat_uf250}
 
 
 def decode(u: jax.Array, c: int, domain: tuple) -> jax.Array:
@@ -509,6 +625,7 @@ def compile_program(problem: Optional[str] = None,
             raise ValueError(f"problem {problem!r} pins V={v_suffix} but "
                              f"n_vars={n_vars} was also given")
         v = resolve_vars(pdef, v_suffix if v_suffix is not None else n_vars)
+        check_bits(pdef, bits_per_var)
         check_mode(pdef, mode)
         tables = (build_tables(pdef, bits_per_var, v)
                   if mode == "lut" else None)

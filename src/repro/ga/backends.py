@@ -718,6 +718,9 @@ class IslandRingTopology(Topology):
                                                        plan_cfg)
             plan["vmem_estimate_bytes"] = _ga_step.resident_vmem_bytes(
                 plan_cfg, 1, const_vmem)
+        if self.executor.name == "fused":
+            plan["ffm_const_bytes"] = _ga_step.ffm_const_bytes(
+                self.executor.fit, plan_cfg)
         return plan
 
     @staticmethod

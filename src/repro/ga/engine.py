@@ -37,6 +37,7 @@ import numpy as np
 
 from repro import faults as FLT
 from repro.ckpt import checkpoint as CKPT
+from repro.core import fitness as F
 from repro.ga import telemetry as RT
 from repro.ga.backends import BACKENDS, Backend, Segment
 from repro.ga.options import EngineOptions, resolve_options
@@ -104,7 +105,8 @@ class EngineResult:
     backend: str
     best_fitness: float
     best_x: np.ndarray            # uint32[V] chromosome
-    best_params: np.ndarray       # float64[V] decoded variables
+    best_params: np.ndarray       # float64[V] decoded variables, or a
+                                  # bitstring's 0/1 assignment (uint8[V*c])
     traj_best: np.ndarray
     traj_mean: np.ndarray
     generations: int
@@ -215,6 +217,11 @@ class _Run:
         self._build_s: Dict[str, float] = {}
         with RT.phase("ga.engine.build", self._build_s):
             with RT.phase("ga.problem.build", self._build_s):
+                build_instance = F.INSTANCES.get(spec.problem)
+                if build_instance is not None:
+                    with RT.phase("ga.problem.instance", self._build_s,
+                                  problem=spec.problem):
+                        build_instance()
                 spec.program()
             self.backend_name = resolve_backend(spec, backend,
                                                 self.options.mesh)

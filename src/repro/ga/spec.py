@@ -137,6 +137,7 @@ class GASpec:
                 object.__setattr__(self, "problem", pdef.name)
                 object.__setattr__(self, "n_vars", v_suffix)
             F.resolve_vars(pdef, self.n_vars)
+            F.check_bits(pdef, self.bits_per_var)
             F.check_mode(pdef, self.mode)
         if self.fitness is not None and self.bounds is None:
             raise ValueError("blackbox fitness requires bounds=")
@@ -307,7 +308,15 @@ class GASpec:
         return (self.problem_def().domain,) * self.v
 
     def decode(self, x: np.ndarray) -> np.ndarray:
-        """Decode a uint32[V] chromosome to real variable values."""
+        """Decode a uint32[V] chromosome to real variable values; a
+        bitstring genome to its 0/1 assignment (uint8[V * c]) in variable
+        order, variable j being bit j % c of word j // c."""
+        pdef = self.problem_def()
+        if pdef is not None and pdef.genome == "bits":
+            c = self.bits_per_var
+            words = np.asarray(x, np.uint32)[:, None]
+            return ((words >> np.arange(c, dtype=np.uint32)) & 1).astype(
+                np.uint8).reshape(-1)
         u = np.asarray(x, np.uint64) & np.uint64((1 << self.bits_per_var) - 1)
         doms = self.var_domains()
         lo = np.array([d[0] for d in doms])

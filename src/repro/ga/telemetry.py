@@ -44,7 +44,8 @@ class PlanInfo:
     of that rejection).  tile_islands is the streamed mode's island tile.
     lane is the selection lane the fused kernels ran ("onehot" | "gather" |
     "-" for executors without one).  gens_per_s is the measured rate that
-    justified a "measured" choice."""
+    justified a "measured" choice.  ffm_const_bytes is the bytes of array
+    constants hoisted into the fused kernels, which each launch reads."""
 
     mode: str = "-"
     source: str = "-"
@@ -55,6 +56,7 @@ class PlanInfo:
     lane: str = "-"
     vmem_estimate_bytes: Optional[int] = None
     gens_per_s: Optional[float] = None
+    ffm_const_bytes: Optional[int] = None
 
     @classmethod
     def from_plan(cls, plan: Dict[str, Any]) -> "PlanInfo":
@@ -67,7 +69,8 @@ class PlanInfo:
                    tile_islands=plan.get("tile_islands"),
                    lane=plan.get("lane", "-"),
                    vmem_estimate_bytes=plan.get("vmem_estimate_bytes"),
-                   gens_per_s=plan.get("plan_gens_per_s"))
+                   gens_per_s=plan.get("plan_gens_per_s"),
+                   ffm_const_bytes=plan.get("ffm_const_bytes"))
 
 
 @dataclasses.dataclass
@@ -125,6 +128,7 @@ PHASES = {
     "ga.sched.build": "build",
     "ga.engine.build": "build",
     "ga.problem.build": "build",
+    "ga.problem.instance": "build",
     "ga.sched.finish": "finish",
     "ga.sched.park": "park",
     "ga.journal.append": "journal",

@@ -38,9 +38,10 @@ Key adaptation — MUX trees → two selection lanes (``GAConfig.sel_lane``):
 
 The FFM stage is PLUGGABLE: the kernel takes a traceable ``ffm`` function
 ``uint32[N, V] bits -> f32[N]`` (normally ``FitnessProgram.stage`` from
-repro.core.fitness — decode + the problem's jnp expression) and traces it
-into the kernel body, so any n-variable registry problem or user blackbox
-runs fused.  The reference executor evaluates the SAME function; the
+repro.core.fitness — decode + the problem's jnp expression, a matrix
+product on the MXU included, as in MAX-3SAT's clause contraction) and
+traces it into the kernel body, so any n-variable registry problem or user
+blackbox runs fused.  The reference executor evaluates the SAME function; the
 registry problems reduce over V in one fixed order (`fitness.vsum`) so both
 compilers round alike.  LUT-mode (HBM gather tables) stays in the pure-JAX
 path.

@@ -129,6 +129,22 @@ def test_planned_bbob_resident_epoch_compiles(one_chip):
     _compile(runner, _island_states(spec, one_chip))
 
 
+def test_planned_maxsat_resident_epoch_compiles(one_chip):
+    """MAX-3SAT at the uf250-1065 shape (the `maxsat-uf250` configuration),
+    16 islands x N=256 of 25 ten-bit words: resident, and the kernel runs
+    the clause contraction on the MXU against the hoisted (1065, 250)
+    bfloat16 incidence matrix — an unaligned contraction over the
+    unpacked bit planes."""
+    spec = _spec(problem="maxsat_uf250", n=256, n_islands=16,
+                 bits_per_var=10, generations=1024)
+    eng, plan = _plan(spec)
+    assert plan["mode"] == "resident" and plan["lane"] == "onehot"
+    assert plan["gens_per_launch"] == 64
+    assert plan["ffm_const_bytes"] == 1065 * 250 * 2 + 1065 * 4
+    runner = eng.backend.topology._resident_runner(plan["epochs_per_launch"])
+    _compile(runner, _island_states(spec, one_chip))
+
+
 def test_planned_streamed_tile_compiles(one_chip):
     """64 islands x N=2048 exceed the budget: the planner streams tiles on
     the gather lane, and the streamed runner compiles."""
